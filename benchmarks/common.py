@@ -1,6 +1,7 @@
 """Benchmark helpers: wall-clock timing + the TPU roofline traffic model.
 
-The container is CPU-only, so every benchmark reports BOTH:
+These benchmarks run the smoke preset on the CPU, so every one reports
+BOTH (neither is a chip measurement; `chip_smoke.py` runs on the chip):
   * us_cpu      — measured CPU wall time (algorithmic reality check), and
   * us_tpu_model — modeled TPU v5e latency from *measured* pass/iteration
                    counts × the memory-bound traffic model (all Top-K stages

@@ -151,6 +151,10 @@ def markdown(rows):
 
 def bench_roofline():
     rows = table()
+    if not rows:
+        raise FileNotFoundError(
+            "roofline: no results/dryrun/*pod1.json — run "
+            "`python -m repro.launch.dryrun_all` first")
     out = []
     for r in rows:
         out.append((f"roofline/{r['arch']}/{r['shape']}", "",

@@ -5,6 +5,8 @@
 Prints ``name,us_per_call,derived`` CSV. CPU wall numbers are measured here;
 'tpu_us'/'speedup_model' values are derived from measured iteration counts ×
 the v5e roofline traffic model (benchmarks/common.py) — both are labeled.
+Neither is a chip measurement. A section that raises prints an ERROR row
+and the run exits non-zero.
 """
 
 import sys
@@ -41,25 +43,28 @@ def _register():
     })
     from . import roofline
     SECTIONS["roofline_serving"] = roofline.bench_roofline_serving
-    try:
-        import glob
-        if glob.glob("results/dryrun/*pod1.json"):
-            SECTIONS["roofline"] = roofline.bench_roofline
-    except Exception:
-        pass
+    # reads results/dryrun/ (git-ignored): fails loudly when it is missing
+    SECTIONS["roofline"] = roofline.bench_roofline
 
 
-def main() -> None:
+def main() -> int:
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     _register()
     names = sys.argv[1:] or list(SECTIONS)
     rows = []
+    failed = []
     for name in names:
         try:
             rows.extend(SECTIONS[name]())
-        except Exception as e:  # noqa: BLE001 — keep the harness running
+        except Exception as e:  # noqa: BLE001 — report every section first
             rows.append((f"{name}/ERROR", "", repr(e)[:120]))
+            failed.append(name)
     emit(rows)
+    if failed:
+        print(f"failed sections: {failed}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

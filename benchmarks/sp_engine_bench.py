@@ -178,6 +178,7 @@ print("RESULT:" + json.dumps(out))
 
 
 def bench_sp_engine():
+    import jax
     from repro.configs.registry import get_config
 
     full = bool(os.environ.get("SP_ENGINE_BENCH_FULL"))
@@ -239,6 +240,17 @@ def bench_sp_engine():
 
     # ---- 3. engine tokens/s, sharded vs single, identical tokens, and ----
     # the HLO-grounded collective check (forced multi-device subprocess)
+    if jax.default_backend() != "cpu":
+        # this process already holds the accelerator; the child below
+        # forces a CPU mesh, so what it measures is the CPU, not the chip
+        raise RuntimeError(
+            "sp_engine runs a forced multi-device CPU mesh in a child "
+            "process: run it with JAX_PLATFORMS=cpu (it is not a device "
+            "measurement; `python chip_smoke.py --chips 4` runs the "
+            "sharded engine on four chips)")
+    print("sp_engine: engine rows come from a forced "
+          f"{shards}-device CPU mesh in a child process (CPU walls)",
+          file=sys.stderr)
     hlo_lens = (512, 2048) if full else (256, 1024)
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={shards}"

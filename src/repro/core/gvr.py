@@ -9,9 +9,9 @@ The paper's four phases (§4.2), expressed functionally and jittable:
                             (monotone non-increasing step function). Each
                             iteration costs one fused row sweep.
   Phase 3 (Verify)        : candidate collection. In this pure-JAX layer the
-                            candidate set stays implicit (a mask); the Pallas
-                            kernel (kernels/gvr_topk.py) materializes it in
-                            VMEM with MXU one-hot compaction.
+                            candidate set stays implicit (a mask), as in the
+                            Pallas kernel (kernels/gvr_topk.py), which
+                            refines over its VMEM-resident row.
   Phase 4 (Refine/snap)   : step the threshold through distinct data values
                             (fused count_ge/count_gt/snap_up/snap_down per
                             sweep) until n_gt(T) < K <= n_ge(T) — T is then

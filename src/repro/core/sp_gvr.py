@@ -80,8 +80,7 @@ def sp_gvr_topk_local(scores_local: jnp.ndarray, prev_idx: jnp.ndarray, k: int,
     """
     b, n_local = scores_local.shape
     x = scores_local.astype(jnp.float32)
-    from repro.parallel.sharding import axis_size
-    d = axis_size(axis_name)
+    d = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     n = n_local * d
     offset = (my * n_local).astype(jnp.int32)
@@ -312,8 +311,7 @@ def sp_gvr_topk(scores: jnp.ndarray, prev_idx: jnp.ndarray, k: int, mesh,
         r = sp_gvr_topk_local(xs, pi, k, axis_name, **kw)
         return r.local_indices, r.local_count, r.threshold, r.secant_iters
 
-    from repro.parallel.sharding import shard_map
-    fn_sm = shard_map(fn, mesh=mesh,
+    fn_sm = jax.shard_map(fn, mesh=mesh,
                           in_specs=(P(None, axis_name), P(None, None)),
                           out_specs=(P(axis_name, None), P(axis_name), P(axis_name),
                                      P(axis_name)),

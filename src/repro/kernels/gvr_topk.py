@@ -1,34 +1,36 @@
 """Pallas TPU kernel: fused Guess-Verify-Refine exact Top-K.
 
-One program per batch row (grid=(B,)). The score row (N ≤ 512K → ≤ 2 MB f32)
-is brought HBM→VMEM once by the BlockSpec — after that every phase is
-on-chip, so the kernel's HBM traffic is the roofline minimum
-(N·4B in + K·8B out + M·4B prediction):
+One program per batch row (grid=(B,)). The score row is brought HBM→VMEM
+once by the BlockSpec as an (R, C) tile (C = 128 lanes for a plain score
+row, C = page_size for the paged indexer), so every phase is on-chip and
+the kernel's HBM traffic is the roofline minimum (N·4B in + K·8B out +
+M·4B prediction):
 
-  P1  gather prev-Top-K values (VMEM gather) → pmin/pmean/pmax.
+  P1  pre-indexed statistics: each of the previous step's M Top-K indices
+      (an SMEM row) addresses one tile row; a lane mask picks the entry →
+      pmin/pmean/pmax. Indices outside [0, N) are ignored.
   P2  secant threshold search — each iteration is a VPU count-reduction
-      over the resident row (the paper's blockCountGE, minus the HBM cost).
-  P3  candidate collection into a VMEM buffer. TPU has no per-thread
-      scatter/ballot; compaction is done per chunk with a *radix-factored
-      one-hot contraction* on the MXU:  compacted = A_hiᵀ @ (A_lo ⊙ v),
-      where pos = 32·hi + lo and A_hi/A_lo are (chunk × 32) one-hots —
-      O(chunk·64) VPU compares + two skinny MXU matmuls instead of an
-      O(chunk²) dense one-hot. Chunks with no candidates are predicated
-      away (pl.when).
-  P4  exact refine on the candidate buffer via *bit-space bisection*:
-      bisect the sortable-int32 image of f32, guaranteeing ≤ 32 exactly
-      convergent iterations of (cheap, buffer-resident) count passes. This
-      replaces the paper's SMEM histogram + snap stepping: on TPU the
-      buffer is VMEM-resident so bounded bisection dominates both. The
-      count at the final key IS n_gt/n_ge — tie partition follows.
-  P5  emit exactly K (all > T* plus lowest-index ties) with the same
-      factored compaction, from the buffer when it's valid, else from the
-      full row (overflow fallback — >C candidates, e.g. massive ties).
+      over the resident tile (the paper's blockCountGE, minus the HBM
+      cost) until count(x ≥ T) lands in the window [K, C_max].
+  P3  exact refine by bit-space bisection on the sortable-int32 image of
+      f32, from the secant's bracket to just above the row maximum: at
+      most 32 exactly convergent count passes. The count at the final key
+      IS n_gt/n_ge, so the tie partition follows. On a VMEM-resident row a
+      full-tile count pass is a few dozen vector ops, so the refine counts
+      over the tile instead of compacting a candidate buffer first (the
+      GPU kernel's reason for the buffer is HBM/L2 traffic, which does not
+      exist here). stats[3] still reports whether the secant left more
+      than C_max candidates.
+  P4  emit exactly K (all > T* plus the lowest-index ties), compacted in
+      index order row by row: a row's exclusive prefix count is one
+      (1,C)·(C,C) triangular matmul, and each selected entry is routed to
+      its output slot by a one-hot contraction on the MXU. The payload is
+      the entry's index and f32 bit pattern split into bytes, which bf16
+      holds exactly, so the routing is exact at any matmul precision.
 
-Validated with interpret=True against kernels/ref.py (lax.top_k oracle).
-Mosaic-lowering notes: the P1 gather uses jnp.take (dynamic VMEM gather);
-cumsum/iota use 2D broadcasted forms where it matters. The factored one-hot
-contraction and all count reductions are plain compare/matmul/reduce ops.
+Every vector is 2-D, with no scatter and no 1-D gather: the forms Mosaic
+lowers. Checked with interpret=True against kernels/ref.py (lax.top_k
+oracle) and compiled for TPU v5e by tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -39,111 +41,94 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_CHUNK = 512
-RADIX = 32  # factored one-hot radix: pos = RADIX*hi + lo
-
-
-def _to_key_u(x):
-    """f32 -> uint32 monotone key (matches topk_baselines transform)."""
-    u = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    sign = (u >> 31) == 1
-    return jnp.where(sign, ~u, u | jnp.uint32(0x80000000))
+LANES = 128
+_PAYLOAD_ROWS = 8        # index bytes (3) + f32 bytes (4), padded to a tile
 
 
-def _from_key_u(u):
-    sign = (u >> 31) == 0
-    v = jnp.where(sign, ~u, u & jnp.uint32(0x7FFFFFFF))
-    return jax.lax.bitcast_convert_type(v, jnp.float32)
+def out_rows(k: int) -> int:
+    """Rows of the (rows, 128) output tile that holds K results."""
+    return -(-k // LANES)
 
 
-def _count_ge(x, t):
-    return jnp.sum((x >= t).astype(jnp.int32))
+def _key(bits):
+    """f32 bit pattern (int32) -> int32 key with the float order."""
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
 
 
-def _compact_chunk(vals, gidx_f, sel, chunk):
-    """Radix-factored one-hot compaction of one chunk.
+def _f32_key(x):
+    return _key(jax.lax.bitcast_convert_type(x, jnp.int32))
 
-    Returns (cvals, cidx_f, count): selected entries packed to the front (in
-    original order), garbage beyond `count`.
+
+def _from_key(key):
+    return jax.lax.bitcast_convert_type(_key(key), jnp.float32)
+
+
+def _scalar_key(x):
+    """_f32_key of a scalar, through a vector: Mosaic bitcasts vectors
+    only."""
+    return jnp.max(_f32_key(jnp.full((1, LANES), x, jnp.float32)))
+
+
+def _midpoint(lo, hi):
+    """floor((lo + hi) / 2) without int32 overflow."""
+    return (lo & hi) + ((lo ^ hi) >> 1)
+
+
+def _prev_stats(x_ref, prev_ref, *, m, n):
+    """P1: min / max / sum / count of the scores the previous Top-K points
+    at. prev_ref is an SMEM (1, M) row of logical indices, or an (OR, 128)
+    tile of them."""
+    c = x_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
+
+    def body(j, s):
+        vmin, vmax, vsum, vcnt = s
+        idx = prev_ref[j // prev_ref.shape[1], j % prev_ref.shape[1]]
+        ok = (idx >= 0) & (idx < n)
+        idc = jnp.clip(idx, 0, n - 1)
+        row = x_ref[pl.ds(idc // c, 1), :]
+        hit = (lane == idc % c) & ok
+        return (jnp.where(hit, jnp.minimum(vmin, row), vmin),
+                jnp.where(hit, jnp.maximum(vmax, row), vmax),
+                vsum + jnp.where(hit, row, 0.0),
+                vcnt + hit.astype(jnp.float32))
+
+    init = (jnp.full((1, c), jnp.inf, jnp.float32),
+            jnp.full((1, c), -jnp.inf, jnp.float32),
+            jnp.zeros((1, c), jnp.float32), jnp.zeros((1, c), jnp.float32))
+    vmin, vmax, vsum, vcnt = jax.lax.fori_loop(0, m, body, init)
+    return jnp.min(vmin), jnp.max(vmax), jnp.sum(vsum), jnp.sum(vcnt)
+
+
+def gvr_on_resident_tile(x_ref, prev_ref, out_vals_ref, out_idx_ref,
+                         stats_ref, *, k, cmax, n, m, max_secant, f_target):
+    """All GVR phases over a VMEM-resident (R, C) score tile `x_ref` in
+    row-major logical order (position r·C + c, N = R·C).
+
+    Shared by the standalone Top-K kernel and the fused indexer+Top-K
+    kernels (where the tile is a scores scratch that never visits HBM).
+    Writes (OR, 128) value / index tiles (slot s at [s // 128, s % 128];
+    the first K slots hold the result) and a (1, 8) stats row:
+    [secant_iters, bisect_iters, cand_count, over_cmax, threshold, n_gt,
+    n_ge, emitted].
     """
-    pos = jnp.cumsum(sel.astype(jnp.int32)) - 1            # target slots
-    cnt = jnp.sum(sel.astype(jnp.int32))
-    # Sanitize unselected lanes: NaN/inf garbage (e.g. uninitialized scratch)
-    # would poison the contraction through 0*NaN.
-    vals = jnp.where(sel, vals, 0.0)
-    gidx_f = jnp.where(sel, gidx_f, 0.0)
-    hi = pos // RADIX
-    lo = pos - hi * RADIX
-    nhi = chunk // RADIX
-    iota_hi = jax.lax.broadcasted_iota(jnp.int32, (chunk, nhi), 1)
-    iota_lo = jax.lax.broadcasted_iota(jnp.int32, (chunk, RADIX), 1)
-    selc = sel.astype(jnp.float32)
-    a_hi = (hi[:, None] == iota_hi).astype(jnp.float32) * selc[:, None]   # (chunk, nhi)
-    a_lo = (lo[:, None] == iota_lo).astype(jnp.float32)                    # (chunk, RADIX)
-    # compacted[p] with p = RADIX*ph + pl_:  A_hiᵀ @ (A_lo ⊙ v) — exact in f32
-    def route(v):
-        t = a_hi.T @ (a_lo * v[:, None])                   # (nhi, RADIX)
-        return t.reshape(chunk)
-    return route(vals), route(gidx_f), cnt
+    def count_ge(t):
+        return jnp.sum((x_ref[...] >= t).astype(jnp.int32))
 
-
-def _bisect_exact_kth(count_ge_fn, lo_f, hi_f, k):
-    """Exact K-th largest via bisection on the sortable-int image of f32.
-
-    Invariant: count_ge(lo) >= k, count_ge(above hi) < k. Terminates in
-    <= 32 iterations at adjacent keys; returns (t_star, n_gt, n_ge, iters).
-    """
-    lo_k = _to_key_u(lo_f)
-    hi_k = _to_key_u(hi_f)
-
-    def cond(s):
-        lo_k, hi_k, it = s
-        return (hi_k - lo_k > jnp.uint32(1)) & (it < 34)
-
-    def body(s):
-        lo_k, hi_k, it = s
-        mid = lo_k + (hi_k - lo_k) // jnp.uint32(2)
-        c = count_ge_fn(_from_key_u(mid))
-        lo_k = jnp.where(c >= k, mid, lo_k)
-        hi_k = jnp.where(c >= k, hi_k, mid)
-        return lo_k, hi_k, it + 1
-
-    lo_k, hi_k, iters = jax.lax.while_loop(cond, body, (lo_k, hi_k, jnp.int32(0)))
-    t_star = _from_key_u(lo_k)
-    n_ge = count_ge_fn(t_star)
-    n_gt = count_ge_fn(_from_key_u(lo_k + jnp.uint32(1)))
-    return t_star, n_gt, n_ge, iters
-
-
-def _gvr_kernel(scores_ref, prev_ref, out_vals_ref, out_idx_ref, stats_ref,
-                cand_vals_ref, cand_idx_ref, out_v_scr, out_i_scr, *,
-                k, cmax, n, m, chunk, max_secant, f_target):
-    x = scores_ref[0, :]                                   # (N,) f32, VMEM-resident
-    gvr_on_resident_row(x, prev_ref[0, :], out_vals_ref, out_idx_ref, stats_ref,
-                        cand_vals_ref, cand_idx_ref, out_v_scr, out_i_scr,
-                        k=k, cmax=cmax, n=n, m=m, chunk=chunk,
-                        max_secant=max_secant, f_target=f_target)
-
-
-def gvr_on_resident_row(x, prev_idx, out_vals_ref, out_idx_ref, stats_ref,
-                        cand_vals_ref, cand_idx_ref, out_v_scr, out_i_scr, *,
-                        k, cmax, n, m, chunk, max_secant, f_target):
-    """All four GVR phases over a VMEM-resident score vector `x` (N,).
-
-    Shared between the standalone Top-K kernel and the fused indexer+Top-K
-    kernel (where `x` lives in a scores scratch that never visits HBM).
-    """
-    nchunks = n // chunk
-    fmax = jnp.float32(jnp.finfo(jnp.float32).max)
+    def count_ge_key(tk):
+        return jnp.sum((_f32_key(x_ref[...]) >= tk).astype(jnp.int32))
 
     # ---------------- Phase 1: pre-indexed statistics -------------------
-    pv = jnp.take(x, prev_idx, axis=0)                     # VMEM gather
-    p_lo = jnp.min(pv)
-    p_hi = jnp.max(pv)
-    t0 = jnp.mean(pv)
-    row_max = jnp.max(x)
-    row_min = jnp.min(x)
+    row_max = jnp.max(x_ref[...])
+    row_min = jnp.min(x_ref[...])
+    p_min, p_max, p_sum, p_cnt = _prev_stats(x_ref, prev_ref, m=m, n=n)
+    have = p_cnt > 0
+    p_lo = jnp.where(have, p_min, row_min)
+    p_hi = jnp.where(have, p_max, row_max)
+    t0 = jnp.where(have, p_sum / jnp.maximum(p_cnt, 1.0),
+                   0.5 * row_min + 0.5 * row_max)
     if m < k:
         p_lo = jnp.minimum(p_lo, row_min)
         p_hi = jnp.maximum(p_hi, row_max)
@@ -154,7 +139,7 @@ def gvr_on_resident_row(x, prev_idx, out_vals_ref, out_idx_ref, stats_ref,
     def secant_body(s):
         (t_lo, c_lo, t_hi, c_hi, t, t_probe, cnt, hi_probed, prev_over,
          done, it) = s
-        n_ge = _count_ge(x, t)
+        n_ge = count_ge(t)
         in_window = (n_ge >= k) & (n_ge <= cmax)
         done2 = done | in_window
         too_many = ~done & (n_ge > cmax)
@@ -164,11 +149,13 @@ def gvr_on_resident_row(x, prev_idx, out_vals_ref, out_idx_ref, stats_ref,
         t_hi = jnp.where(too_few, t, t_hi)
         c_hi = jnp.where(too_few, n_ge.astype(jnp.float32), c_hi)
         denom = c_lo - c_hi
-        frac = jnp.where(jnp.abs(denom) > 0, (c_lo - ftarget) / denom, jnp.float32(0.5))
+        frac = jnp.where(denom != 0,
+                         (c_lo - ftarget) / jnp.where(denom != 0, denom, 1.0),
+                         jnp.float32(0.5))
         frac = jnp.where(it == 0, jnp.minimum(frac, 0.5), frac)
         t_new = t_lo + frac * (t_hi - t_lo)
-        inside = (t_new > t_lo) & (t_new < t_hi) & jnp.isfinite(t_new)
-        t_new = jnp.where(inside, t_new, 0.5 * (t_lo + t_hi))
+        inside = (t_new > t_lo) & (t_new < t_hi)
+        t_new = jnp.where(inside, t_new, 0.5 * t_lo + 0.5 * t_hi)
         probe_lo = (frac <= 0) & (t_lo != t)
         t_new = jnp.where(probe_lo, t_lo, t_new)
         probe_hi = too_many & prev_over & ~hi_probed & (t_hi != t)
@@ -181,7 +168,7 @@ def gvr_on_resident_row(x, prev_idx, out_vals_ref, out_idx_ref, stats_ref,
         t_lo = jnp.where(rescue_lo, row_min, t_lo)
         c_lo = jnp.where(rescue_lo, jnp.float32(n), c_lo)
         rescued = rescue_hi | rescue_lo
-        t_new = jnp.where(rescued, 0.5 * (t_lo + t_hi), t_new)
+        t_new = jnp.where(rescued, 0.5 * t_lo + 0.5 * t_hi, t_new)
         collapsed = collapsed & ~rescued
         t_new = jnp.where(collapsed, t_lo, t_new)
         done2 = done2 | collapsed
@@ -202,158 +189,185 @@ def gvr_on_resident_row(x, prev_idx, out_vals_ref, out_idx_ref, stats_ref,
     (t_lo, _c_lo, _t_hi, _c_hi, _t, t_probe, cnt, _hp, _po, _done,
      secant_iters) = jax.lax.while_loop(secant_cond, secant_body, init)
     t_exit = jnp.where(cnt >= k, t_probe, t_lo)
-    c_exit = _count_ge(x, t_exit)
-    buffer_ok = c_exit <= cmax          # else overflow → full-row refine
+    c_exit = count_ge(t_exit)
 
-    # ---------------- Phase 3: candidate collection ---------------------
-    def collect(_):
-        def chunk_body(j, base):
-            xm = jax.lax.dynamic_slice(x, (j * chunk,), (chunk,))
-            sel = xm >= t_exit
-            gidx_f = (jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)[0]
-                      + j * chunk).astype(jnp.float32)
-            cv, ci, c = _compact_chunk(xm, gidx_f, sel, chunk)
-
-            @pl.when(c > 0)
-            def _():
-                cand_vals_ref[pl.ds(base, chunk)] = cv
-                cand_idx_ref[pl.ds(base, chunk)] = ci
-            return base + c
-        return jax.lax.fori_loop(0, nchunks, chunk_body, jnp.int32(0))
-
-    total = jax.lax.cond(buffer_ok, collect, lambda _: jnp.int32(0), None)
-
-    # ---------------- Phase 4: exact refine (bit-bisection) -------------
-    cpad = cand_vals_ref.shape[0]
-    bpos = jax.lax.broadcasted_iota(jnp.int32, (1, cpad), 1)[0]
-
-    def count_buf(t):
-        bv = cand_vals_ref[...]
-        valid = bpos < total
-        return jnp.sum((valid & (bv >= t)).astype(jnp.int32))
-
-    def count_row(t):
-        return _count_ge(x, t)
-
-    # bracket: count_ge(lo0) >= k. t_exit qualifies when c_exit >= k, else row_min.
+    # ---------------- Phase 3: exact refine (bit-bisection) -------------
+    # invariant: count_ge_key(lo) >= k > count_ge_key(hi); hi starts one
+    # key above the row maximum, so it holds even when the maximum ties
     lo0 = jnp.where(c_exit >= k, t_exit, row_min)
-    t_star_b, n_gt_b, n_ge_b, bi_b = jax.lax.cond(
-        buffer_ok,
-        lambda _: _bisect_exact_kth(count_buf, lo0, row_max, k),
-        lambda _: _bisect_exact_kth(count_row, lo0, row_max, k),
-        None)
-    t_star, n_gt, n_ge, bisect_iters = t_star_b, n_gt_b, n_ge_b, bi_b
-    quota = k - n_gt                                        # ties to take
 
-    # ---------------- Phase 5: emit exactly K ---------------------------
-    def emit_from_buffer(_):
-        bv = cand_vals_ref[...]
-        bi = cand_idx_ref[...]
-        valid = bpos < total
-        eq = valid & (bv == t_star)
-        eq_rank = jnp.cumsum(eq.astype(jnp.int32))          # inclusive
-        sel_all = (valid & (bv > t_star)) | (eq & (eq_rank <= quota))
+    def bis_cond(s):
+        lo, hi, it = s
+        return (hi > lo + 1) & (it < 34)          # hi - lo may overflow
 
-        def chunk_body(j, base):
-            sl = jax.lax.dynamic_slice
-            cv, ci, c = _compact_chunk(sl(bv, (j * chunk,), (chunk,)),
-                                       sl(bi, (j * chunk,), (chunk,)),
-                                       sl(sel_all, (j * chunk,), (chunk,)), chunk)
+    def bis_body(s):
+        lo, hi, it = s
+        mid = _midpoint(lo, hi)
+        ok = count_ge_key(mid) >= k
+        return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid), it + 1
 
-            @pl.when(c > 0)
-            def _():
-                out_v_scr[pl.ds(base, chunk)] = cv
-                out_i_scr[pl.ds(base, chunk)] = ci
-            return base + c
-        return jax.lax.fori_loop(0, cpad // chunk, chunk_body, jnp.int32(0))
+    t_key, _, bisect_iters = jax.lax.while_loop(
+        bis_cond, bis_body,
+        (_scalar_key(lo0), _scalar_key(row_max) + 1, jnp.int32(0)))
+    # count(>= t_key) >= k > count(>= t_key + 1): t_key is the K-th value
+    n_ge = count_ge_key(t_key)
+    n_gt = count_ge_key(t_key + 1)
 
-    def emit_from_row(_):
-        # overflow fallback: stream the row; running tie-rank carried across
-        # chunks keeps the lowest-index tie policy.
-        def chunk_body(j, carry):
-            base, eq_seen = carry
-            xm = jax.lax.dynamic_slice(x, (j * chunk,), (chunk,))
-            eq = xm == t_star
-            eq_rank = eq_seen + jnp.cumsum(eq.astype(jnp.int32))
-            sel = (xm > t_star) | (eq & (eq_rank <= quota))
-            gidx_f = (jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)[0]
-                      + j * chunk).astype(jnp.float32)
-            cv, ci, c = _compact_chunk(xm, gidx_f, sel, chunk)
+    # ---------------- Phase 4: emit exactly K ---------------------------
+    _emit(x_ref, t_key, k - n_gt, out_vals_ref, out_idx_ref)
 
-            @pl.when(c > 0)
-            def _():
-                out_v_scr[pl.ds(base, chunk)] = cv
-                out_i_scr[pl.ds(base, chunk)] = ci
-            return base + c, eq_seen + jnp.sum(eq.astype(jnp.int32))
-        out = jax.lax.fori_loop(0, nchunks, chunk_body,
-                                (jnp.int32(0), jnp.int32(0)))
-        return out[0]
+    lane8 = jax.lax.broadcasted_iota(jnp.int32, (1, 8), 1)
+    stats = jnp.zeros((1, 8), jnp.float32)
+    for j, v in enumerate((secant_iters.astype(jnp.float32),
+                           bisect_iters.astype(jnp.float32),
+                           c_exit.astype(jnp.float32),
+                           jnp.where(c_exit <= cmax, 0.0, 1.0),
+                           _from_key(jnp.full((1, 8), t_key, jnp.int32)),
+                           n_gt.astype(jnp.float32),
+                           n_ge.astype(jnp.float32),
+                           jnp.float32(k))):
+        stats = jnp.where(lane8 == j, v, stats)
+    stats_ref[...] = stats
 
-    emitted = jax.lax.cond(buffer_ok, emit_from_buffer, emit_from_row, None)
 
-    out_vals_ref[0, :] = out_v_scr[:k]
-    out_idx_ref[0, :] = out_i_scr[:k].astype(jnp.int32)
-    stats_ref[0, 0] = secant_iters.astype(jnp.float32)
-    stats_ref[0, 1] = bisect_iters.astype(jnp.float32)
-    stats_ref[0, 2] = c_exit.astype(jnp.float32)
-    stats_ref[0, 3] = jnp.where(buffer_ok, 0.0, 1.0)        # fallback flag
-    stats_ref[0, 4] = t_star
-    stats_ref[0, 5] = n_gt.astype(jnp.float32)
-    stats_ref[0, 6] = n_ge.astype(jnp.float32)
-    stats_ref[0, 7] = emitted.astype(jnp.float32)
+def _emit(x_ref, t_key, quota, out_vals_ref, out_idx_ref):
+    """P4: compact, in index order, every entry whose key exceeds t_key
+    plus the first `quota` entries equal to it into the (OR, 128) output
+    tiles."""
+    r_rows, c = x_ref.shape
+    n_out = out_idx_ref.shape[0]
+    span = -(-c // LANES) + 1            # output rows one source row can hit
+    ii = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    upper = (ii < jj).astype(jnp.bfloat16)     # exclusive-prefix operator
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (LANES, c), 0)
+    prow = jax.lax.broadcasted_iota(jnp.int32, (_PAYLOAD_ROWS, c), 0)
+
+    out_idx_ref[...] = jnp.zeros(out_idx_ref.shape, jnp.int32)
+    out_vals_ref[...] = jnp.zeros(out_vals_ref.shape, jnp.float32)
+
+    def prefix(mask):
+        return jnp.dot(mask.astype(jnp.bfloat16), upper,
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    def row_body(r, carry):
+        base, eq_seen = carry
+        xr = x_ref[pl.ds(r, 1), :]
+        bits = jax.lax.bitcast_convert_type(xr, jnp.int32)
+        kr = _key(bits)
+        eq = kr == t_key
+        rank = eq_seen + prefix(eq) + 1                 # 1-based tie rank
+        sel = (kr > t_key) | (eq & (rank <= quota))
+        cnt = jnp.sum(sel.astype(jnp.int32))
+
+        @pl.when(cnt > 0)
+        def _():
+            pos = base + prefix(sel)
+            gidx = r * c + lane
+            parts = (gidx, gidx >> 8, gidx >> 16,
+                     bits, bits >> 8, bits >> 16, bits >> 24)
+            payload = jnp.zeros((_PAYLOAD_ROWS, c), jnp.int32)
+            for j, part in enumerate(parts):
+                payload = jnp.where(prow == j, part & 0xFF, payload)
+            payload = payload.astype(jnp.float32).astype(jnp.bfloat16)
+            h0 = base // LANES
+            for t in range(span):
+                h = h0 + t
+
+                @pl.when(h < n_out)
+                def _():
+                    hit = sel & (pos // LANES == h)
+                    onehot = ((slot == pos % LANES) & hit).astype(jnp.bfloat16)
+                    routed = jax.lax.dot_general(
+                        payload, onehot, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32
+                    ).astype(jnp.int32)                          # (8, 128)
+                    idx = (routed[0:1] | (routed[1:2] << 8)
+                           | (routed[2:3] << 16))
+                    vbits = (routed[3:4] | (routed[4:5] << 8)
+                             | (routed[5:6] << 16) | (routed[6:7] << 24))
+                    row = pl.ds(h, 1)
+                    out_idx_ref[row, :] = out_idx_ref[row, :] | idx
+                    old = jax.lax.bitcast_convert_type(out_vals_ref[row, :],
+                                                       jnp.int32)
+                    out_vals_ref[row, :] = jax.lax.bitcast_convert_type(
+                        old | vbits, jnp.float32)
+        return base + cnt, eq_seen + jnp.sum(eq.astype(jnp.int32))
+
+    jax.lax.fori_loop(0, r_rows, row_body, (jnp.int32(0), jnp.int32(0)))
+
+
+# ---- shared pallas_call plumbing of every GVR kernel ----------------------
+
+def gvr_params(k: int, n: int, max_candidates: Optional[int],
+               f_target: Optional[int]):
+    """(C_max, secant target count)."""
+    cmax = max_candidates if max_candidates is not None else min(3 * k, n)
+    cmax = max(cmax, k)
+    ft = f_target if f_target is not None else (k + cmax) // 2
+    return cmax, ft
+
+
+def prev_spec(m: int, index_map):
+    """The previous Top-K row, in SMEM: P1 reads it one scalar at a time."""
+    return pl.BlockSpec((None, 1, m), index_map, memory_space=pltpu.SMEM)
+
+
+def topk_out_specs(k: int, index_map):
+    """(OR, 128) value + index tiles and the (1, 8) stats row of one grid
+    row; `index_map` returns (row, 0, 0)."""
+    orows = out_rows(k)
+    return (pl.BlockSpec((None, orows, LANES), index_map),
+            pl.BlockSpec((None, orows, LANES), index_map),
+            pl.BlockSpec((None, 1, 8), index_map))
+
+
+def topk_out_shapes(rows: int, k: int):
+    orows = out_rows(k)
+    return (jax.ShapeDtypeStruct((rows, orows, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((rows, orows, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((rows, 1, 8), jnp.float32))
+
+
+def unpack_topk(vals, idx, stats, k: int):
+    """(rows, OR, 128) tiles -> (rows, K) values and indices, (rows, 8)."""
+    rows = vals.shape[0]
+    return (vals.reshape(rows, -1)[:, :k], idx.reshape(rows, -1)[:, :k],
+            stats[:, 0])
+
+
+def _gvr_kernel(prev_ref, scores_ref, out_vals_ref, out_idx_ref, stats_ref,
+                **kw):
+    gvr_on_resident_tile(scores_ref, prev_ref, out_vals_ref, out_idx_ref,
+                         stats_ref, **kw)
 
 
 def gvr_topk_pallas(scores: jnp.ndarray, prev_idx: jnp.ndarray, k: int,
                     *, max_candidates: Optional[int] = None,
-                    chunk: int = DEFAULT_CHUNK,
                     max_secant_iters: int = 12,
                     f_target: Optional[int] = None,
-                    interpret: bool = True):
-    """pl.pallas_call wrapper. scores: (B, N) f32; prev_idx: (B, M) int32.
+                    interpret: bool = False):
+    """pl.pallas_call wrapper. scores: (B, N) f32, N a multiple of 128
+    (ops.py pads with -FLT_MAX); prev_idx: (B, M) int32.
 
     Returns (values (B,K) f32, indices (B,K) i32, stats (B,8) f32).
-    N must be a multiple of `chunk` (ops.py pads with -FLT_MAX).
     """
     b, n = scores.shape
     m = prev_idx.shape[-1]
-    assert n % chunk == 0, (n, chunk)
-    cmax = max_candidates if max_candidates is not None else min(3 * k, n)
-    cmax = max(cmax, k)
-    cpad = ((cmax + chunk - 1) // chunk + 1) * chunk
-    opad = ((k + chunk - 1) // chunk + 1) * chunk
-    ft = f_target if f_target is not None else (k + cmax) // 2
-
-    kern = functools.partial(_gvr_kernel, k=k, cmax=cmax, n=n, m=m, chunk=chunk,
+    assert n % LANES == 0, n
+    cmax, ft = gvr_params(k, n, max_candidates, f_target)
+    kern = functools.partial(_gvr_kernel, k=k, cmax=cmax, n=n, m=m,
                              max_secant=max_secant_iters, f_target=ft)
-    out_shapes = (
-        jax.ShapeDtypeStruct((b, k), jnp.float32),
-        jax.ShapeDtypeStruct((b, k), jnp.int32),
-        jax.ShapeDtypeStruct((b, 8), jnp.float32),
-    )
-    grid = (b,)
-    return pl.pallas_call(
+    row = lambda i: (i, 0, 0)
+    out = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n), lambda i: (i, 0)),
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, 8), lambda i: (i, 0)),
-        ),
-        out_shape=out_shapes,
-        scratch_shapes=[
-            pltpu_vmem((cpad,), jnp.float32),
-            pltpu_vmem((cpad,), jnp.float32),
-            pltpu_vmem((opad,), jnp.float32),
-            pltpu_vmem((opad,), jnp.float32),
-        ],
+        grid=(b,),
+        in_specs=[prev_spec(m, row),
+                  pl.BlockSpec((None, n // LANES, LANES), row)],
+        out_specs=topk_out_specs(k, row),
+        out_shape=topk_out_shapes(b, k),
         interpret=interpret,
-    )(scores.astype(jnp.float32), prev_idx.astype(jnp.int32))
-
-
-def pltpu_vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
+    )(prev_idx.astype(jnp.int32)[:, None],
+      scores.astype(jnp.float32).reshape(b, n // LANES, LANES))
+    return unpack_topk(*out, k)

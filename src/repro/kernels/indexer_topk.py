@@ -6,10 +6,10 @@ The paper's pipeline materializes the indexer score row to HBM
 working set, so we fuse them:
 
   grid = (B, N/kv_chunk). Each step DMAs one K-cache chunk
-  (kv_chunk × d_i bf16), computes the Eq.-1 scores on the MXU
-  (ReLU(q·Kᵀ) weighted over heads), and appends them to a VMEM scores
-  scratch. On the final chunk the full GVR pipeline (see gvr_topk.py)
-  runs over the resident scores — which therefore NEVER touch HBM.
+  (kv_chunk × d_i), computes the Eq.-1 scores (ReLU(q·Kᵀ) on the MXU,
+  weighted over heads on the VPU), and writes them into a VMEM scores
+  tile. On the final chunk the full GVR pipeline (see gvr_topk.py) runs
+  over the resident tile — the scores NEVER touch HBM.
 
 HBM traffic: N·d_i·2B (K cache, irreducible) + M·4B (prev idx) + K·8B out.
 The 2·N·4B score write+read of the unfused pipeline is eliminated — at
@@ -23,10 +23,10 @@ indexer's required traffic for free.
 and the block table is scalar-prefetched, so each grid step DMAs one
 physical (page_size × d_i) page — the kv chunk IS the logical page, the
 index_map does the logical→physical translation, and the contiguous
-logical indexer-K view is never materialized. Scores land in the same
-VMEM scratch (still never HBM) in logical order, so GVR and the emitted
-Top-K indices stay in logical token space — the feedback invariant the
-paged serving layer depends on. Unmapped pages (-1) score the sentinel.
+logical indexer-K view is never materialized. Page j's scores become row j
+of the (MP, page_size) scores tile, so GVR and the emitted Top-K indices
+stay in logical token space — the feedback invariant the paged serving
+layer depends on. Unmapped pages (-1) score the sentinel.
 """
 
 from __future__ import annotations
@@ -39,48 +39,60 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .gvr_topk import DEFAULT_CHUNK, gvr_on_resident_row, pltpu_vmem
+from .gvr_topk import (LANES, gvr_on_resident_tile, gvr_params, out_rows,
+                       prev_spec, topk_out_shapes, topk_out_specs,
+                       unpack_topk)
 
 NEG = -3.4028235e38  # python float: a jnp scalar would be a captured constant
 
 
-def _fused_kernel(q_ref, kv_ref, w_ref, prev_ref, len_ref,
-                  out_vals_ref, out_idx_ref, stats_ref,
-                  scores_scr, cand_vals_ref, cand_idx_ref, out_v_scr, out_i_scr,
-                  *, k, cmax, n, m, kv_chunk, chunk, max_secant, f_target, nkv):
+def _chunk_scores(q, kc, w_col):
+    """Eq. 1 over one chunk: sum_h w_h · ReLU(q_h · kc) -> (1, rows).
+    q (H, D); kc (rows, D); w_col (H, 1) f32."""
+    s = jax.lax.dot_general(q, kc, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    return jnp.sum(w_col * jnp.maximum(s, 0.0), axis=0, keepdims=True)
+
+
+def _weights(w, b, h):
+    """(H,) or (B, H) head weights -> (B, H, 1) f32 (a column per slot)."""
+    if w.ndim == 1:
+        w = jnp.broadcast_to(w[None], (b, h))
+    return w.astype(jnp.float32)[:, :, None]
+
+
+def _fused_kernel(len_ref, q_ref, kv_ref, w_ref, prev_ref,
+                  out_vals_ref, out_idx_ref, stats_ref, scores_scr,
+                  *, kv_chunk, nkv, gvr):
+    b = pl.program_id(0)
     j = pl.program_id(1)
-    q = q_ref[0]                                           # (H, D)
-    kc = kv_ref[0]                                         # (kv_chunk, D)
-    w = w_ref[0]                                           # (H,)
-    # Eq. 1 on the MXU: ReLU(q @ K^T) weighted over heads -> (kv_chunk,)
-    s = jnp.maximum(jnp.dot(q.astype(jnp.float32), kc.astype(jnp.float32).T), 0.0)
-    scores = jnp.dot(w.astype(jnp.float32), s)             # (kv_chunk,)
-    # ragged mask: positions beyond this row's true length get the sentinel
-    length = len_ref[0]
-    pos = jax.lax.broadcasted_iota(jnp.int32, (1, kv_chunk), 1)[0] + j * kv_chunk
-    scores = jnp.where(pos < length, scores, NEG)
-    scores_scr[pl.ds(j * kv_chunk, kv_chunk)] = scores
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    length = len_ref[b]
+    for r in range(kv_chunk // LANES):
+        sc = _chunk_scores(q_ref[...], kv_ref[pl.ds(r * LANES, LANES), :],
+                           w_ref[...])
+        pos = lane + j * kv_chunk + r * LANES
+        # ragged mask: positions beyond this row's length get the sentinel
+        scores_scr[pl.ds(j * (kv_chunk // LANES) + r, 1), :] = jnp.where(
+            pos < length, sc, NEG)
 
     @pl.when(j == nkv - 1)
     def _():
-        gvr_on_resident_row(scores_scr[...], prev_ref[0, :],
-                            out_vals_ref, out_idx_ref, stats_ref,
-                            cand_vals_ref, cand_idx_ref, out_v_scr, out_i_scr,
-                            k=k, cmax=cmax, n=n, m=m, chunk=chunk,
-                            max_secant=max_secant, f_target=f_target)
+        gvr_on_resident_tile(scores_scr, prev_ref, out_vals_ref, out_idx_ref,
+                             stats_ref, **gvr)
 
 
 def indexer_topk_pallas(q: jnp.ndarray, kcache: jnp.ndarray, w: jnp.ndarray,
                         prev_idx: jnp.ndarray, k: int,
                         *, lengths: Optional[jnp.ndarray] = None,
                         kv_chunk: int = 2048,
-                        chunk: int = DEFAULT_CHUNK,
                         max_candidates: Optional[int] = None,
                         max_secant_iters: int = 12,
                         f_target: Optional[int] = None,
-                        interpret: bool = True):
+                        interpret: bool = False):
     """Fused indexer+Top-K. q: (B,H,D); kcache: (B,N,D); w: (H,) or (B,H);
-    prev_idx: (B,M) int32; lengths: (B,) int32 (defaults to N).
+    prev_idx: (B,M) int32; lengths: (B,) int32 (defaults to N). N must be
+    a multiple of kv_chunk, and kv_chunk of 128 (ops.py pads).
 
     Returns (values (B,K), indices (B,K), stats (B,8)).
     """
@@ -88,51 +100,34 @@ def indexer_topk_pallas(q: jnp.ndarray, kcache: jnp.ndarray, w: jnp.ndarray,
     n = kcache.shape[1]
     m = prev_idx.shape[-1]
     kv_chunk = min(kv_chunk, n)
-    assert n % kv_chunk == 0 and n % chunk == 0, (n, kv_chunk, chunk)
+    assert n % kv_chunk == 0 and kv_chunk % LANES == 0, (n, kv_chunk)
     nkv = n // kv_chunk
-    if w.ndim == 1:
-        w = jnp.broadcast_to(w[None], (b, h))
     if lengths is None:
         lengths = jnp.full((b,), n, jnp.int32)
-    cmax = max_candidates if max_candidates is not None else min(3 * k, n)
-    cmax = max(cmax, k)
-    cpad = ((cmax + chunk - 1) // chunk + 1) * chunk
-    opad = ((k + chunk - 1) // chunk + 1) * chunk
-    ft = f_target if f_target is not None else (k + cmax) // 2
-
-    kern = functools.partial(_fused_kernel, k=k, cmax=cmax, n=n, m=m,
-                             kv_chunk=kv_chunk, chunk=chunk,
-                             max_secant=max_secant_iters, f_target=ft, nkv=nkv)
-    out_shapes = (
-        jax.ShapeDtypeStruct((b, k), jnp.float32),
-        jax.ShapeDtypeStruct((b, k), jnp.int32),
-        jax.ShapeDtypeStruct((b, 8), jnp.float32),
-    )
-    return pl.pallas_call(
-        kern,
+    cmax, ft = gvr_params(k, n, max_candidates, f_target)
+    gvr = dict(k=k, cmax=cmax, n=n, m=m, max_secant=max_secant_iters,
+               f_target=ft)
+    kern = functools.partial(_fused_kernel, kv_chunk=kv_chunk, nkv=nkv,
+                             gvr=gvr)
+    row = lambda i, j, ln: (i, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, nkv),
         in_specs=[
-            pl.BlockSpec((1, h, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, kv_chunk, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, h), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, m), lambda i, j: (i, 0)),
-            pl.BlockSpec((1,), lambda i, j: (i,)),
+            pl.BlockSpec((None, h, d), row),
+            pl.BlockSpec((None, kv_chunk, d), lambda i, j, ln: (i, j, 0)),
+            pl.BlockSpec((None, h, 1), row),
+            prev_spec(m, row),
         ],
-        out_specs=(
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 8), lambda i, j: (i, 0)),
-        ),
-        out_shape=out_shapes,
-        scratch_shapes=[
-            pltpu_vmem((n,), jnp.float32),        # resident scores (never HBM)
-            pltpu_vmem((cpad,), jnp.float32),
-            pltpu_vmem((cpad,), jnp.float32),
-            pltpu_vmem((opad,), jnp.float32),
-            pltpu_vmem((opad,), jnp.float32),
-        ],
+        out_specs=topk_out_specs(k, row),
+        scratch_shapes=[pltpu.VMEM((n // LANES, LANES), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kern, grid_spec=grid_spec, out_shape=topk_out_shapes(b, k),
         interpret=interpret,
-    )(q, kcache, w, prev_idx.astype(jnp.int32), lengths.astype(jnp.int32))
+    )(lengths.astype(jnp.int32), q, kcache, _weights(w, b, h),
+      prev_idx.astype(jnp.int32)[:, None])
+    return unpack_topk(*out, k)
 
 
 # --------------------------------------------------------------------------
@@ -140,56 +135,57 @@ def indexer_topk_pallas(q: jnp.ndarray, kcache: jnp.ndarray, w: jnp.ndarray,
 # logical indexer-K view is never materialized.
 # --------------------------------------------------------------------------
 
-def _paged_fused_kernel(table_ref, q_ref, pages_ref, w_ref, prev_ref, len_ref,
-                        out_vals_ref, out_idx_ref, stats_ref,
-                        scores_scr, cand_vals_ref, cand_idx_ref,
-                        out_v_scr, out_i_scr,
-                        *, k, cmax, n, m, page_size, chunk, max_secant,
-                        f_target, mp):
+def _score_page(table_ref, len_ref, q_ref, pages_ref, w_ref, scores_scr,
+                *, b, j, qrow):
+    """Score logical page j of slot b into row j of the scores tile. Both
+    the ragged tail and an unmapped page (-1) score the sentinel, so an
+    unmapped page can never be selected."""
+    page_size = scores_scr.shape[1]
+    sc = _chunk_scores(q_ref[...], pages_ref[...], w_ref[...])
+    pos = (jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
+           + j * page_size)
+    ok = (pos < len_ref[b, qrow]) & (table_ref[b, j] >= 0)
+    scores_scr[pl.ds(j, 1), :] = jnp.where(ok, sc, NEG)
+
+
+def _paged_fused_kernel(table_ref, len_ref, q_ref, pages_ref, w_ref,
+                        prev_ref, out_vals_ref, out_idx_ref, stats_ref,
+                        scores_scr, *, mp, gvr):
     b = pl.program_id(0)
     j = pl.program_id(1)
-    q = q_ref[0]                                           # (H, D)
-    kc = pages_ref[0]                                      # (page_size, D)
-    w = w_ref[0]                                           # (H,)
-    # Eq. 1 on the MXU over one physical page -> (page_size,) logical scores
-    s = jnp.maximum(jnp.dot(q.astype(jnp.float32), kc.astype(jnp.float32).T), 0.0)
-    scores = jnp.dot(w.astype(jnp.float32), s)             # (page_size,)
-    # mask ragged tail AND unmapped pages (-1 sentinel): both score NEG, so
-    # an unmapped page can never be selected
-    length = len_ref[0]
-    pos = (jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)[0]
-           + j * page_size)
-    mapped = table_ref[b, j] >= 0
-    scores = jnp.where((pos < length) & mapped, scores, NEG)
-    scores_scr[pl.ds(j * page_size, page_size)] = scores
+    _score_page(table_ref, len_ref, q_ref, pages_ref, w_ref, scores_scr,
+                b=b, j=j, qrow=0)
 
     @pl.when(j == mp - 1)
     def _():
-        gvr_on_resident_row(scores_scr[...], prev_ref[0, :],
-                            out_vals_ref, out_idx_ref, stats_ref,
-                            cand_vals_ref, cand_idx_ref, out_v_scr, out_i_scr,
-                            k=k, cmax=cmax, n=n, m=m, chunk=chunk,
-                            max_secant=max_secant, f_target=f_target)
+        gvr_on_resident_tile(scores_scr, prev_ref, out_vals_ref, out_idx_ref,
+                             stats_ref, **gvr)
+
+
+def _page_spec(page_size, d, table_at):
+    # the fused gather: page row index = prefetched table entry (unmapped
+    # entries clip to page 0; their scores are masked)
+    return pl.BlockSpec(
+        (None, page_size, d),
+        lambda *a: (jnp.maximum(table_at(*a), 0), 0, 0))
 
 
 def paged_indexer_topk_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                               w: jnp.ndarray, table: jnp.ndarray,
                               prev_idx: jnp.ndarray, k: int,
                               *, lengths: Optional[jnp.ndarray] = None,
-                              chunk: int = DEFAULT_CHUNK,
                               max_candidates: Optional[int] = None,
                               max_secant_iters: int = 12,
                               f_target: Optional[int] = None,
-                              interpret: bool = True):
+                              interpret: bool = False):
     """Fused paged indexer+Top-K. q: (B,H,D); k_pages: (P, page_size, D)
     global indexer-K page pool; table: (B, MP) int32 block table (-1 =
     unmapped); w: (H,) or (B,H); prev_idx: (B,M) int32 LOGICAL indices;
     lengths: (B,) int32 (defaults to MP·page_size).
 
     The grid's kv chunk is the logical page: step (b, j) DMAs physical page
-    table[b, j] (scalar-prefetched index_map), scores it, and appends the
-    scores at logical offset j·page_size in the VMEM scratch. MP·page_size
-    must be a multiple of `chunk` (ops.py pads the table with -1 columns).
+    table[b, j] (scalar-prefetched index_map), scores it, and writes row j
+    of the (MP, page_size) VMEM scores tile.
 
     Returns (values (B,K), indices (B,K) int32 — logical, stats (B,8)).
     """
@@ -198,55 +194,31 @@ def paged_indexer_topk_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
     mp = table.shape[1]
     n = mp * page_size
     m = prev_idx.shape[-1]
-    assert n % chunk == 0, (n, chunk)
-    if w.ndim == 1:
-        w = jnp.broadcast_to(w[None], (b, h))
     if lengths is None:
         lengths = jnp.full((b,), n, jnp.int32)
-    cmax = max_candidates if max_candidates is not None else min(3 * k, n)
-    cmax = max(cmax, k)
-    cpad = ((cmax + chunk - 1) // chunk + 1) * chunk
-    opad = ((k + chunk - 1) // chunk + 1) * chunk
-    ft = f_target if f_target is not None else (k + cmax) // 2
-
-    kern = functools.partial(_paged_fused_kernel, k=k, cmax=cmax, n=n, m=m,
-                             page_size=page_size, chunk=chunk,
-                             max_secant=max_secant_iters, f_target=ft, mp=mp)
-    out_shapes = (
-        jax.ShapeDtypeStruct((b, k), jnp.float32),
-        jax.ShapeDtypeStruct((b, k), jnp.int32),
-        jax.ShapeDtypeStruct((b, 8), jnp.float32),
-    )
+    cmax, ft = gvr_params(k, n, max_candidates, f_target)
+    gvr = dict(k=k, cmax=cmax, n=n, m=m, max_secant=max_secant_iters,
+               f_target=ft)
+    kern = functools.partial(_paged_fused_kernel, mp=mp, gvr=gvr)
+    row = lambda i, j, t, ln: (i, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, mp),
         in_specs=[
-            pl.BlockSpec((1, h, d), lambda i, j, t: (i, 0, 0)),
-            # the fused gather: page row index = prefetched table entry
-            # (unmapped entries clip to page 0; their scores are masked)
-            pl.BlockSpec((1, page_size, d),
-                         lambda i, j, t: (jnp.maximum(t[i, j], 0), 0, 0)),
-            pl.BlockSpec((1, h), lambda i, j, t: (i, 0)),
-            pl.BlockSpec((1, m), lambda i, j, t: (i, 0)),
-            pl.BlockSpec((1,), lambda i, j, t: (i,)),
+            pl.BlockSpec((None, h, d), row),
+            _page_spec(page_size, d, lambda i, j, t, ln: t[i, j]),
+            pl.BlockSpec((None, h, 1), row),
+            prev_spec(m, row),
         ],
-        out_specs=(
-            pl.BlockSpec((1, k), lambda i, j, t: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j, t: (i, 0)),
-            pl.BlockSpec((1, 8), lambda i, j, t: (i, 0)),
-        ),
-        scratch_shapes=[
-            pltpu_vmem((n,), jnp.float32),        # resident scores (never HBM)
-            pltpu_vmem((cpad,), jnp.float32),
-            pltpu_vmem((cpad,), jnp.float32),
-            pltpu_vmem((opad,), jnp.float32),
-            pltpu_vmem((opad,), jnp.float32),
-        ],
+        out_specs=topk_out_specs(k, row),
+        scratch_shapes=[pltpu.VMEM((mp, page_size), jnp.float32)],
     )
-    return pl.pallas_call(
-        kern, grid_spec=grid_spec, out_shape=out_shapes, interpret=interpret,
-    )(table.astype(jnp.int32), q, k_pages, w,
-      prev_idx.astype(jnp.int32), lengths.astype(jnp.int32))
+    out = pl.pallas_call(
+        kern, grid_spec=grid_spec, out_shape=topk_out_shapes(b, k),
+        interpret=interpret,
+    )(table.astype(jnp.int32), lengths.astype(jnp.int32)[:, None], q,
+      k_pages, _weights(w, b, h), prev_idx.astype(jnp.int32)[:, None])
+    return unpack_topk(*out, k)
 
 
 # --------------------------------------------------------------------------
@@ -255,53 +227,48 @@ def paged_indexer_topk_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
 # inside the kernel (DESIGN.md §spec-decode).
 # --------------------------------------------------------------------------
 
-def _paged_fused_mq_kernel(table_ref, q_ref, pages_ref, w_ref, prev_ref,
-                           len_ref, out_vals_ref, out_idx_ref, stats_ref,
-                           scores_scr, prev_scr, cand_vals_ref, cand_idx_ref,
-                           out_v_scr, out_i_scr,
-                           *, k, cmax, n, m, page_size, chunk, max_secant,
-                           f_target, mp):
+def _paged_fused_mq_kernel(table_ref, len_ref, q_ref, pages_ref, w_ref,
+                           prev_ref, out_vals_ref, out_idx_ref, stats_ref,
+                           scores_scr, thread_vmem, thread_smem, sem, *, mp,
+                           gvr):
     b = pl.program_id(0)
     qq = pl.program_id(1)
     j = pl.program_id(2)
-    q = q_ref[0, 0]                                        # (H, D)
-    kc = pages_ref[0]                                      # (page_size, D)
-    w = w_ref[0]                                           # (H,)
-    s = jnp.maximum(jnp.dot(q.astype(jnp.float32), kc.astype(jnp.float32).T), 0.0)
-    scores = jnp.dot(w.astype(jnp.float32), s)             # (page_size,)
     # per-query-row causal extent: verify position q masks beyond ITS
     # length (the engine passes lengths[b, q] = L0 + q + 1)
-    length = len_ref[0, 0]
-    pos = (jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)[0]
-           + j * page_size)
-    mapped = table_ref[b, j] >= 0
-    scores = jnp.where((pos < length) & mapped, scores, NEG)
-    scores_scr[pl.ds(j * page_size, page_size)] = scores
+    _score_page(table_ref, len_ref, q_ref, pages_ref, w_ref, scores_scr,
+                b=b, j=j, qrow=qq)
+
+    @pl.when((j == mp - 1) & (qq == 0))
+    def _():
+        # query row 0 warms from the caller's prev_idx (the previous
+        # TICK's selection)
+        def cp(i, c):
+            thread_smem[i // LANES, i % LANES] = prev_ref[0, i]
+            return c
+        jax.lax.fori_loop(0, prev_ref.shape[1], cp, 0)
 
     @pl.when(j == mp - 1)
     def _():
-        # the causally-extended feedback: query row 0 warms from the
-        # caller's prev_idx (the previous TICK's selection); every later
-        # row warms from the row BEFORE it in this launch, carried in a
-        # VMEM scratch — no HBM round-trip between draft positions
-        prev = jnp.where(qq == 0, prev_ref[0, :], prev_scr[...])
-        gvr_on_resident_row(scores_scr[...], prev,
-                            out_vals_ref, out_idx_ref, stats_ref,
-                            cand_vals_ref, cand_idx_ref, out_v_scr, out_i_scr,
-                            k=k, cmax=cmax, n=n, m=m, chunk=chunk,
-                            max_secant=max_secant, f_target=f_target)
-        prev_scr[...] = out_idx_ref[0, :]
+        # every later row warms from the row BEFORE it in this launch: its
+        # emitted indices were copied VMEM→SMEM below — the temporal
+        # signal never round-trips HBM between draft positions
+        gvr_on_resident_tile(scores_scr, thread_smem, out_vals_ref,
+                             out_idx_ref, stats_ref, **gvr)
+        thread_vmem[...] = out_idx_ref[...]
+        copy = pltpu.make_async_copy(thread_vmem, thread_smem, sem)
+        copy.start()
+        copy.wait()
 
 
 def paged_indexer_topk_mq_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                                  w: jnp.ndarray, table: jnp.ndarray,
                                  prev_idx: jnp.ndarray, k: int,
                                  *, lengths: jnp.ndarray,
-                                 chunk: int = DEFAULT_CHUNK,
                                  max_candidates: Optional[int] = None,
                                  max_secant_iters: int = 12,
                                  f_target: Optional[int] = None,
-                                 interpret: bool = True):
+                                 interpret: bool = False):
     """Fused paged indexer+GVR over Q query rows per slot (the verify
     tick's d+1 draft positions). q: (B, Q, H, D); k_pages: (P, page_size,
     D) global indexer-K page pool; table: (B, MP) int32 shared block
@@ -310,7 +277,7 @@ def paged_indexer_topk_mq_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
     q's causal extent (position L0 + q attends to L0 + q + 1 tokens).
 
     `prev_idx` must carry exactly K entries: rows 1..Q-1 warm-start from
-    the PREVIOUS ROW's emitted Top-K, threaded through a VMEM scratch
+    the PREVIOUS ROW's emitted Top-K, threaded through an SMEM scratch
     inside the launch — the kernel form of the verify scan's causally-
     extended feedback, so the temporal-correlation signal never leaves
     the chip between draft positions.
@@ -326,53 +293,33 @@ def paged_indexer_topk_mq_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
     assert m == k, ("the mq kernel threads each row's K-entry output into "
                     "the next row's warm start, so prev_idx must carry "
                     f"exactly K entries; got M={m}, K={k}")
-    assert n % chunk == 0, (n, chunk)
-    if w.ndim == 1:
-        w = jnp.broadcast_to(w[None], (b, h))
-    cmax = max_candidates if max_candidates is not None else min(3 * k, n)
-    cmax = max(cmax, k)
-    cpad = ((cmax + chunk - 1) // chunk + 1) * chunk
-    opad = ((k + chunk - 1) // chunk + 1) * chunk
-    ft = f_target if f_target is not None else (k + cmax) // 2
-
-    kern = functools.partial(_paged_fused_mq_kernel, k=k, cmax=cmax, n=n,
-                             m=m, page_size=page_size, chunk=chunk,
-                             max_secant=max_secant_iters, f_target=ft, mp=mp)
-    # outputs flattened to (B*Q, ...) so gvr_on_resident_row's (1, K)
-    # block writes apply unchanged; reshaped on return
-    out_shapes = (
-        jax.ShapeDtypeStruct((b * qn, k), jnp.float32),
-        jax.ShapeDtypeStruct((b * qn, k), jnp.int32),
-        jax.ShapeDtypeStruct((b * qn, 8), jnp.float32),
-    )
+    cmax, ft = gvr_params(k, n, max_candidates, f_target)
+    gvr = dict(k=k, cmax=cmax, n=n, m=m, max_secant=max_secant_iters,
+               f_target=ft)
+    kern = functools.partial(_paged_fused_mq_kernel, mp=mp, gvr=gvr)
+    slot = lambda i, qq, j, t, ln: (i, 0, 0)
+    # outputs flattened to (B*Q, ...) rows; reshaped on return
+    out_row = lambda i, qq, j, t, ln: (i * qn + qq, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, qn, mp),
         in_specs=[
-            pl.BlockSpec((1, 1, h, d), lambda i, qq, j, t: (i, qq, 0, 0)),
-            pl.BlockSpec((1, page_size, d),
-                         lambda i, qq, j, t: (jnp.maximum(t[i, j], 0), 0, 0)),
-            pl.BlockSpec((1, h), lambda i, qq, j, t: (i, 0)),
-            pl.BlockSpec((1, m), lambda i, qq, j, t: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, qq, j, t: (i, qq)),
+            pl.BlockSpec((None, None, h, d),
+                         lambda i, qq, j, t, ln: (i, qq, 0, 0)),
+            _page_spec(page_size, d, lambda i, qq, j, t, ln: t[i, j]),
+            pl.BlockSpec((None, h, 1), slot),
+            prev_spec(m, slot),
         ],
-        out_specs=(
-            pl.BlockSpec((1, k), lambda i, qq, j, t: (i * qn + qq, 0)),
-            pl.BlockSpec((1, k), lambda i, qq, j, t: (i * qn + qq, 0)),
-            pl.BlockSpec((1, 8), lambda i, qq, j, t: (i * qn + qq, 0)),
-        ),
-        scratch_shapes=[
-            pltpu_vmem((n,), jnp.float32),        # resident scores (never HBM)
-            pltpu_vmem((k,), jnp.int32),          # cross-row feedback thread
-            pltpu_vmem((cpad,), jnp.float32),
-            pltpu_vmem((cpad,), jnp.float32),
-            pltpu_vmem((opad,), jnp.float32),
-            pltpu_vmem((opad,), jnp.float32),
-        ],
+        out_specs=topk_out_specs(k, out_row),
+        scratch_shapes=[pltpu.VMEM((mp, page_size), jnp.float32),
+                        pltpu.VMEM((out_rows(k), LANES), jnp.int32),
+                        pltpu.SMEM((out_rows(k), LANES), jnp.int32),
+                        pltpu.SemaphoreType.DMA],
     )
-    vals, idx, stats = pl.pallas_call(
-        kern, grid_spec=grid_spec, out_shape=out_shapes, interpret=interpret,
-    )(table.astype(jnp.int32), q, k_pages, w,
-      prev_idx.astype(jnp.int32), lengths.astype(jnp.int32))
+    vals, idx, stats = unpack_topk(*pl.pallas_call(
+        kern, grid_spec=grid_spec, out_shape=topk_out_shapes(b * qn, k),
+        interpret=interpret,
+    )(table.astype(jnp.int32), lengths.astype(jnp.int32), q, k_pages,
+      _weights(w, b, h), prev_idx.astype(jnp.int32)[:, None]), k)
     return (vals.reshape(b, qn, k), idx.reshape(b, qn, k),
             stats.reshape(b, qn, 8))

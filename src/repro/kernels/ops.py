@@ -1,9 +1,11 @@
 """jit'd public wrappers around the Pallas kernels (padding + dtype contracts).
 
 Each op pads ragged/odd shapes to the kernel's tiling contract, runs the
-kernel (interpret=True on CPU, compiled on TPU), and strips padding. The
-pure-jnp oracles live in ref.py; tests assert allclose across a
-shape × dtype × distribution sweep.
+kernel and strips padding. `interpret` defaults to None: compiled by
+Mosaic when the default backend is a TPU, the Pallas interpreter on any
+other backend (the CPU tests). Only an explicit `interpret=True` runs the
+interpreter on a TPU. The pure-jnp oracles live in ref.py; tests assert
+allclose across a shape × dtype × distribution sweep.
 """
 
 from __future__ import annotations
@@ -14,7 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .gvr_topk import DEFAULT_CHUNK, gvr_topk_pallas
+from .gvr_topk import LANES, gvr_topk_pallas
 from .indexer_topk import (indexer_topk_pallas, paged_indexer_topk_mq_pallas,
                            paged_indexer_topk_pallas)
 from .paged_gather import paged_gather_pallas
@@ -27,6 +29,14 @@ from .sparse_attn import (paged_dense_decode_attn_pallas,
 NEG = -3.4028235e38
 
 
+def _interpret(interpret: Optional[bool]) -> bool:
+    """Compiled on a TPU backend unless the caller names interpret=True;
+    interpreted elsewhere, where Mosaic cannot compile."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
 def _pad_rows(x: jnp.ndarray, mult: int, value) -> jnp.ndarray:
     n = x.shape[-1]
     pad = (-n) % mult
@@ -35,14 +45,13 @@ def _pad_rows(x: jnp.ndarray, mult: int, value) -> jnp.ndarray:
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)], constant_values=value)
 
 
-@partial(jax.jit, static_argnames=("k", "chunk", "max_candidates",
+@partial(jax.jit, static_argnames=("k", "max_candidates",
                                    "max_secant_iters", "interpret"))
 def gvr_topk(scores: jnp.ndarray, prev_idx: jnp.ndarray, k: int,
              *, lengths: Optional[jnp.ndarray] = None,
-             chunk: int = DEFAULT_CHUNK,
              max_candidates: Optional[int] = None,
              max_secant_iters: int = 12,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """Exact Top-K with GVR (Pallas). scores (B,N) f32; prev_idx (B,M) i32.
 
     Returns (values (B,K) f32, indices (B,K) i32, stats (B,8) f32).
@@ -57,42 +66,41 @@ def gvr_topk(scores: jnp.ndarray, prev_idx: jnp.ndarray, k: int,
         ln = lengths[None] if squeeze else lengths
         pos = jnp.arange(x.shape[-1], dtype=jnp.int32)
         x = jnp.where(pos[None, :] < ln[:, None], x, NEG)
-    x = _pad_rows(x, chunk, NEG)
-    v, i, s = gvr_topk_pallas(x, p.astype(jnp.int32), k, chunk=chunk,
+    x = _pad_rows(x, LANES, NEG)
+    v, i, s = gvr_topk_pallas(x, p.astype(jnp.int32), k,
                               max_candidates=max_candidates,
                               max_secant_iters=max_secant_iters,
-                              interpret=interpret)
+                              interpret=_interpret(interpret))
     if squeeze:
         return v[0], i[0], s[0]
     return v, i, s
 
 
-@partial(jax.jit, static_argnames=("k", "kv_chunk", "chunk", "interpret"))
+@partial(jax.jit, static_argnames=("k", "kv_chunk", "interpret"))
 def indexer_topk(q: jnp.ndarray, kcache: jnp.ndarray, w: jnp.ndarray,
                  prev_idx: jnp.ndarray, k: int,
                  *, lengths: Optional[jnp.ndarray] = None,
-                 kv_chunk: int = 2048, chunk: int = DEFAULT_CHUNK,
-                 interpret: bool = True):
+                 kv_chunk: int = 2048,
+                 interpret: Optional[bool] = None):
     """Fused DSA indexer scoring + GVR Top-K (scores never touch HBM)."""
     b, _, _ = q.shape
     n = kcache.shape[1]
-    kv_chunk = min(kv_chunk, n)
-    # pad the cache length to the kv_chunk/chunk lattice; padded positions are
-    # masked by `lengths` inside the kernel
-    mult = max(kv_chunk, chunk)
-    pad = (-n) % mult
+    # pad the cache length to the kv_chunk lattice (kv_chunk itself to the
+    # 128 lanes); padded positions are masked by `lengths` in the kernel
+    kv_chunk = -(-min(kv_chunk, n) // LANES) * LANES
+    pad = (-n) % kv_chunk
     if pad:
         kcache = jnp.pad(kcache, ((0, 0), (0, pad), (0, 0)))
     if lengths is None:
         lengths = jnp.full((b,), n, jnp.int32)
     return indexer_topk_pallas(q, kcache, w, prev_idx, k, lengths=lengths,
-                               kv_chunk=kv_chunk, chunk=chunk,
-                               interpret=interpret)
+                               kv_chunk=kv_chunk,
+                               interpret=_interpret(interpret))
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def paged_gather(pages: jnp.ndarray, table: jnp.ndarray,
-                 *, interpret: bool = True):
+                 *, interpret: Optional[bool] = None):
     """Contiguous logical KV view from a paged pool (Pallas DMA gather).
 
     pages: (P, page_size, ...) — any trailing feature dims (KV heads × head
@@ -107,7 +115,8 @@ def paged_gather(pages: jnp.ndarray, table: jnp.ndarray,
         d *= f
     b, mp = table.shape
     out = paged_gather_pallas(pages.reshape(p, page_size, d),
-                              table.astype(jnp.int32), interpret=interpret)
+                              table.astype(jnp.int32),
+                              interpret=_interpret(interpret))
     return out.reshape((b, mp * page_size) + feat)
 
 
@@ -116,12 +125,12 @@ def paged_gather(pages: jnp.ndarray, table: jnp.ndarray,
 def sparse_decode_attn(q: jnp.ndarray, kcache: jnp.ndarray, vcache: jnp.ndarray,
                        idx: jnp.ndarray, *, scale: Optional[float] = None,
                        gather_block: int = 8, gather_mode: str = "pregather",
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """Decode attention over the Top-K selected tokens only (B,H,DV)."""
     return sparse_decode_attn_pallas(q, kcache, vcache, idx, scale=scale,
                                      gather_block=gather_block,
                                      gather_mode=gather_mode,
-                                     interpret=interpret)
+                                     interpret=_interpret(interpret))
 
 
 @partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -129,7 +138,7 @@ def paged_sparse_decode_attn(q: jnp.ndarray, k_pages: jnp.ndarray,
                              v_pages: jnp.ndarray, table: jnp.ndarray,
                              idx: jnp.ndarray, *,
                              scale: Optional[float] = None,
-                             interpret: bool = True):
+                             interpret: Optional[bool] = None):
     """Block-table-native sparse decode attention (B,H,DV).
 
     The Top-K gather and the logical→physical page translation are fused
@@ -139,7 +148,8 @@ def paged_sparse_decode_attn(q: jnp.ndarray, k_pages: jnp.ndarray,
     masked out of the softmax (DESIGN.md §paged).
     """
     return paged_sparse_decode_attn_pallas(q, k_pages, v_pages, table, idx,
-                                           scale=scale, interpret=interpret)
+                                           scale=scale,
+                                           interpret=_interpret(interpret))
 
 
 @partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -147,7 +157,7 @@ def paged_sparse_decode_attn_pg(q: jnp.ndarray, k_pages: jnp.ndarray,
                                 v_pages: jnp.ndarray, table: jnp.ndarray,
                                 idx: jnp.ndarray, *,
                                 scale: Optional[float] = None,
-                                interpret: bool = True):
+                                interpret: Optional[bool] = None):
     """Page-granular block-table-native sparse decode attention (B,H,DV):
     selected indices sharing a logical page move as ONE whole-page DMA
     descriptor (≤ min(K, MP) descriptors per query vs exactly K row-sized
@@ -157,7 +167,7 @@ def paged_sparse_decode_attn_pg(q: jnp.ndarray, k_pages: jnp.ndarray,
     page-vs-token guarantee lives on the XLA serving path)."""
     return paged_sparse_decode_attn_pg_pallas(q, k_pages, v_pages, table,
                                               idx, scale=scale,
-                                              interpret=interpret)
+                                              interpret=_interpret(interpret))
 
 
 @partial(jax.jit, static_argnames=("scale", "window", "interpret"))
@@ -166,7 +176,7 @@ def paged_dense_decode_attn(q: jnp.ndarray, k_pages: jnp.ndarray,
                             lengths: jnp.ndarray, *,
                             scale: Optional[float] = None,
                             window: Optional[int] = None,
-                            interpret: bool = True):
+                            interpret: Optional[bool] = None):
     """Fused paged DENSE decode attention (B,H,DV) — the pre-DSA-gate
     fallback's hot-spot form: the full causal extent is attended straight
     off the page pools (grid (B, MP), one whole-page DMA per step), never
@@ -174,7 +184,8 @@ def paged_dense_decode_attn(q: jnp.ndarray, k_pages: jnp.ndarray,
     masking happens on global positions inside the kernel."""
     return paged_dense_decode_attn_pallas(q, k_pages, v_pages, table,
                                           lengths, scale=scale,
-                                          window=window, interpret=interpret)
+                                          window=window,
+                                          interpret=_interpret(interpret))
 
 
 @partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -182,7 +193,7 @@ def paged_sparse_decode_attn_mq(q: jnp.ndarray, k_pages: jnp.ndarray,
                                 v_pages: jnp.ndarray, table: jnp.ndarray,
                                 idx: jnp.ndarray, *,
                                 scale: Optional[float] = None,
-                                interpret: bool = True):
+                                interpret: Optional[bool] = None):
     """Multi-query-row block-table-native sparse decode attention
     (B,Q,H,DV) — the speculative verify tick's attention hot spot: the
     d+1 draft positions of each slot gather their own Top-K rows against
@@ -190,69 +201,41 @@ def paged_sparse_decode_attn_mq(q: jnp.ndarray, k_pages: jnp.ndarray,
     addressing and masking are the single-row kernel's verbatim)."""
     return paged_sparse_decode_attn_mq_pallas(q, k_pages, v_pages, table,
                                               idx, scale=scale,
-                                              interpret=interpret)
+                                              interpret=_interpret(interpret))
 
 
-@partial(jax.jit, static_argnames=("k", "chunk", "interpret"))
+@partial(jax.jit, static_argnames=("k", "interpret"))
 def paged_indexer_topk_mq(q: jnp.ndarray, k_pages: jnp.ndarray,
                           w: jnp.ndarray, table: jnp.ndarray,
                           prev_idx: jnp.ndarray, k: int, *,
                           lengths: jnp.ndarray,
-                          chunk: int = DEFAULT_CHUNK,
-                          interpret: bool = True):
+                          interpret: Optional[bool] = None):
     """Fused paged indexer + GVR Top-K over Q query rows per slot, with
     the verify tick's causally-extended feedback threaded INSIDE the
     launch: row 0 warms from `prev_idx` (the previous tick's Top-K,
-    exactly K entries), every later row from the row before it, via a
-    VMEM scratch — the temporal signal never round-trips HBM between
-    draft positions. `lengths` is (B, Q): row q's causal extent. The
-    table is padded here with -1 columns to meet the GVR chunk lattice,
-    as in `paged_indexer_topk`.
+    exactly K entries), every later row from the row before it — the
+    temporal signal never round-trips HBM between draft positions.
+    `lengths` is (B, Q): row q's causal extent.
 
     Returns (values (B,Q,K), indices (B,Q,K) logical, stats (B,Q,8)).
     """
-    b, qn = q.shape[:2]
-    page_size = k_pages.shape[1]
-    mp = table.shape[1]
-    n = mp * page_size
-    chunk = max(32, (min(chunk, n) // 32) * 32)
-    mp_pad = mp
-    while (mp_pad * page_size) % chunk:
-        mp_pad += 1
-    if mp_pad != mp:
-        table = jnp.pad(table, ((0, 0), (0, mp_pad - mp)), constant_values=-1)
     return paged_indexer_topk_mq_pallas(q, k_pages, w, table, prev_idx, k,
-                                        lengths=lengths, chunk=chunk,
-                                        interpret=interpret)
+                                        lengths=lengths,
+                                        interpret=_interpret(interpret))
 
 
-@partial(jax.jit, static_argnames=("k", "chunk", "interpret"))
+@partial(jax.jit, static_argnames=("k", "interpret"))
 def paged_indexer_topk(q: jnp.ndarray, k_pages: jnp.ndarray, w: jnp.ndarray,
                        table: jnp.ndarray, prev_idx: jnp.ndarray, k: int,
                        *, lengths: Optional[jnp.ndarray] = None,
-                       chunk: int = DEFAULT_CHUNK,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """Fused paged indexer scoring + GVR Top-K over a block table.
 
     The kv chunk is the logical page: the kernel scores physical pages
     addressed by the scalar-prefetched table, so neither the logical
     indexer-K view nor the score row ever touches HBM. Indices in and out
-    are LOGICAL token positions. The table is padded here with -1 columns
-    (scored as the sentinel) so MP·page_size meets the GVR chunk lattice.
+    are LOGICAL token positions.
     """
-    b = q.shape[0]
-    page_size = k_pages.shape[1]
-    mp = table.shape[1]
-    n = mp * page_size
-    # the GVR compaction needs chunk % 32 == 0 and n % chunk == 0
-    chunk = max(32, (min(chunk, n) // 32) * 32)
-    mp_pad = mp
-    while (mp_pad * page_size) % chunk:
-        mp_pad += 1
-    if mp_pad != mp:
-        table = jnp.pad(table, ((0, 0), (0, mp_pad - mp)), constant_values=-1)
-    if lengths is None:
-        lengths = jnp.full((b,), n, jnp.int32)
     return paged_indexer_topk_pallas(q, k_pages, w, table, prev_idx, k,
-                                     lengths=lengths, chunk=chunk,
-                                     interpret=interpret)
+                                     lengths=lengths,
+                                     interpret=_interpret(interpret))

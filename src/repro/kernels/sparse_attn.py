@@ -42,6 +42,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _gqa_expand(h, kvh):
+    """(H, KVH) one-hot: head h reads KV head h // (H / KVH)."""
+    head = jax.lax.broadcasted_iota(jnp.int32, (h, kvh), 0)
+    kv = jax.lax.broadcasted_iota(jnp.int32, (h, kvh), 1)
+    return (kv == head // (h // kvh)).astype(jnp.float32)
+
+
+def _rows_per_head(expand, rows):
+    """(KVH, D) -> (H, D): each head's KV row, by an exact one-hot matmul
+    (one non-zero term per output), in 2-D forms Mosaic lowers."""
+    return jax.lax.dot(expand, rows, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
 def _attn_kernel(idx_ref, q_ref, k_ref, v_ref, o_ref,
                  m_scr, l_scr, acc_scr, *, nsteps, kk, scale, h, kvh, dv):
     b = pl.program_id(0)
@@ -64,11 +78,13 @@ def _attn_kernel(idx_ref, q_ref, k_ref, v_ref, o_ref,
     logits = jnp.einsum("khd,tkd->kht", qg, kb).reshape(h, gb) * scale
     # mask padded entries (idx < 0) — positions beyond the valid count
     col = jax.lax.broadcasted_iota(jnp.int32, (1, gb), 1)[0] + j * gb
-    valid = jnp.zeros((gb,), bool)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, gb), 1)
+    valid = jnp.zeros((1, gb), jnp.int32)
     for t in range(gb):                                   # gb is small & static
-        valid = valid.at[t].set(idx_ref[b, jnp.minimum(col[t], kk - 1)] >= 0)
-    valid = valid & (col < kk)
-    logits = jnp.where(valid[None, :], logits, -jnp.inf)
+        ok = idx_ref[b, jnp.minimum(j * gb + t, kk - 1)] >= 0
+        valid = jnp.where(lane == t, ok.astype(jnp.int32), valid)
+    valid = (valid > 0) & (col[None, :] < kk)
+    logits = jnp.where(valid, logits, -jnp.inf)
 
     m_prev = m_scr[...]                                   # (H, 1)
     l_prev = l_scr[...]
@@ -94,7 +110,7 @@ def sparse_decode_attn_pallas(q: jnp.ndarray, kcache: jnp.ndarray,
                               *, scale: Optional[float] = None,
                               gather_block: int = 8,
                               gather_mode: str = "kernel",
-                              interpret: bool = True):
+                              interpret: bool = False):
     """q: (B,H,D); k/vcache: (B,N,KVH,D[v]); idx: (B,K) int32, -1-padded.
 
     gather_mode:
@@ -189,8 +205,9 @@ def _paged_attn_kernel(table_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,
     valid = (li >= 0) & (table_ref[b, li_safe // page_size] >= 0)
 
     # logits[h] = scale * q[h] · kb[h // g]  — one gathered token
-    qg = q.reshape(kvh, g, -1)
-    logits = jnp.einsum("khd,kd->kh", qg, kb).reshape(h, 1) * scale
+    expand = _gqa_expand(h, kvh)
+    logits = jnp.sum(q * _rows_per_head(expand, kb), axis=1,
+                     keepdims=True) * scale
     logits = jnp.where(valid, logits, -jnp.inf)
 
     m_prev = m_scr[...]                                   # (H, 1)
@@ -201,7 +218,7 @@ def _paged_attn_kernel(table_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,
     p = jnp.exp(jnp.where(jnp.isfinite(logits), logits - m_safe, -jnp.inf))
     p = jnp.where(jnp.isfinite(logits), p, 0.0)           # (H, 1)
     l_scr[...] = l_prev * alpha + p
-    pv = jnp.einsum("kg,kd->kgd", p.reshape(kvh, g), vb).reshape(h, dv)
+    pv = p * _rows_per_head(expand, vb)                   # (H, DV)
     acc_scr[...] = acc_scr[...] * alpha + pv
     m_scr[...] = m_new
 
@@ -215,7 +232,7 @@ def paged_sparse_decode_attn_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                                     v_pages: jnp.ndarray, table: jnp.ndarray,
                                     idx: jnp.ndarray, *,
                                     scale: Optional[float] = None,
-                                    interpret: bool = True):
+                                    interpret: bool = False):
     """q: (B,H,D); k/v_pages: (P, page_size, KVH, D[v]) global page pools;
     table: (B, MP) int32 block table (-1 = unmapped); idx: (B,K) int32
     LOGICAL Top-K indices, -1-padded.
@@ -303,8 +320,9 @@ def _paged_attn_mq_kernel(table_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,
     li_safe = jnp.clip(li, 0, n_logical - 1)
     valid = (li >= 0) & (table_ref[b, li_safe // page_size] >= 0)
 
-    qg = q.reshape(kvh, g, -1)
-    logits = jnp.einsum("khd,kd->kh", qg, kb).reshape(h, 1) * scale
+    expand = _gqa_expand(h, kvh)
+    logits = jnp.sum(q * _rows_per_head(expand, kb), axis=1,
+                     keepdims=True) * scale
     logits = jnp.where(valid, logits, -jnp.inf)
 
     m_prev = m_scr[...]                                   # (H, 1)
@@ -315,7 +333,7 @@ def _paged_attn_mq_kernel(table_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,
     p = jnp.exp(jnp.where(jnp.isfinite(logits), logits - m_safe, -jnp.inf))
     p = jnp.where(jnp.isfinite(logits), p, 0.0)           # (H, 1)
     l_scr[...] = l_prev * alpha + p
-    pv = jnp.einsum("kg,kd->kgd", p.reshape(kvh, g), vb).reshape(h, dv)
+    pv = p * _rows_per_head(expand, vb)                   # (H, DV)
     acc_scr[...] = acc_scr[...] * alpha + pv
     m_scr[...] = m_new
 
@@ -329,7 +347,7 @@ def paged_sparse_decode_attn_mq_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                                        v_pages: jnp.ndarray,
                                        table: jnp.ndarray, idx: jnp.ndarray,
                                        *, scale: Optional[float] = None,
-                                       interpret: bool = True):
+                                       interpret: bool = False):
     """q: (B, Q, H, D) — Q query rows per slot (the verify tick's d+1 draft
     positions); k/v_pages: (P, page_size, KVH, D[v]) global page pools;
     table: (B, MP) int32 block table shared by all of a slot's query rows;
@@ -407,7 +425,7 @@ def _paged_attn_pg_kernel(tpad_ref, up_ref, q_ref, k_ref, v_ref, rv_ref,
     q = q_ref[0].astype(jnp.float32)                     # (H, D)
     kb = k_ref[0].astype(jnp.float32)                    # (page_size, KVH, D)
     vb = v_ref[0].astype(jnp.float32)                    # (page_size, KVH, DV)
-    rv = rv_ref[0, 0]                                    # (page_size,) int32
+    rv = rv_ref[0]                                       # (1, page_size) int32
 
     # one whole gathered page per step: rows the Top-K did NOT select (and
     # every row of sentinel/unmapped pages) arrive in VMEM but are masked
@@ -415,7 +433,7 @@ def _paged_attn_pg_kernel(tpad_ref, up_ref, q_ref, k_ref, v_ref, rv_ref,
     # page-granular DMA contract
     qg = q.reshape(kvh, g, -1)
     logits = jnp.einsum("khd,tkd->kht", qg, kb).reshape(h, page_size) * scale
-    logits = jnp.where((rv > 0)[None, :], logits, -jnp.inf)
+    logits = jnp.where(rv > 0, logits, -jnp.inf)
 
     m_prev = m_scr[...]                                   # (H, 1)
     l_prev = l_scr[...]
@@ -440,7 +458,7 @@ def paged_sparse_decode_attn_pg_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                                        v_pages: jnp.ndarray,
                                        table: jnp.ndarray, idx: jnp.ndarray,
                                        *, scale: Optional[float] = None,
-                                       interpret: bool = True):
+                                       interpret: bool = False):
     """Page-granular form of `paged_sparse_decode_attn_pallas`: same
     arguments and masking semantics, coarser DMA. The wrapper builds the
     per-slot DISTINCT-page descriptor list (`sparse.dsa.distinct_pages` —
@@ -505,7 +523,8 @@ def paged_sparse_decode_attn_pg_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                          lambda i, j, t, u: _page(i, j, t, u) + (0, 0, 0)),
             pl.BlockSpec((1, page_size, kvh, dv),
                          lambda i, j, t, u: _page(i, j, t, u) + (0, 0, 0)),
-            pl.BlockSpec((1, 1, page_size), lambda i, j, t, u: (i, j, 0)),
+            pl.BlockSpec((1, None, 1, page_size),
+                         lambda i, j, t, u: (i, j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, h, dv), lambda i, j, t, u: (i, 0, 0)),
         scratch_shapes=[
@@ -521,7 +540,7 @@ def paged_sparse_decode_attn_pg_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
     out_shape = jax.ShapeDtypeStruct((b, h, dv), jnp.float32)
     return pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
                           interpret=interpret)(tpad, up, q, k_pages, v_pages,
-                                               row_valid)
+                                               row_valid[:, :, None])
 
 
 # --------------------------------------------------------------------------
@@ -584,7 +603,7 @@ def paged_dense_decode_attn_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                                    lengths: jnp.ndarray, *,
                                    scale: Optional[float] = None,
                                    window: Optional[int] = None,
-                                   interpret: bool = True):
+                                   interpret: bool = False):
     """Fused paged DENSE decode attention (the pre-DSA-gate fallback): one
     query per slot attends its full causal extent straight off the page
     pools. q: (B, H, D); k/v_pages: (P, page_size, KVH, D[v]); table:

@@ -9,25 +9,20 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """`axis_types` only where the jax version has it (added after 0.4.x);
-    older versions default to auto sharding semantics anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int):
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests / small runs)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=_auto(len(axes)))
 
 
 def make_seq_mesh(seq_shards: int):
@@ -41,4 +36,4 @@ def make_seq_mesh(seq_shards: int):
             f"available device(s) — on CPU hosts force more with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N before "
             f"the first jax call")
-    return jax.make_mesh((seq_shards,), ("seq",), **_axis_type_kwargs(1))
+    return jax.make_mesh((seq_shards,), ("seq",), axis_types=_auto(1))

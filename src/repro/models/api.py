@@ -137,10 +137,10 @@ class Model:
     # ---- sequence-sharded paged decode (SP-GVR serving path) ------------
     def init_sp_paged_decode_state(self, batch, max_len, *,
                                    num_pages_per_shard, page_size,
-                                   seq_shards, dtype=None):
+                                   seq_shards, dtype=None, mesh=None):
         """Sequence-sharded paged layout: per-shard page pools (leading
-        shard axis) + shard-local block tables. Raises for families
-        without the sharded decode path."""
+        shard axis) + shard-local block tables, placed on `mesh` when
+        given. Raises for families without the sharded decode path."""
         fn = getattr(self.mod, "init_sp_paged_decode_state", None)
         if fn is None:
             raise NotImplementedError(
@@ -148,7 +148,8 @@ class Model:
                 f"paged decode state")
         return fn(self.cfg, batch, max_len,
                   num_pages_per_shard=num_pages_per_shard,
-                  page_size=page_size, seq_shards=seq_shards, dtype=dtype)
+                  page_size=page_size, seq_shards=seq_shards, dtype=dtype,
+                  mesh=mesh)
 
     def sp_paged_state_batch_axes(self) -> Optional[Dict[str, int]]:
         """Slot-axis map of the sequence-sharded paged decode state

@@ -305,8 +305,7 @@ def moe_mlp_ep(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         tok_spec = P(token_axes, None, None)
     else:
         tok_spec = P(None, None, None)   # tiny decode batch: replicate tokens
-    from repro.parallel.sharding import shard_map
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, P(), P(expert_axis), P(expert_axis), P(expert_axis)),
         out_specs=tok_spec, check_vma=False,

@@ -23,7 +23,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.parallel.sharding import MeshRules, constrain
 from repro.sparse import dsa as dsa_mod
@@ -491,8 +491,8 @@ def serve_step(params, state, tokens, cfg: ModelConfig, *, mesh=None,
 
 def init_sp_paged_decode_state(cfg: ModelConfig, batch: int, max_len: int, *,
                                num_pages_per_shard: int, page_size: int,
-                               seq_shards: int,
-                               dtype=None) -> Dict[str, jnp.ndarray]:
+                               seq_shards: int, dtype=None, mesh=None
+                               ) -> Dict[str, jnp.ndarray]:
     """Sequence-sharded variant of `init_paged_decode_state`.
 
     Page pools gain a leading shard axis — (L, S, PL+1, page_size, ...) —
@@ -500,6 +500,10 @@ def init_sp_paged_decode_state(cfg: ModelConfig, batch: int, max_len: int, *,
     shard's extra final page is its own write sink. `max_len` must divide
     into `seq_shards` page-aligned spans so logical-page ownership is
     whole-page. The block table holds shard-local physical ids.
+
+    With `mesh`, every leaf is created in place: each pool split over the
+    mesh's "seq" axis (device s allocates only shard s), the rest
+    replicated — no device ever holds the whole pool.
     """
     dtype = dtype or jnp.dtype(cfg.dtype)
     if max_len % (page_size * seq_shards) != 0:
@@ -509,24 +513,30 @@ def init_sp_paged_decode_state(cfg: ModelConfig, batch: int, max_len: int, *,
             f"must be page-aligned for whole-page ownership")
     l, hd = cfg.n_layers, cfg.hd
     mp = max_len // page_size
+    pool_at = rep_at = None
+    if mesh is not None:
+        pool_at = NamedSharding(mesh, P(None, "seq"))
+        rep_at = NamedSharding(mesh, P())
+    pool = (l, seq_shards, num_pages_per_shard + 1, page_size)
     state = {
-        "k_pages": jnp.zeros((l, seq_shards, num_pages_per_shard + 1,
-                              page_size, cfg.n_kv_heads, hd), dtype),
-        "v_pages": jnp.zeros((l, seq_shards, num_pages_per_shard + 1,
-                              page_size, cfg.n_kv_heads, hd), dtype),
-        "page_table": jnp.full((batch, mp), -1, jnp.int32),
-        "length": jnp.zeros((batch,), jnp.int32),
+        "k_pages": jnp.zeros(pool + (cfg.n_kv_heads, hd), dtype,
+                             device=pool_at),
+        "v_pages": jnp.zeros(pool + (cfg.n_kv_heads, hd), dtype,
+                             device=pool_at),
+        "page_table": jnp.full((batch, mp), -1, jnp.int32, device=rep_at),
+        "length": jnp.zeros((batch,), jnp.int32, device=rep_at),
     }
     if cfg.dsa.enabled:
         from repro.core.temporal import seed_slot_idx
-        state["idx_k_pages"] = jnp.zeros(
-            (l, seq_shards, num_pages_per_shard + 1, page_size,
-             cfg.dsa.indexer_dim), dtype)
+        state["idx_k_pages"] = jnp.zeros(pool + (cfg.dsa.indexer_dim,),
+                                         dtype, device=pool_at)
         kk = min(cfg.dsa.k, max_len)
         base = seed_slot_idx(kk, max_len)
         state["prev_topk"] = jnp.broadcast_to(base[None, None], (l, batch, kk))
-        state["topk_valid"] = jnp.zeros((l, batch), bool)
-        state["sel_gvr"] = jnp.zeros((l, batch), bool)
+        state["topk_valid"] = jnp.zeros((l, batch), bool, device=rep_at)
+        state["sel_gvr"] = jnp.zeros((l, batch), bool, device=rep_at)
+        if rep_at is not None:
+            state["prev_topk"] = jax.device_put(state["prev_topk"], rep_at)
     return state
 
 
@@ -569,14 +579,13 @@ def _sp_paged_token_body(params, state, tokens, mwp, cfg: ModelConfig, *,
     invocation. Returns (logits, new_state) with the shard axis restored
     on the pool leaves."""
     from repro.sparse import sp_dsa as sp_dsa_mod
-    from repro.parallel.sharding import axis_size
 
     b = tokens.shape[0]
     hd = cfg.hd
     ppl = state["k_pages"].shape[2] - 1                  # pages per shard
     page_size = state["k_pages"].shape[3]
     mp = state["page_table"].shape[1]
-    num_shards = axis_size(seq_axis)
+    num_shards = jax.lax.axis_size(seq_axis)
     mp_local = mp // num_shards
     n_local = mp_local * page_size
     kk = state["prev_topk"].shape[-1]
@@ -703,8 +712,7 @@ def serve_step_sp_paged(params, state, tokens, cfg: ModelConfig, *, mesh,
     st_spec = {key: (pool_spec if key in ("k_pages", "v_pages", "idx_k_pages")
                      else P()) for key in state}
     param_spec = jax.tree.map(lambda _: P(), params)
-    from repro.parallel.sharding import shard_map
-    fn = shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                    in_specs=(param_spec, st_spec, P(), P()),
                    out_specs=(P(), st_spec), check_vma=False)
     return fn(params, state, tokens, mwp)
@@ -1342,7 +1350,6 @@ def _sp_paged_verify_mq_body(params, state, tokens, draft_len, max_accept,
     per-shard end state), via `_spec_accept_rollback`.
     """
     from repro.sparse import sp_dsa as sp_dsa_mod
-    from repro.parallel.sharding import axis_size
 
     b, d1 = tokens.shape
     hd = cfg.hd
@@ -1351,7 +1358,7 @@ def _sp_paged_verify_mq_body(params, state, tokens, draft_len, max_accept,
     ppl = state["k_pages"].shape[2] - 1                  # pages per shard
     page_size = state["k_pages"].shape[3]
     mp = state["page_table"].shape[1]
-    num_shards = axis_size(seq_axis)
+    num_shards = jax.lax.axis_size(seq_axis)
     mp_local = mp // num_shards
     n_local = mp_local * page_size
     kk = state["prev_topk"].shape[-1]
@@ -1516,8 +1523,7 @@ def serve_step_sp_spec_paged(params, state, tokens, cfg: ModelConfig, *,
     st_spec = {key: (pool_spec if key in ("k_pages", "v_pages", "idx_k_pages")
                      else P()) for key in state}
     param_spec = jax.tree.map(lambda _: P(), params)
-    from repro.parallel.sharding import shard_map
-    fn = shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                    in_specs=(param_spec, st_spec, P(), P(), P(), P()),
                    out_specs=(P(), P(), P(), P(), st_spec), check_vma=False)
     return fn(params, state, tokens, jnp.asarray(draft_len, jnp.int32),

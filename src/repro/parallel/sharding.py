@@ -17,39 +17,6 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable shard_map: `jax.shard_map` where available (newer
-    jax), else `jax.experimental.shard_map` (whose `check_rep` is the old
-    name of `check_vma`)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
-
-
-def abstract_mesh(shape, axes):
-    """Version-portable AbstractMesh: newer jax takes (shape, axis_names),
-    older takes a tuple of (name, size) pairs."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
-
-
-def axis_size(axis_name) -> int:
-    """Version-portable static mesh-axis size inside shard_map:
-    `jax.lax.axis_size` where available, else `lax.psum(1, axis)` (which
-    old jax constant-folds to a Python int against the axis env)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 # default logical rules; "batch" spans both pod and data for multi-pod DP
 DEFAULT_RULES = {
     "batch": ("pod", "data"),

@@ -15,9 +15,11 @@ masked out by the engine's merge (their rows still flow through the jitted
 step — shapes stay static, so the step **never recompiles** — but their
 state is discarded; score rows beyond a slot's `length` are already dead
 via the `NEG_SENTINEL` masking convention in `core.gvr`/`sparse.dsa`).
-Freed slots are refilled mid-stream by **chunked prefill**: the admitted
-request's prompt streams through batch-1 `serve_step` chunks into its slot
-while the other slots keep decoding — no global pause.
+Freed slots are refilled mid-stream by **chunked prefill**: each tick
+streams one chunk of every admitted request's prompt, one token per slot
+per pool-wide step (the same jitted step decode uses, with only the
+prefilling rows active), while the other slots keep decoding — no global
+pause.
 
 ## Mapping to the paper's per-step Top-K feedback buffer
 

@@ -6,8 +6,9 @@ decode state). Per tick it:
   1. admits queued requests into freed slots (scheduler policy), resetting
      the slot's GVR feedback through the `FeedbackPool`;
   2. streams one `prefill_chunk` of each PREFILL slot's prompt into the
-     pool via a batch-1 jitted chunk (other slots are untouched — they keep
-     decoding the same tick);
+     pool: one pool-wide step per chunk position in which every PREFILL
+     slot takes its next prompt token (other slots are untouched — they
+     keep decoding the same tick);
   3. runs ONE jitted `serve_step` over the whole pool for the DECODE slots,
      samples their next tokens (greedy by default; per-request temperature/
      top-p with a seeded PRNG key otherwise), and merges the new state back
@@ -98,6 +99,7 @@ from typing import Dict, List, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.models.transformer import PAGED_NEVER_WRITE
 
@@ -345,9 +347,15 @@ class DecodeEngine:
                 num_slots=self.num_slots, max_len=self.max_len,
                 page_size=int(page_size), num_pages_per_shard=per_shard,
                 seq_shards=self.seq_shards, prefix_caching=prefix_caching)
+            # pools are created split over the mesh (no device ever holds
+            # the whole pool); weights are replicated once here, not moved
+            # to every device on every step
             self.state = model.init_sp_paged_decode_state(
                 self.num_slots, self.max_len, num_pages_per_shard=per_shard,
-                page_size=int(page_size), seq_shards=self.seq_shards)
+                page_size=int(page_size), seq_shards=self.seq_shards,
+                mesh=self.mesh)
+            self.params = jax.device_put(
+                params, NamedSharding(self.mesh, PartitionSpec()))
         elif kv_layout == "paged":
             axes = model.paged_state_batch_axes()
             if axes is None:
@@ -419,9 +427,12 @@ class DecodeEngine:
             # cold-row fallback is always radix (never the tiny-n exact path)
             self._cold_method = "radix"
 
-        self._tick_fn = jax.jit(self._tick_impl)
-        self._prefill_fn = jax.jit(self._prefill_impl)
-        self._spec_fn = (jax.jit(self._tick_spec_impl)
+        # one compiled pool-wide step serves decode and prefill. The pool
+        # state is donated: each call updates the KV pools in place instead
+        # of holding a second copy (host code only reads the state a call
+        # returns)
+        self._tick_fn = jax.jit(self._tick_impl, donate_argnums=(1,))
+        self._spec_fn = (jax.jit(self._tick_spec_impl, donate_argnums=(1,))
                          if self.spec_depth > 0 else None)
 
     # ---- jitted kernels -------------------------------------------------
@@ -455,16 +466,23 @@ class DecodeEngine:
             merged[key] = jnp.where(active.reshape(shape), arr, state[key])
         return merged
 
-    def _tick_impl(self, params, state, tokens, active):
-        """One pool-wide decode step; inactive rows keep their old state.
-        Paged layout: inactive rows additionally redirect their cache write
-        to the sink page (pool-global page leaves can't be row-merged)."""
-        mwp = (jnp.where(active, jnp.int32(0), jnp.int32(PAGED_NEVER_WRITE))
+    def _tick_impl(self, params, state, tokens, active, min_write_pos):
+        """One pool-wide step, for decode and prefill alike: active rows
+        take one token each, inactive rows keep their old state. Paged
+        layout: inactive rows additionally redirect their cache write to
+        the sink page (pool-global page leaves can't be row-merged), and
+        active rows skip it below `min_write_pos` — the shared-prefix
+        replay must not touch pages it shares. Returns the merged state,
+        the argmax tokens, the logits and which active rows the GVR path
+        served."""
+        mwp = (jnp.where(active, min_write_pos, jnp.int32(PAGED_NEVER_WRITE))
                if self.kv is not None else None)
         logits, new_state = self._serve_step(params, state, tokens, mwp)
         merged = self._merge_active(new_state, state, active)
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return merged, next_tok, logits
+        gvr = (new_state["sel_gvr"][0] & active if "sel_gvr" in new_state
+               else jnp.zeros_like(active))
+        return merged, next_tok, logits, gvr
 
     def _tick_spec_impl(self, params, state, tokens, active, draft_len,
                         max_accept):
@@ -490,55 +508,6 @@ class DecodeEngine:
         out_tokens, accept_len, logits_all, sel_pos, new_state = out
         merged = self._merge_active(new_state, state, active)
         return merged, out_tokens, accept_len, logits_all, sel_pos
-
-    def _slice_slot(self, state, slot):
-        """Batch-1 view of one slot; pool-global leaves pass through whole
-        (a batch-1 paged step writes straight into the global page pool)."""
-        out = {}
-        for k, v in state.items():
-            ax = self._axes.get(k)
-            out[k] = (v if ax is None
-                      else jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=ax))
-        return out
-
-    def _write_slot(self, state, sub, slot):
-        out = {}
-        for k in state:
-            ax = self._axes.get(k)
-            out[k] = (sub[k] if ax is None
-                      else jax.lax.dynamic_update_slice_in_dim(
-                          state[k], sub[k], slot, axis=ax))
-        return out
-
-    def _prefill_impl(self, params, state, tokens, slot, count,
-                      min_write_pos=None):
-        """Stream `count` prompt tokens (of a fixed-size padded chunk) into
-        one slot, leaving every other slot untouched. Returns the updated
-        pool state, the next token implied by the last real prompt token,
-        and the per-token GVR-path mask for the method log. Paged layout:
-        positions below `min_write_pos` skip their cache write — the
-        shared-prefix replay must not touch pages it shares."""
-        sub = self._slice_slot(state, slot)
-        vocab = self.cfg.vocab
-        logits0 = jnp.zeros((1, vocab), jnp.float32)
-        mwp = (min_write_pos[None] if min_write_pos is not None else None)
-
-        def body(carry, tok):
-            st, last_logits, i = carry
-            logits, st2 = self._serve_step(params, st, tok[None], mwp)
-            take = i < count
-            st = jax.tree.map(lambda new, old: jnp.where(take, new, old),
-                              st2, st)
-            last_logits = jnp.where(take, logits, last_logits)
-            gvr = (st2["sel_gvr"][0, 0] & take) if "sel_gvr" in st2 else \
-                jnp.asarray(False)
-            return (st, last_logits, i + 1), gvr
-
-        (sub, last_logits, _), gvr_steps = jax.lax.scan(
-            body, (sub, logits0, jnp.int32(0)), tokens)
-        state = self._write_slot(state, sub, slot)
-        next_tok = jnp.argmax(last_logits[0]).astype(jnp.int32)
-        return state, next_tok, gvr_steps, last_logits
 
     # ---- host-side lifecycle --------------------------------------------
 
@@ -581,7 +550,14 @@ class DecodeEngine:
 
     def _push_page_table(self) -> None:
         if self.kv is not None and self.kv.dirty:
-            self.state["page_table"] = jnp.asarray(self.kv.table_array())
+            table = jnp.asarray(self.kv.table_array())
+            if self.seq_shards > 1:
+                # keep it replicated over the mesh, as created: a table on
+                # one device is another signature, and the step would
+                # compile again
+                table = jax.device_put(table,
+                                       NamedSharding(self.mesh, PartitionSpec()))
+            self.state["page_table"] = table
             self.kv.dirty = False
 
     def _copy_page(self, cow) -> None:
@@ -740,40 +716,54 @@ class DecodeEngine:
             self.slots[slot] = req
 
     def _prefill_tick(self) -> None:
-        for req in list(self.slots):
-            if req is None or req.phase != PREFILL:
-                continue
-            chunk = req.prompt[req.prefill_pos:req.prefill_pos + self.prefill_chunk]
-            count = len(chunk)
-            padded = np.zeros((self.prefill_chunk,), np.int32)
-            padded[:count] = chunk
-            if self.kv is not None:
-                # prompt pages were all mapped at admission; only the write
-                # mask (shared-prefix replay protection) varies per request
-                self._push_page_table()
-                self.state, next_tok, gvr_steps, last_logits = self._prefill_fn(
-                    self.params, self.state, jnp.asarray(padded),
-                    req.slot, count, jnp.int32(req._materialized))
-            else:
-                self.state, next_tok, gvr_steps, last_logits = self._prefill_fn(
-                    self.params, self.state, jnp.asarray(padded),
-                    req.slot, count)
-            # the tick's dispatch decision is made at tick entry — log the
-            # path that served the chunk's first token
-            self._log(req, self._method_name(bool(np.asarray(gvr_steps)[0])))
-            req.prefill_pos += count
-            self.prefill_tokens += count
+        """Stream one `prefill_chunk` of every PREFILL slot's prompt: one
+        pool-wide step per chunk position, each PREFILL slot taking its
+        next prompt token (DECODE slots sit these steps out)."""
+        chunks = {s: r.prompt[r.prefill_pos:r.prefill_pos + self.prefill_chunk]
+                  for s, r in enumerate(self.slots)
+                  if r is not None and r.phase == PREFILL}
+        if not chunks:
+            return
+        self._push_page_table()
+        mwp = np.zeros((self.num_slots,), np.int32)
+        for s in chunks:
+            mwp[s] = self.slots[s]._materialized
+        mwp = jnp.asarray(mwp)
+        last = {}
+        for i in range(max(len(c) for c in chunks.values())):
+            tokens = np.zeros((self.num_slots,), np.int32)
+            active = np.zeros((self.num_slots,), bool)
+            for s, c in chunks.items():
+                if i < len(c):
+                    tokens[s], active[s] = c[i], True
+            self.state, next_tok, logits, gvr = self._tick_fn(
+                self.params, self.state, jnp.asarray(tokens),
+                jnp.asarray(active), mwp)
+            if i == 0:
+                # the tick's dispatch decision is made at tick entry — log
+                # the path that served the chunk's first token
+                first_gvr = gvr
+            for s, c in chunks.items():
+                if i == len(c) - 1:
+                    last[s] = (next_tok, logits)
+        first_gvr = np.asarray(first_gvr)
+        for s, c in chunks.items():
+            req = self.slots[s]
+            self._log(req, self._method_name(bool(first_gvr[s])))
+            req.prefill_pos += len(c)
+            self.prefill_tokens += len(c)
             if req.prefill_pos >= len(req.prompt):
                 if self.kv is not None:
-                    self.kv.commit_prefix(req.slot, req.prompt)
+                    self.kv.commit_prefix(s, req.prompt)
                 # the last prompt token's logits yield the first generation
+                next_tok, logits = last[s]
                 req.phase = DECODE
-                req.generated.append(self._next_token(req, int(next_tok),
-                                                      last_logits[0]))
+                req.generated.append(self._next_token(
+                    req, int(next_tok[s]), logits[s]))
                 if self.record_logits:
-                    req.logits_log.append(np.asarray(last_logits[0]))
+                    req.logits_log.append(np.asarray(logits[s]))
                 self.decoded_tokens += 1
-                self._maybe_finish(req.slot)
+                self._maybe_finish(s)
 
     # ---- speculative decode tick (serve.spec) ---------------------------
 
@@ -910,12 +900,11 @@ class DecodeEngine:
         for s, req in enumerate(self.slots):
             if active[s]:
                 tokens[s] = req.generated[-1]
-        self.state, next_tok, _logits = self._tick_fn(
-            self.params, self.state, jnp.asarray(tokens), jnp.asarray(active))
+        self.state, next_tok, _logits, sel_gvr = self._tick_fn(
+            self.params, self.state, jnp.asarray(tokens), jnp.asarray(active),
+            jnp.zeros((self.num_slots,), jnp.int32))
         next_tok = np.asarray(next_tok)
-        sel_gvr = (np.asarray(self.state["sel_gvr"][0])
-                   if "sel_gvr" in self.state
-                   else np.zeros((self.num_slots,), bool))
+        sel_gvr = np.asarray(sel_gvr)
         for s, req in enumerate(self.slots):
             if not active[s]:
                 continue

@@ -115,10 +115,11 @@ def dsa_sparse_attention(q: jnp.ndarray, kcache: jnp.ndarray, vcache: jnp.ndarra
     kcache = constrain(kcache, rules, "batch", None, None, None)
     vcache = constrain(vcache, rules, "batch", None, None, None)
     idx_safe = jnp.clip(topk_idx, 0, kcache.shape[1] - 1)
-    kg = jnp.take_along_axis(
-        kcache, idx_safe[:, :, None, None].repeat(kvh, 2).repeat(hd, 3), axis=1)
-    vg = jnp.take_along_axis(
-        vcache, idx_safe[:, :, None, None].repeat(kvh, 2).repeat(hd, 3), axis=1)
+    # whole (KVH, HD) rows per index: a per-element index array would lower
+    # to an element-wise gather, which on a TPU costs far more than the
+    # K-row DMA this is
+    rows = jax.vmap(lambda c, i: c[i])
+    kg, vg = rows(kcache, idx_safe), rows(vcache, idx_safe)
     # keep the gather batch-parallel: resharding (for TP heads) must happen on
     # the small (B,K) gathered rows, never on the (B,N) cache — otherwise the
     # partitioner all-gathers the entire cache per step.

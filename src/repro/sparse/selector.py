@@ -41,6 +41,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.gvr import extract_topk, gvr_threshold
@@ -108,8 +109,7 @@ def select_topk(scores: jnp.ndarray, k: int, *,
                     g = jnp.zeros((s_.shape[0],), bool)
                 return r.indices, r.values, it, g
 
-            from repro.parallel.sharding import shard_map as _shard_map
-            idx, vals, iters, gvr_rows = _shard_map(
+            idx, vals, iters, gvr_rows = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(bspec, P(axes), bspec, P(axes)),
                 out_specs=(bspec, bspec, P(axes), P(axes)),

@@ -68,9 +68,8 @@ def sp_dsa_decode_local(q, kc, vc, ikc, h, idx_params, prev_topk, lengths,
     nl = kc.shape[1]
     kvh = kc.shape[2]
     g = hl // kvh
-    from repro.parallel.sharding import axis_size
     my = jax.lax.axis_index(seq_axis)
-    d = axis_size(seq_axis)
+    d = jax.lax.axis_size(seq_axis)
     off = (my * nl).astype(jnp.int32)
 
     # -- 1. sequence-local cache write ---------------------------------
@@ -214,8 +213,7 @@ def sp_dsa_decode_paged_local(q, k_pages, v_pages, table_local, idx_params, h,
         scores = jnp.where(in_win, scores, NEG)
 
     # -- 2./3. SP-GVR exact distributed Top-K → canonical global buffer --
-    from repro.parallel.sharding import axis_size
-    d = axis_size(seq_axis)
+    d = jax.lax.axis_size(seq_axis)
     n = n_local * d
     sel = sp_gvr_topk_local(scores, prev_topk, k, seq_axis,
                             max_candidates=max_candidates)
@@ -267,8 +265,7 @@ def make_sp_dsa(mesh, *, k: int, scale: float, heads: int, dim: int,
         return body(q, kc, vc, ikc, h, idx_params, prev_topk, lengths,
                     knew, vnew, iknew)
 
-    from repro.parallel.sharding import shard_map
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(hspec, kv_spec, kv_spec, P(None, seq_axis, None),
                   P(None, None), P(), P(None, None), P(None),

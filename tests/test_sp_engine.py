@@ -70,6 +70,7 @@ def run(reqs, **kw):
         "prefix": rep.prefix_hit_tokens,
         "preempt": rep.preemptions,
         "completed": rep.completed,
+        "step_signatures": eng._tick_fn._cache_size(),
     }
 
 out = {"cov": {}, "pre": {}}
@@ -135,6 +136,17 @@ def test_sp_engine_preemption_trace_bit_identical(sp_engine_results):
     assert sharded["tokens"] == single["tokens"]
     assert sharded["log"] == single["log"]
     assert sharded["hit"] == single["hit"]
+
+
+@pytest.mark.parametrize("trace,layout", [("cov", "single"), ("cov", "sp2"),
+                                          ("cov", "sp4"), ("pre", "single"),
+                                          ("pre", "sp2")])
+def test_engine_step_compiles_once(sp_engine_results, trace, layout):
+    """Host-side state updates (block-table pushes, admission, eviction,
+    copy-on-write) keep every leaf's placement, so the pool-wide step has
+    one signature for the whole run: on a chip each extra one is another
+    full-width compile."""
+    assert sp_engine_results[trace][layout]["step_signatures"] == 1
 
 
 # ---- shard-aware preemption victim choice (host-side, no mesh) ------------

@@ -381,8 +381,7 @@ _PROP = {"uid": 5000, "spec": {}}
 @pytest.fixture(scope="module", autouse=True)
 def _prop_ctx(model_and_params):
     cfg, model, params = model_and_params
-    _PROP.update(cfg=cfg, model=model, params=params,
-                 base=_engine(model, params))
+    _PROP.update(cfg=cfg, model=model, params=params, base={})
     yield
 
 
@@ -414,7 +413,16 @@ def test_property_spec_replays_nonspec_exactly(data):
         return [Request(uid=uid0 + i, prompt=p, max_new_tokens=m, arrival=a)
                 for i, (p, m, a) in enumerate(specs)]
 
-    base = _PROP["base"]
+    # one non-speculative twin per speculative engine: both see the same
+    # request history, so their prefix caches (whose hits depend on the
+    # page size and on which prompts came before) stay in step and the
+    # method sequences are comparable
+    key = (page_size, depth)
+    if key not in _PROP["base"]:
+        _PROP["base"][key] = _engine(model, params, page_size=page_size)
+        _PROP["spec"][key] = _engine(model, params, page_size=page_size,
+                                     spec_depth=depth)
+    base = _PROP["base"][key]
     rb = mk(_PROP["uid"])
     base.run(rb, max_ticks=800)
     cont = {r.uid - _PROP["uid"]: list(r.generated) for r in rb}
@@ -431,9 +439,7 @@ def test_property_spec_replays_nonspec_exactly(data):
             draft[at] = (draft[at] + 1) % cfg.vocab
         return draft
 
-    eng = _PROP["spec"].setdefault(
-        (page_size, depth),
-        _engine(model, params, page_size=page_size, spec_depth=depth))
+    eng = _PROP["spec"][key]
     eng.drafter = ScriptedDrafter(draft_fn)
     rs = mk(_PROP["uid"] + 1000)
     for r in rs:

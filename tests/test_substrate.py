@@ -110,9 +110,10 @@ def test_grad_clip_bounds_update():
 
 
 def test_zero1_specs_shard_moments():
-    from repro.parallel.sharding import abstract_mesh, make_rules
+    from jax.sharding import AbstractMesh
+    from repro.parallel.sharding import make_rules
     from jax.sharding import PartitionSpec as P
-    mesh = abstract_mesh((2, 2), ("data", "model"))
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     rules = make_rules(mesh)
     pspecs = {"w": P(None, "model"), "tiny": P(None)}
     shapes = {"w": jax.ShapeDtypeStruct((8, 4), jnp.float32),
@@ -175,9 +176,10 @@ def test_straggler_monitor_flags_outliers():
 # ---------------------------- sharding rules -------------------------------
 
 def test_divisibility_fallback():
-    from repro.parallel.sharding import abstract_mesh, make_rules
+    from jax.sharding import AbstractMesh
+    from repro.parallel.sharding import make_rules
     from jax.sharding import PartitionSpec as P
-    mesh = abstract_mesh((2, 8), ("data", "model"))
+    mesh = AbstractMesh((2, 8), ("data", "model"))
     rules = make_rules(mesh)
     # 28 heads on an 8-way model axis -> replicate; 32 -> shard
     assert rules.spec("d_model", "heads", sizes=(64, 28)) == P(None, None)

@@ -1,0 +1,154 @@
+"""Compile the served kernels and the paged decode step for TPU v5e.
+
+Nothing runs: each program is compiled for a v5e chip that is described,
+not attached (the TPU compiler ships with jaxlib), at the widths of
+llama3.2-1b — K=2048 over a 32K context in 64-token pages, 64 indexer
+heads × 128, 32/8 attention heads × 64 (and the block-table kernels
+again at 128K context). Mosaic refuses here exactly what
+it would refuse on the chip (tiling, unsupported ops, VMEM/SMEM budgets),
+and the whole-step compile reports the device memory the step needs.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs.registry import get_config
+from repro.kernels import ops
+from repro.models.api import build_model
+
+B, K, PAGE, N = 4, 2048, 64, 32768
+H, KVH, HD = 32, 8, 64
+IH, ID = 64, 128
+HBM_BYTES = 16 * 2 ** 30                  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    # a described chip's executables cannot be read back from the
+    # persistent cache: keep them out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _kernel_case(name, n=N):
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    mp = n // PAGE                        # block-table width, in SMEM
+    n_pages = B * mp + 1
+    pages = ((n_pages, PAGE, KVH, HD), bf16)
+    cases = {
+        "gvr_topk": (
+            lambda s, p: ops.gvr_topk(s, p, K, interpret=False),
+            [((B, n), f32), ((B, K), i32)]),
+        "indexer_topk": (
+            lambda q, kc, w, p: ops.indexer_topk(q, kc, w, p, K,
+                                                 interpret=False),
+            [((B, IH, ID), bf16), ((B, n, ID), bf16), ((B, IH), f32),
+             ((B, K), i32)]),
+        "paged_indexer_topk": (
+            lambda q, kp, w, t, p: ops.paged_indexer_topk(
+                q, kp, w, t, p, K, interpret=False),
+            [((B, IH, ID), bf16), ((n_pages, PAGE, ID), bf16), ((B, IH), f32),
+             ((B, mp), i32), ((B, K), i32)]),
+        "paged_sparse_decode_attn": (
+            lambda q, kp, vp, t, i: ops.paged_sparse_decode_attn(
+                q, kp, vp, t, i, interpret=False),
+            [((B, H, HD), bf16), pages, pages, ((B, mp), i32),
+             ((B, K), i32)]),
+        "paged_sparse_decode_attn_pg": (
+            lambda q, kp, vp, t, i: ops.paged_sparse_decode_attn_pg(
+                q, kp, vp, t, i, interpret=False),
+            [((B, H, HD), bf16), pages, pages, ((B, mp), i32),
+             ((B, K), i32)]),
+        "sparse_decode_attn": (
+            lambda q, kc, vc, i: ops.sparse_decode_attn(
+                q, kc, vc, i, interpret=False),
+            [((B, H, HD), bf16), ((B, n, KVH, HD), bf16),
+             ((B, n, KVH, HD), bf16), ((B, K), i32)]),
+        "paged_dense_decode_attn": (
+            lambda q, kp, vp, t, n: ops.paged_dense_decode_attn(
+                q, kp, vp, t, n, interpret=False),
+            [((B, H, HD), bf16), pages, pages, ((B, mp), i32), ((B,), i32)]),
+        "paged_gather": (
+            lambda kp, t: ops.paged_gather(kp, t, interpret=False),
+            [pages, ((B, mp), i32)]),
+        "paged_indexer_topk_mq": (
+            lambda q, kp, w, t, p, n: ops.paged_indexer_topk_mq(
+                q, kp, w, t, p, K, lengths=n, interpret=False),
+            [((B, 3, IH, ID), bf16), ((n_pages, PAGE, ID), bf16), ((B, IH), f32),
+             ((B, mp), i32), ((B, K), i32), ((B, 3), i32)]),
+        "paged_sparse_decode_attn_mq": (
+            lambda q, kp, vp, t, i: ops.paged_sparse_decode_attn_mq(
+                q, kp, vp, t, i, interpret=False),
+            [((B, 3, H, HD), bf16), pages, pages, ((B, mp), i32),
+             ((B, 3, K), i32)]),
+    }
+    return cases[name]
+
+
+@pytest.mark.parametrize("name", ["gvr_topk", "indexer_topk",
+                                  "paged_indexer_topk",
+                                  "paged_sparse_decode_attn",
+                                  "paged_sparse_decode_attn_mq",
+                                  "paged_sparse_decode_attn_pg",
+                                  "sparse_decode_attn",
+                                  "paged_dense_decode_attn", "paged_gather",
+                                  "paged_indexer_topk_mq"])
+def test_kernel_compiles_for_v5e(one_chip, name):
+    _compile_kernel(one_chip, *_kernel_case(name))
+
+
+@pytest.mark.parametrize("name", ["paged_indexer_topk",
+                                  "paged_sparse_decode_attn"])
+def test_block_table_fits_smem_at_128k(one_chip, name):
+    """At 128K context the scalar-prefetched (B, MP) block table is
+    B × 2048 int32 in SMEM."""
+    _compile_kernel(one_chip, *_kernel_case(name, n=131072))
+
+
+def _compile_kernel(one_chip, fn, specs):
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()      # Mosaic, not XLA
+
+
+def test_paged_decode_step_fits_one_v5e(one_chip):
+    """The served step at full width — 4 slots × 16K context of paged KV,
+    bf16 weights — compiles for one chip and, with the state donated as
+    the engine does, needs less than its 16 GiB."""
+    model = build_model(get_config("llama3.2-1b"))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init_params,
+                                    jax.random.PRNGKey(0)))
+    state = on_chip(jax.eval_shape(lambda: model.init_paged_decode_state(
+        B, 16384, num_pages=B * 16384 // PAGE, page_size=PAGE)))
+    tokens = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, s, t, m: model.serve_step_paged(
+        p, s, t, min_write_pos=m), donate_argnums=(1,))
+    mem = step.lower(params, state, tokens, tokens).compile() \
+        .memory_analysis()
+    assert mem.alias_size_in_bytes > 0                  # the pools in place
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, total
