@@ -59,6 +59,7 @@ class SPGVRResult(NamedTuple):
     secant_iters: jnp.ndarray    # (B,) int32
     snap_iters: jnp.ndarray      # (B,) int32
     hist_levels: jnp.ndarray     # (B,) int32
+    fallback: jnp.ndarray        # (B,) bool — safety-net K-th merge taken
 
 
 def _pax(v, axis_name):
@@ -272,7 +273,7 @@ def sp_gvr_topk_local(scores_local: jnp.ndarray, prev_idx: jnp.ndarray, k: int,
     return SPGVRResult(local_indices=gidx, local_count=my_count,
                        threshold=t_star, n_gt=n_gt,
                        secant_iters=secant_iters, snap_iters=sts["it"],
-                       hist_levels=hist_levels)
+                       hist_levels=hist_levels, fallback=fb)
 
 
 def _pax_max(v, axis_name):

@@ -94,13 +94,14 @@ class Model:
 
     def serve_step_paged(self, params, state, tokens, *, min_write_pos=None,
                          paged_attn="fused", gather_granularity="token",
-                         mesh=None, rules=None):
+                         mesh=None, rules=None, with_counts=False):
         """One paged decode step. `paged_attn` selects the sparse-attention
         form: "fused" (block-table-native, O(K) gathered KV traffic —
         default) or "gather" (materialize the logical view first; the PR-2
         oracle). `gather_granularity` ("token" | "page") picks the DMA
         shape of the fused sparse gather. All combinations are
-        bit-identical — see transformer.serve_step_paged.
+        bit-identical — see transformer.serve_step_paged. `with_counts`
+        adds the rows' GVR counts as a third result.
         """
         fn = getattr(self.mod, "serve_step_paged", None)
         if fn is None:
@@ -109,7 +110,7 @@ class Model:
         return fn(params, state, tokens, self.cfg,
                   min_write_pos=min_write_pos, paged_attn=paged_attn,
                   gather_granularity=gather_granularity,
-                  mesh=mesh, rules=rules)
+                  mesh=mesh, rules=rules, with_counts=with_counts)
 
     def serve_step_spec_paged(self, params, state, tokens, *, draft_len,
                               max_accept, eos_id=-1, min_write_pos=None,
@@ -158,18 +159,21 @@ class Model:
         return fn(self.cfg) if fn is not None else None
 
     def serve_step_sp_paged(self, params, state, tokens, *, mesh,
-                            min_write_pos=None, rules=None):
+                            min_write_pos=None, rules=None,
+                            with_counts=False):
         """One sequence-sharded paged decode step (shard_map over the
         mesh's "seq" axis; SP-GVR selection + O(K)-psum paged attention).
         Bit-identical to `serve_step_paged(paged_attn="fused")` — see
-        transformer.serve_step_sp_paged."""
+        transformer.serve_step_sp_paged. `with_counts` adds the rows' GVR
+        counts as a third result."""
         fn = getattr(self.mod, "serve_step_sp_paged", None)
         if fn is None:
             raise NotImplementedError(
                 f"family {self.cfg.family!r} has no sequence-sharded "
                 f"paged serve_step")
         return fn(params, state, tokens, self.cfg, mesh=mesh,
-                  min_write_pos=min_write_pos, rules=rules)
+                  min_write_pos=min_write_pos, rules=rules,
+                  with_counts=with_counts)
 
     def serve_step_sp_spec_paged(self, params, state, tokens, *, mesh,
                                  draft_len, max_accept, eos_id=-1,
@@ -190,13 +194,16 @@ class Model:
                   rules=rules)
 
     def serve_step(self, params, state, tokens, *, mesh=None, rules=None,
-                   seq_sharded: bool = False):
+                   seq_sharded: bool = False, with_counts: bool = False):
+        """One decode step. `with_counts` (families with a DSA selector:
+        the transformer) adds the rows' GVR counts as a third result."""
         if self.cfg.family == "hybrid":
             return self.mod.serve_step(params, state, tokens, self.cfg,
                                        mesh=mesh, rules=rules,
                                        seq_sharded=seq_sharded)
+        counts = {"with_counts": True} if with_counts else {}
         return self.mod.serve_step(params, state, tokens, self.cfg,
-                                   mesh=mesh, rules=rules)
+                                   mesh=mesh, rules=rules, **counts)
 
     # ---- dry-run stand-ins (ShapeDtypeStruct; no allocation) ------------
     def input_specs(self, shape: str) -> Dict[str, Any]:

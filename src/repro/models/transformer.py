@@ -13,6 +13,13 @@ all_to_all (layers.moe_mlp_ep) when a mesh is provided.
 serve_step carries functional decode state (KV caches, DSA indexer cache,
 prev-Top-K feedback, lengths) and runs the paper's DSA pipeline per layer
 when enabled.
+
+Every decode body names its stages with `jax.named_scope`: `step.qkv`,
+`step.kv_write`, `step.indexer`, `step.topk`, `step.attention`,
+`step.mlp`, `step.head` (the indexer and selection scopes sit in
+`sparse/dsa.py` and `sparse/sp_dsa.py`). The names reach the compiled
+HLO's op_name metadata, where a profiler trace's ops find them; the
+benchmark's readers (bench/scopes.py) depend on them.
 """
 
 from __future__ import annotations
@@ -359,35 +366,92 @@ def _attend_decode(p, h, q, kc, vc, idx_kc, prev_topk, topk_valid, new_len,
         if topk_valid is not None:
             # a DSA step just wrote genuine feedback → rows become warm
             out["topk_valid"] = jnp.ones_like(topk_valid)
-            out["sel_gvr"] = (res.gvr_rows if res.gvr_rows is not None
-                              else jnp.ones_like(topk_valid))
-    elif paged is not None:
-        # fused dense pre-DSA fallback: attend over the full logical extent
-        # straight off the page pools (bit-identical to gathering the view
-        # first — see layers.decode_attention_paged)
-        kp, vp, table = paged
-        attn = decode_attention_paged(q, kp, vp, table, new_len,
-                                      scale=hd ** -0.5,
-                                      window=cfg.swa_window, rules=rules)
-        if prev_topk is not None:
-            out["prev_topk"] = prev_topk
-            if topk_valid is not None:
-                out["topk_valid"] = topk_valid
-                out["sel_gvr"] = jnp.zeros_like(topk_valid)
-    else:
-        attn = decode_attention(q, kc, vc, new_len, scale=hd ** -0.5,
-                                window=cfg.swa_window)
-        if prev_topk is not None:
-            out["prev_topk"] = prev_topk
-            if topk_valid is not None:
-                out["topk_valid"] = topk_valid
-                out["sel_gvr"] = jnp.zeros_like(topk_valid)
+            out.update(sel_telemetry(res, topk_valid))
+        return attn, out
+    with jax.named_scope("step.attention"):
+        if paged is not None:
+            # fused dense pre-DSA fallback: attend over the full logical
+            # extent straight off the page pools (bit-identical to
+            # gathering the view first — see layers.decode_attention_paged)
+            kp, vp, table = paged
+            attn = decode_attention_paged(q, kp, vp, table, new_len,
+                                          scale=hd ** -0.5,
+                                          window=cfg.swa_window, rules=rules)
+        else:
+            attn = decode_attention(q, kc, vc, new_len, scale=hd ** -0.5,
+                                    window=cfg.swa_window)
+    if prev_topk is not None:
+        out["prev_topk"] = prev_topk
+        if topk_valid is not None:
+            out["topk_valid"] = topk_valid
+            out.update(sel_telemetry(None, topk_valid))
     return attn, out
 
 
+def _decode_out_mlp(p, x, attn, cfg: ModelConfig, mesh, rules):
+    """The rest of one decode layer: the attention output projection and
+    the residual MLP/MoE block. attn: (B, H, HD); x: (B, D)."""
+    with jax.named_scope("step.attention"):
+        attn = attn.reshape(x.shape[0], cfg.n_heads * cfg.hd).astype(x.dtype)
+        x = x + attn @ p["wo"]
+    with jax.named_scope("step.mlp"):
+        h = rms_norm(x, p["ln2"])
+        if cfg.moe.num_experts:
+            m = _mlp(p, h[:, None, :], cfg, mesh)[:, 0]
+        else:
+            m = _mlp(p, h, cfg, mesh)
+        x = x + m
+    return constrain(x, rules, "batch", "d_model")
+
+
+def _lm_head(params, x, cfg: ModelConfig):
+    """The final norm and the LM head: float32 logits."""
+    with jax.named_scope("step.head"):
+        x = rms_norm(x, params["final_norm"])
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        return (x @ head).astype(jnp.float32)
+
+
+def sel_telemetry(res, valid) -> Dict[str, jnp.ndarray]:
+    """One layer's selector telemetry, (B,) each: which rows the GVR path
+    served (`sel_gvr`, kept in the decode state for the engine's method
+    log), and for those rows GVR's secant iterations and whether its
+    safety net ran (`sel_iters`, `sel_fallback`: values of other rows
+    mean nothing; they leave the step only as `sel_counts`). `res` is the
+    layer's DSAOutput, SelectorOutput or SPDSAPagedResult; None where no
+    selection ran (the dense fallback). `valid` is the (B,) bool feedback
+    validity, for shape."""
+    none = jnp.zeros_like(valid)
+    if res is None:
+        return {"sel_gvr": none, "sel_iters": none.astype(jnp.int32),
+                "sel_fallback": none}
+    iters = res.secant_iters       # None where lax.top_k served every row
+    return {"sel_gvr": res.gvr_rows,
+            "sel_iters": (none if iters is None else iters).astype(jnp.int32),
+            "sel_fallback": res.fallback}
+
+
+def sel_counts(outs, b: int) -> jnp.ndarray:
+    """Per row, summed over the layer scan's leading (layer) axis of its
+    `sel_telemetry` stacks in `outs` ((L, ..., B) each): [GVR-served
+    row-layers, their secant iterations, their safety-net fallbacks] —
+    (..., B, 3) int32; zeros (B, 3) where no layer carries telemetry (DSA
+    off). The counts a step returns with `with_counts=True`."""
+    if "sel_gvr" not in outs:
+        return jnp.zeros((b, 3), jnp.int32)
+    gvr = outs["sel_gvr"]
+    return jnp.stack(
+        [jnp.sum(gvr, axis=0, dtype=jnp.int32),
+         jnp.sum(jnp.where(gvr, outs["sel_iters"], 0), axis=0,
+                 dtype=jnp.int32),
+         jnp.sum(gvr & outs["sel_fallback"], axis=0, dtype=jnp.int32)],
+        axis=-1)
+
+
 def serve_step(params, state, tokens, cfg: ModelConfig, *, mesh=None,
-               rules: Optional[MeshRules] = None):
-    """One decode step. tokens: (B,) int32. Returns (logits (B,V), state).
+               rules: Optional[MeshRules] = None, with_counts: bool = False):
+    """One decode step. tokens: (B,) int32. Returns (logits (B,V), state),
+    and with `with_counts` the rows' GVR counts (B, 3) third (`sel_counts`).
 
     Per layer: append KV (and indexer K) at position `length`, then attend —
     DSA sparse path when enabled and the cache is long enough, dense
@@ -415,34 +479,30 @@ def serve_step(params, state, tokens, cfg: ModelConfig, *, mesh=None,
         vc = constrain(vc, rules, "batch", None, None, None)
         if idx_kc is not None:
             idx_kc = constrain(idx_kc, rules, "batch", None, None)
-        h = rms_norm(x, p["ln1"])
-        q, kn, vn = _project_qkv(p, h, b, positions, cfg, rules)
-        kc = _write_row(kc, kn, positions)
-        vc = _write_row(vc, vn, positions)
-        kc = constrain(kc, rules, "batch", None, None, None)
-        vc = constrain(vc, rules, "batch", None, None, None)
+        with jax.named_scope("step.qkv"):
+            h = rms_norm(x, p["ln1"])
+            q, kn, vn = _project_qkv(p, h, b, positions, cfg, rules)
+        with jax.named_scope("step.kv_write"):
+            kc = _write_row(kc, kn, positions)
+            vc = _write_row(vc, vn, positions)
+            kc = constrain(kc, rules, "batch", None, None, None)
+            vc = constrain(vc, rules, "batch", None, None, None)
 
         out = {"k": kc, "v": vc, "p": p}
         if use_dsa:
-            ik = dsa_mod.indexer_k(p["indexer"], h, positions,
-                                   dim=cfg.dsa.indexer_dim,
-                                   rope_base=cfg.rope_base)
-            idx_kc = _write_row(idx_kc, ik, positions)
+            with jax.named_scope("step.indexer"):
+                ik = dsa_mod.indexer_k(p["indexer"], h, positions,
+                                       dim=cfg.dsa.indexer_dim,
+                                       rope_base=cfg.rope_base)
+            with jax.named_scope("step.kv_write"):
+                idx_kc = _write_row(idx_kc, ik, positions)
         if idx_kc is not None:
             out["idx_k"] = idx_kc
         attn, extras = _attend_decode(p, h, q, kc, vc, idx_kc, prev_topk,
                                       topk_valid, new_len, cfg, use_dsa,
                                       rules, mesh)
         out.update(extras)
-        attn = attn.reshape(b, cfg.n_heads * hd).astype(x.dtype)
-        x = x + attn @ p["wo"]
-        h = rms_norm(x, p["ln2"])
-        if cfg.moe.num_experts:
-            m = _mlp(p, h[:, None, :], cfg, mesh)[:, 0]
-        else:
-            m = _mlp(p, h, cfg, mesh)
-        x = x + m
-        x = constrain(x, rules, "batch", "d_model")
+        x = _decode_out_mlp(p, x, attn, cfg, mesh, rules)
         return x, out
 
     carry_in = {"p": params["layers"], "k": state["k"], "v": state["v"]}
@@ -462,11 +522,10 @@ def serve_step(params, state, tokens, cfg: ModelConfig, *, mesh=None,
             new_state["topk_valid"] = outs["topk_valid"]
             new_state["sel_gvr"] = outs["sel_gvr"]
     new_state["length"] = new_len
-
-    x = rms_norm(x, params["final_norm"])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head).astype(jnp.float32)
-    return constrain(logits, rules, "batch", "vocab"), new_state
+    logits = constrain(_lm_head(params, x, cfg), rules, "batch", "vocab")
+    if with_counts:
+        return logits, new_state, sel_counts(outs, b)
+    return logits, new_state
 
 
 # --------------------------------------------------------------------------
@@ -576,8 +635,8 @@ def _sp_paged_token_body(params, state, tokens, mwp, cfg: ModelConfig, *,
     module level so the speculative verify step can scan it over the d+1
     draft positions of a verify tick within a single shard_map
     (`serve_step_sp_spec_paged`); `serve_step_sp_paged` wraps exactly one
-    invocation. Returns (logits, new_state) with the shard axis restored
-    on the pool leaves."""
+    invocation. Returns (logits, new_state, counts) with the shard axis
+    restored on the pool leaves; counts: `sel_counts` (B, 3)."""
     from repro.sparse import sp_dsa as sp_dsa_mod
 
     b = tokens.shape[0]
@@ -617,18 +676,22 @@ def _sp_paged_token_body(params, state, tokens, mwp, cfg: ModelConfig, *,
         idx_kp = carry["idx_k_pages"]
         prev_topk = carry["prev_topk"]
         topk_valid = carry.get("topk_valid")
-        h = rms_norm(x, p["ln1"])
-        q, kn, vn = _project_qkv(p, h, b, positions, cfg, None)
-        kp = kp.at[dest, off].set(kn.astype(kp.dtype))
-        vp = vp.at[dest, off].set(vn.astype(vp.dtype))
-        ik = dsa_mod.indexer_k(p["indexer"], h, positions,
-                               dim=cfg.dsa.indexer_dim,
-                               rope_base=cfg.rope_base)
-        idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
-        # shard-local logical indexer view: N/S × d_i per device — the
-        # irreducible indexer read, now split across the mesh
-        idx_kc = idx_kp[gather_local].reshape(b, n_local,
-                                              cfg.dsa.indexer_dim)
+        with jax.named_scope("step.qkv"):
+            h = rms_norm(x, p["ln1"])
+            q, kn, vn = _project_qkv(p, h, b, positions, cfg, None)
+        with jax.named_scope("step.indexer"):
+            ik = dsa_mod.indexer_k(p["indexer"], h, positions,
+                                   dim=cfg.dsa.indexer_dim,
+                                   rope_base=cfg.rope_base)
+        with jax.named_scope("step.kv_write"):
+            kp = kp.at[dest, off].set(kn.astype(kp.dtype))
+            vp = vp.at[dest, off].set(vn.astype(vp.dtype))
+            idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
+        with jax.named_scope("step.indexer"):
+            # shard-local logical indexer view: N/S × d_i per device — the
+            # irreducible indexer read, now split across the mesh
+            idx_kc = idx_kp[gather_local].reshape(b, n_local,
+                                                  cfg.dsa.indexer_dim)
         res = sp_dsa_mod.sp_dsa_decode_paged_local(
             q, kp, vp, table_local, p["indexer"], h, idx_kc,
             prev_topk, topk_valid, new_len,
@@ -641,15 +704,8 @@ def _sp_paged_token_body(params, state, tokens, mwp, cfg: ModelConfig, *,
                "p": p, "prev_topk": res.new_topk}
         if topk_valid is not None:
             out["topk_valid"] = jnp.ones_like(topk_valid)
-            out["sel_gvr"] = res.gvr_rows
-        attn = res.attn_out.reshape(b, cfg.n_heads * hd).astype(x.dtype)
-        x = x + attn @ p["wo"]
-        h = rms_norm(x, p["ln2"])
-        if cfg.moe.num_experts:
-            m = _mlp(p, h[:, None, :], cfg, None)[:, 0]
-        else:
-            m = _mlp(p, h, cfg, None)
-        x = x + m
+            out.update(sel_telemetry(res, topk_valid))
+        x = _decode_out_mlp(p, x, res.attn_out, cfg, None, None)
         return x, out
 
     carry_in = {"p": params["layers"],
@@ -669,19 +725,17 @@ def _sp_paged_token_body(params, state, tokens, mwp, cfg: ModelConfig, *,
         new_state["topk_valid"] = outs["topk_valid"]
         new_state["sel_gvr"] = outs["sel_gvr"]
     new_state["length"] = new_len
-
-    x = rms_norm(x, params["final_norm"])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head).astype(jnp.float32)
-    return logits, new_state
+    return _lm_head(params, x, cfg), new_state, sel_counts(outs, b)
 
 
 def serve_step_sp_paged(params, state, tokens, cfg: ModelConfig, *, mesh,
                         min_write_pos: Optional[jnp.ndarray] = None,
                         seq_axis: str = "seq",
-                        rules: Optional[MeshRules] = None):
+                        rules: Optional[MeshRules] = None,
+                        with_counts: bool = False):
     """One sequence-sharded paged decode step (inside a shard_map over the
-    mesh's `seq_axis`). tokens: (B,) int32. Returns (logits, state).
+    mesh's `seq_axis`). tokens: (B,) int32. Returns (logits, state), and
+    with `with_counts` the rows' GVR counts (B, 3) third (`sel_counts`).
 
     Per shard and per layer: the shard owning logical position `length`
     scatters the new token's K/V/indexer-K rows into ITS page pool (every
@@ -705,8 +759,9 @@ def serve_step_sp_paged(params, state, tokens, cfg: ModelConfig, *, mesh,
            else jnp.zeros((b,), jnp.int32))
 
     def body(params, state, tokens, mwp):
-        return _sp_paged_token_body(params, state, tokens, mwp, cfg,
-                                    seq_axis=seq_axis)
+        out = _sp_paged_token_body(params, state, tokens, mwp, cfg,
+                                   seq_axis=seq_axis)
+        return out if with_counts else out[:2]
 
     pool_spec = P(None, seq_axis)
     st_spec = {key: (pool_spec if key in ("k_pages", "v_pages", "idx_k_pages")
@@ -714,7 +769,8 @@ def serve_step_sp_paged(params, state, tokens, cfg: ModelConfig, *, mesh,
     param_spec = jax.tree.map(lambda _: P(), params)
     fn = jax.shard_map(body, mesh=mesh,
                    in_specs=(param_spec, st_spec, P(), P()),
-                   out_specs=(P(), st_spec), check_vma=False)
+                   out_specs=(P(), st_spec) + ((P(),) if with_counts else ()),
+                   check_vma=False)
     return fn(params, state, tokens, mwp)
 
 
@@ -800,8 +856,10 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
                      min_write_pos: Optional[jnp.ndarray] = None,
                      paged_attn: str = "fused",
                      gather_granularity: str = "token",
-                     mesh=None, rules: Optional[MeshRules] = None):
-    """One paged decode step. tokens: (B,) int32. Returns (logits, state).
+                     mesh=None, rules: Optional[MeshRules] = None,
+                     with_counts: bool = False):
+    """One paged decode step. tokens: (B,) int32. Returns (logits, state),
+    and with `with_counts` the rows' GVR counts (B, 3) third (`sel_counts`).
 
     Mirrors `serve_step` exactly, with the logical→physical translation at
     the cache boundary: the new token's rows scatter into
@@ -879,29 +937,36 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
         idx_kp = carry.get("idx_k_pages")
         prev_topk = carry.get("prev_topk")
         topk_valid = carry.get("topk_valid")
-        h = rms_norm(x, p["ln1"])
-        q, kn, vn = _project_qkv(p, h, b, positions, cfg, rules)
-        kp = kp.at[dest, off].set(kn.astype(kp.dtype))
-        vp = vp.at[dest, off].set(vn.astype(vp.dtype))
+        with jax.named_scope("step.qkv"):
+            h = rms_norm(x, p["ln1"])
+            q, kn, vn = _project_qkv(p, h, b, positions, cfg, rules)
+        with jax.named_scope("step.kv_write"):
+            kp = kp.at[dest, off].set(kn.astype(kp.dtype))
+            vp = vp.at[dest, off].set(vn.astype(vp.dtype))
         if fused:
             kc = vc = None            # K/V logical views intentionally unbuilt
         else:
-            kc = kp[gather].reshape(b, n, cfg.n_kv_heads, hd)
-            vc = vp[gather].reshape(b, n, cfg.n_kv_heads, hd)
-            kc = constrain(kc, rules, "batch", None, None, None)
-            vc = constrain(vc, rules, "batch", None, None, None)
+            with jax.named_scope("step.attention"):
+                kc = kp[gather].reshape(b, n, cfg.n_kv_heads, hd)
+                vc = vp[gather].reshape(b, n, cfg.n_kv_heads, hd)
+                kc = constrain(kc, rules, "batch", None, None, None)
+                vc = constrain(vc, rules, "batch", None, None, None)
 
         out = {"k_pages": kp, "v_pages": vp, "p": p}
         idx_kc = None
         if use_dsa:
-            ik = dsa_mod.indexer_k(p["indexer"], h, positions,
-                                   dim=cfg.dsa.indexer_dim,
-                                   rope_base=cfg.rope_base)
-            idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
-            # the indexer scores all N tokens (paper Table 2: irreducible
-            # O(N·d_i)), so its logical view costs what scoring in page
-            # space would — and keeps scores/Top-K in logical order
-            idx_kc = idx_kp[gather].reshape(b, n, cfg.dsa.indexer_dim)
+            with jax.named_scope("step.indexer"):
+                ik = dsa_mod.indexer_k(p["indexer"], h, positions,
+                                       dim=cfg.dsa.indexer_dim,
+                                       rope_base=cfg.rope_base)
+            with jax.named_scope("step.kv_write"):
+                idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
+            with jax.named_scope("step.indexer"):
+                # the indexer scores all N tokens (paper Table 2:
+                # irreducible O(N·d_i)), so its logical view costs what
+                # scoring in page space would — and keeps scores/Top-K in
+                # logical order
+                idx_kc = idx_kp[gather].reshape(b, n, cfg.dsa.indexer_dim)
         if idx_kp is not None:
             out["idx_k_pages"] = idx_kp
         attn, extras = _attend_decode(p, h, q, kc, vc, idx_kc, prev_topk,
@@ -910,15 +975,7 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
                                       paged=(kp, vp, table) if fused else None,
                                       gather_granularity=gather_granularity)
         out.update(extras)
-        attn = attn.reshape(b, cfg.n_heads * hd).astype(x.dtype)
-        x = x + attn @ p["wo"]
-        h = rms_norm(x, p["ln2"])
-        if cfg.moe.num_experts:
-            m = _mlp(p, h[:, None, :], cfg, mesh)[:, 0]
-        else:
-            m = _mlp(p, h, cfg, mesh)
-        x = x + m
-        x = constrain(x, rules, "batch", "d_model")
+        x = _decode_out_mlp(p, x, attn, cfg, mesh, rules)
         return x, out
 
     carry_in = {"p": params["layers"], "k_pages": state["k_pages"],
@@ -939,11 +996,10 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
             new_state["topk_valid"] = outs["topk_valid"]
             new_state["sel_gvr"] = outs["sel_gvr"]
     new_state["length"] = new_len
-
-    x = rms_norm(x, params["final_norm"])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head).astype(jnp.float32)
-    return constrain(logits, rules, "batch", "vocab"), new_state
+    logits = constrain(_lm_head(params, x, cfg), rules, "batch", "vocab")
+    if with_counts:
+        return logits, new_state, sel_counts(outs, b)
+    return logits, new_state
 
 
 # --------------------------------------------------------------------------
@@ -980,8 +1036,8 @@ def _spec_verify_scan(step_fn, state, tokens, draft_len, max_accept,
     in-graph rollback (used by `serve_step_spec_paged` and, inside the
     shard_map, by `serve_step_sp_spec_paged`).
 
-    step_fn(state, tok (B,), mwp (B,)) -> (logits (B, V), new_state) — one
-    per-token paged decode step. tokens: (B, D+1) — column 0 is the last
+    step_fn(state, tok (B,), mwp (B,)) -> (logits (B, V), new_state,
+    counts (B, 3)) — one per-token paged decode step with its `sel_counts`. tokens: (B, D+1) — column 0 is the last
     emitted token, columns 1..D the draft. draft_len: (B,) in [0, D] — rows
     verify positions 0..draft_len (position j > draft_len is frozen: state
     row kept, cache write redirected to the sink page). max_accept: (B,)
@@ -990,9 +1046,11 @@ def _spec_verify_scan(step_fn, state, tokens, draft_len, max_accept,
     (-1 = disabled; vocab ids are non-negative so it never matches).
 
     Returns (out_tokens (B, D+1), accept_len (B,), logits_all (B, D+1, V),
-    sel_gvr_pos (B, D+1), new_state): `out_tokens[:, j]` is position j's
-    argmax, the engine appends columns 0..accept_len; `sel_gvr_pos` is the
-    layer-0 per-position GVR telemetry (column j valid iff j <= draft_len).
+    sel_gvr_pos (B, D+1), gvr_counts (B, 3), new_state): `out_tokens[:, j]`
+    is position j's argmax, the engine appends columns 0..accept_len;
+    `sel_gvr_pos` is the layer-0 per-position GVR telemetry (column j valid
+    iff j <= draft_len); `gvr_counts` is `sel_counts` over the executed
+    positions (j <= draft_len) of every layer.
     """
     b, d1 = tokens.shape
     never = jnp.int32(PAGED_NEVER_WRITE)
@@ -1002,7 +1060,7 @@ def _spec_verify_scan(step_fn, state, tokens, draft_len, max_accept,
         j, tok = inp
         live = j <= draft_len                          # (B,)
         mwp = jnp.where(live, base_mwp, never)
-        logits, st2 = step_fn(st, tok, mwp)
+        logits, st2, counts = step_fn(st, tok, mwp)
         merged = {}
         for key, arr in st2.items():
             ax = axes.get(key)
@@ -1017,9 +1075,9 @@ def _spec_verify_scan(step_fn, state, tokens, draft_len, max_accept,
             # raw (unmerged) per-position stacks: entry j is only ever
             # selected for rows with accept_len <= draft_len, i.e. rows
             # for which position j really executed
-            ys["prev_topk"] = st2["prev_topk"]
-            ys["topk_valid"] = st2["topk_valid"]
-            ys["sel_gvr"] = st2["sel_gvr"]
+            for key in ("prev_topk", "topk_valid", "sel_gvr"):
+                ys[key] = st2[key]
+            ys["sel_counts"] = counts
         return merged, ys
 
     xs = (jnp.arange(d1, dtype=jnp.int32), tokens.T)
@@ -1036,10 +1094,12 @@ def _spec_accept_rollback(length0, end_state, ys, tokens, draft_len,
     agree on every accept/reject/eos trace whenever their stacks agree.
 
     ys: {"logits": (D+1, B, V)} plus, when DSA state is carried,
-    per-position stacks "prev_topk" (D+1, L, B, K) and "topk_valid" /
+    per-position stacks "prev_topk" (D+1, L, B, K), "topk_valid" and
     "sel_gvr" (D+1, L, B) — RAW (unmerged) values; entry j is only ever
     selected for rows whose position j really executed (accept_len <=
-    draft_len). Returns the serve_step_spec_paged 5-tuple.
+    draft_len) — and "sel_counts" (D+1, B, 3), each position's
+    `sel_counts`, summed over the executed positions only. Returns the
+    serve_step_spec_paged 6-tuple.
     """
     b, d1 = tokens.shape
     logits_all = ys["logits"]                          # (D+1, B, V)
@@ -1074,10 +1134,15 @@ def _spec_accept_rollback(length0, end_state, ys, tokens, draft_len,
             gi = jnp.broadcast_to(a[None, None, :], (1,) + stk.shape[1:])
             new_state[key] = jnp.take_along_axis(stk, gi, axis=0)[0]
         sel_pos = jnp.transpose(ys["sel_gvr"][:, 0, :])   # (B, D+1), layer 0
+        executed = (jnp.arange(d1, dtype=jnp.int32)[:, None]
+                    <= draft_len[None, :])             # (D+1, B)
+        counts = jnp.sum(jnp.where(executed[:, :, None], ys["sel_counts"],
+                                   0), axis=0)
     else:
         sel_pos = jnp.zeros((b, d1), bool)
+        counts = jnp.zeros((b, 3), jnp.int32)
     return (argmax_all.T, a, jnp.transpose(logits_all, (1, 0, 2)),
-            sel_pos, new_state)
+            sel_pos, counts, new_state)
 
 
 def _paged_verify_mq(params, state, tokens, cfg: ModelConfig, *, draft_len,
@@ -1147,25 +1212,30 @@ def _paged_verify_mq(params, state, tokens, cfg: ModelConfig, *, draft_len,
         idx_kp = carry.get("idx_k_pages")
         prev_topk = carry.get("prev_topk")               # (B, K)
         topk_valid = carry.get("topk_valid")             # (B,)
-        h = rms_norm(x, p["ln1"])                        # (B, Q, D)
-        hf = h.reshape(b * d1, -1)
-        q, kn, vn = _project_qkv(p, hf, b * d1, flat_pos, cfg, rules)
-        q = q.reshape(b, d1, cfg.n_heads, hd)
-        kn = kn.reshape(b, d1, cfg.n_kv_heads, hd)
-        vn = vn.reshape(b, d1, cfg.n_kv_heads, hd)
+        with jax.named_scope("step.qkv"):
+            h = rms_norm(x, p["ln1"])                    # (B, Q, D)
+            hf = h.reshape(b * d1, -1)
+            q, kn, vn = _project_qkv(p, hf, b * d1, flat_pos, cfg, rules)
+            q = q.reshape(b, d1, cfg.n_heads, hd)
+            kn = kn.reshape(b, d1, cfg.n_kv_heads, hd)
+            vn = vn.reshape(b, d1, cfg.n_kv_heads, hd)
         # all Q rows write before anything attends — safe because every
         # consumer masks beyond its own extent (see docstring)
-        kp = kp.at[dest, off].set(kn.astype(kp.dtype))
-        vp = vp.at[dest, off].set(vn.astype(vp.dtype))
+        with jax.named_scope("step.kv_write"):
+            kp = kp.at[dest, off].set(kn.astype(kp.dtype))
+            vp = vp.at[dest, off].set(vn.astype(vp.dtype))
 
         out = {"k_pages": kp, "v_pages": vp}
         if use_dsa:
-            ik = dsa_mod.indexer_k(p["indexer"], hf, flat_pos,
-                                   dim=cfg.dsa.indexer_dim,
-                                   rope_base=cfg.rope_base)
-            ik = ik.reshape(b, d1, cfg.dsa.indexer_dim)
-            idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
-            idx_kc = idx_kp[gather].reshape(b, n, cfg.dsa.indexer_dim)
+            with jax.named_scope("step.indexer"):
+                ik = dsa_mod.indexer_k(p["indexer"], hf, flat_pos,
+                                       dim=cfg.dsa.indexer_dim,
+                                       rope_base=cfg.rope_base)
+                ik = ik.reshape(b, d1, cfg.dsa.indexer_dim)
+            with jax.named_scope("step.kv_write"):
+                idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
+            with jax.named_scope("step.indexer"):
+                idx_kc = idx_kp[gather].reshape(b, n, cfg.dsa.indexer_dim)
 
             # the per-row Top-K chain: the mq indexer kernel's VMEM
             # feedback threading, in XLA form — selection is inherently
@@ -1181,45 +1251,48 @@ def _paged_verify_mq(params, state, tokens, cfg: ModelConfig, *, draft_len,
                     max_candidates=cfg.dsa.max_candidates,
                     gate_max_n=cfg.dsa.gate_max_n, min_n=cfg.dsa.min_n,
                     swa_window=cfg.swa_window, rules=rules, mesh=mesh)
-                gvr = (sel.gvr_rows if sel.gvr_rows is not None
-                       else jnp.ones_like(valid))
-                return (sel.indices, jnp.ones_like(valid)), (sel.indices, gvr)
+                return ((sel.indices, jnp.ones_like(valid)),
+                        (sel.indices, sel_telemetry(sel, valid)))
 
-            _, (idx_all, gvr_all) = jax.lax.scan(
+            _, (idx_all, tele) = jax.lax.scan(
                 sel_row, (prev_topk, topk_valid),
                 (jnp.swapaxes(h, 0, 1), jnp.swapaxes(lengths_q, 0, 1)))
             idx_q = jnp.swapaxes(idx_all, 0, 1)          # (B, Q, K)
-            if fused:
-                attn = dsa_mod.dsa_sparse_attention_paged_mq(
-                    q, kp, vp, table, idx_q, lengths_q, scale=hd ** -0.5,
-                    granularity=gather_granularity, rules=rules)
-            else:
-                kc = kp[gather].reshape(b, n, cfg.n_kv_heads, hd)
-                vc = vp[gather].reshape(b, n, cfg.n_kv_heads, hd)
-                attn = dsa_mod.dsa_sparse_attention(
-                    q.reshape(b * d1, cfg.n_heads, hd),
-                    jnp.repeat(kc, d1, axis=0), jnp.repeat(vc, d1, axis=0),
-                    idx_q.reshape(b * d1, -1), lengths_q.reshape(b * d1),
-                    scale=hd ** -0.5, rules=rules)
-                attn = attn.reshape(b, d1, cfg.n_heads, hd)
+            with jax.named_scope("step.attention"):
+                if fused:
+                    attn = dsa_mod.dsa_sparse_attention_paged_mq(
+                        q, kp, vp, table, idx_q, lengths_q,
+                        scale=hd ** -0.5, granularity=gather_granularity,
+                        rules=rules)
+                else:
+                    kc = kp[gather].reshape(b, n, cfg.n_kv_heads, hd)
+                    vc = vp[gather].reshape(b, n, cfg.n_kv_heads, hd)
+                    attn = dsa_mod.dsa_sparse_attention(
+                        q.reshape(b * d1, cfg.n_heads, hd),
+                        jnp.repeat(kc, d1, axis=0),
+                        jnp.repeat(vc, d1, axis=0),
+                        idx_q.reshape(b * d1, -1), lengths_q.reshape(b * d1),
+                        scale=hd ** -0.5, rules=rules)
+                    attn = attn.reshape(b, d1, cfg.n_heads, hd)
             out["sel_idx"] = idx_all                      # (Q, B, K)
-            out["sel_gvr"] = gvr_all                      # (Q, B)
+            out.update(tele)                              # (Q, B) each
             out["sel_valid"] = jnp.ones((d1,) + topk_valid.shape, bool)
         else:
             qf = q.reshape(b * d1, cfg.n_heads, hd)
             lf = lengths_q.reshape(b * d1)
-            if fused:
-                attn = decode_attention_paged(
-                    qf, kp, vp, jnp.repeat(table, d1, axis=0), lf,
-                    scale=hd ** -0.5, window=cfg.swa_window, rules=rules)
-            else:
-                kc = kp[gather].reshape(b, n, cfg.n_kv_heads, hd)
-                vc = vp[gather].reshape(b, n, cfg.n_kv_heads, hd)
-                attn = decode_attention(
-                    qf, jnp.repeat(kc, d1, axis=0),
-                    jnp.repeat(vc, d1, axis=0), lf,
-                    scale=hd ** -0.5, window=cfg.swa_window)
-            attn = attn.reshape(b, d1, cfg.n_heads, hd)
+            with jax.named_scope("step.attention"):
+                if fused:
+                    attn = decode_attention_paged(
+                        qf, kp, vp, jnp.repeat(table, d1, axis=0), lf,
+                        scale=hd ** -0.5, window=cfg.swa_window, rules=rules)
+                else:
+                    kc = kp[gather].reshape(b, n, cfg.n_kv_heads, hd)
+                    vc = vp[gather].reshape(b, n, cfg.n_kv_heads, hd)
+                    attn = decode_attention(
+                        qf, jnp.repeat(kc, d1, axis=0),
+                        jnp.repeat(vc, d1, axis=0), lf,
+                        scale=hd ** -0.5, window=cfg.swa_window)
+                attn = attn.reshape(b, d1, cfg.n_heads, hd)
             if prev_topk is not None:
                 # pre-gate passthrough: the scan stacks the same incoming
                 # feedback at every position
@@ -1227,22 +1300,27 @@ def _paged_verify_mq(params, state, tokens, cfg: ModelConfig, *, draft_len,
                     prev_topk[None], (d1,) + prev_topk.shape)
                 out["sel_valid"] = jnp.broadcast_to(
                     topk_valid[None], (d1,) + topk_valid.shape)
-                out["sel_gvr"] = jnp.zeros((d1,) + topk_valid.shape, bool)
+                none = sel_telemetry(None, topk_valid)
+                out.update({k: jnp.broadcast_to(v[None], (d1,) + v.shape)
+                            for k, v in none.items()})
         if idx_kp is not None:
             out["idx_k_pages"] = idx_kp
 
-        attn = attn.reshape(b, d1, cfg.n_heads * hd).astype(x.dtype)
-        x = x + attn @ p["wo"]
-        h2 = rms_norm(x, p["ln2"])
-        if cfg.moe.num_experts:
-            # MoE per position with the scan's (B, 1, D) call shape —
-            # routing/capacity must see the same token batch per call
-            mo = jax.lax.map(lambda hh: _mlp(p, hh[:, None, :], cfg, mesh)[:, 0],
-                             jnp.swapaxes(h2, 0, 1))
-            m = jnp.swapaxes(mo, 0, 1)
-        else:
-            m = _mlp(p, h2, cfg, mesh)
-        x = x + m
+        with jax.named_scope("step.attention"):
+            attn = attn.reshape(b, d1, cfg.n_heads * hd).astype(x.dtype)
+            x = x + attn @ p["wo"]
+        with jax.named_scope("step.mlp"):
+            h2 = rms_norm(x, p["ln2"])
+            if cfg.moe.num_experts:
+                # MoE per position with the scan's (B, 1, D) call shape —
+                # routing/capacity must see the same token batch per call
+                mo = jax.lax.map(
+                    lambda hh: _mlp(p, hh[:, None, :], cfg, mesh)[:, 0],
+                    jnp.swapaxes(h2, 0, 1))
+                m = jnp.swapaxes(mo, 0, 1)
+            else:
+                m = _mlp(p, h2, cfg, mesh)
+            x = x + m
         x = constrain(x, rules, "batch", None, "d_model")
         return x, out
 
@@ -1253,16 +1331,14 @@ def _paged_verify_mq(params, state, tokens, cfg: ModelConfig, *, draft_len,
         carry_in["prev_topk"] = state["prev_topk"]
         carry_in["topk_valid"] = state["topk_valid"]
     x, outs = jax.lax.scan(layer, x, carry_in)
-
-    x = rms_norm(x, params["final_norm"])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head).astype(jnp.float32)              # (B, Q, V)
+    logits = _lm_head(params, x, cfg)                    # (B, Q, V)
 
     ys = {"logits": jnp.transpose(logits, (1, 0, 2))}    # (D+1, B, V)
     if cfg.dsa.enabled:
         ys["prev_topk"] = jnp.swapaxes(outs["sel_idx"], 0, 1)   # (Q, L, B, K)
         ys["topk_valid"] = jnp.swapaxes(outs["sel_valid"], 0, 1)
         ys["sel_gvr"] = jnp.swapaxes(outs["sel_gvr"], 0, 1)
+        ys["sel_counts"] = sel_counts(outs, b)            # (Q, B, 3)
     end_state = dict(state)
     end_state["k_pages"] = outs["k_pages"]
     end_state["v_pages"] = outs["v_pages"]
@@ -1295,7 +1371,8 @@ def serve_step_spec_paged(params, state, tokens, cfg: ModelConfig, *,
       (`_paged_verify_mq` — the served form of the Pallas mq kernels).
 
     Returns (out_tokens (B, D+1), accept_len (B,), logits_all (B, D+1, V),
-    sel_gvr_pos (B, D+1), new_state).
+    sel_gvr_pos (B, D+1), gvr_counts (B, 3), new_state) — see
+    `_spec_verify_scan`.
     """
     b = tokens.shape[0]
     base_mwp = (min_write_pos if min_write_pos is not None
@@ -1319,7 +1396,7 @@ def serve_step_spec_paged(params, state, tokens, cfg: ModelConfig, *,
         return serve_step_paged(params, st, tok, cfg, min_write_pos=mwp,
                                 paged_attn=paged_attn,
                                 gather_granularity=gather_granularity,
-                                mesh=mesh, rules=rules)
+                                mesh=mesh, rules=rules, with_counts=True)
 
     return _spec_verify_scan(step_fn, state, tokens, draft_len, max_accept,
                              int(eos_id), base_mwp,
@@ -1346,7 +1423,7 @@ def _sp_paged_verify_mq_body(params, state, tokens, draft_len, max_accept,
     stale under the scan — contribute exactly zero, and frozen rows'
     garbage stacks are never selected by the shared rollback arithmetic.
 
-    Returns the serve_step_spec_paged 5-tuple (replicated outputs + the
+    Returns the serve_step_spec_paged 6-tuple (replicated outputs + the
     per-shard end state), via `_spec_accept_rollback`.
     """
     from repro.sparse import sp_dsa as sp_dsa_mod
@@ -1394,21 +1471,25 @@ def _sp_paged_verify_mq_body(params, state, tokens, draft_len, max_accept,
         idx_kp = carry["idx_k_pages"]
         prev_topk = carry["prev_topk"]                   # (B, K)
         topk_valid = carry.get("topk_valid")             # (B,)
-        h = rms_norm(x, p["ln1"])                        # (B, Q, D)
-        hf = h.reshape(b * d1, -1)
-        q, kn, vn = _project_qkv(p, hf, b * d1, flat_pos, cfg, None)
-        q = q.reshape(b, d1, cfg.n_heads, hd)
-        kn = kn.reshape(b, d1, cfg.n_kv_heads, hd)
-        vn = vn.reshape(b, d1, cfg.n_kv_heads, hd)
-        kp = kp.at[dest, off].set(kn.astype(kp.dtype))
-        vp = vp.at[dest, off].set(vn.astype(vp.dtype))
-        ik = dsa_mod.indexer_k(p["indexer"], hf, flat_pos,
-                               dim=cfg.dsa.indexer_dim,
-                               rope_base=cfg.rope_base)
-        ik = ik.reshape(b, d1, cfg.dsa.indexer_dim)
-        idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
-        idx_kc = idx_kp[gather_local].reshape(b, n_local,
-                                              cfg.dsa.indexer_dim)
+        with jax.named_scope("step.qkv"):
+            h = rms_norm(x, p["ln1"])                    # (B, Q, D)
+            hf = h.reshape(b * d1, -1)
+            q, kn, vn = _project_qkv(p, hf, b * d1, flat_pos, cfg, None)
+            q = q.reshape(b, d1, cfg.n_heads, hd)
+            kn = kn.reshape(b, d1, cfg.n_kv_heads, hd)
+            vn = vn.reshape(b, d1, cfg.n_kv_heads, hd)
+        with jax.named_scope("step.indexer"):
+            ik = dsa_mod.indexer_k(p["indexer"], hf, flat_pos,
+                                   dim=cfg.dsa.indexer_dim,
+                                   rope_base=cfg.rope_base)
+            ik = ik.reshape(b, d1, cfg.dsa.indexer_dim)
+        with jax.named_scope("step.kv_write"):
+            kp = kp.at[dest, off].set(kn.astype(kp.dtype))
+            vp = vp.at[dest, off].set(vn.astype(vp.dtype))
+            idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
+        with jax.named_scope("step.indexer"):
+            idx_kc = idx_kp[gather_local].reshape(b, n_local,
+                                                  cfg.dsa.indexer_dim)
 
         def sel_row(cr, inp):
             prev, valid = cr
@@ -1422,31 +1503,32 @@ def _sp_paged_verify_mq_body(params, state, tokens, draft_len, max_accept,
                 max_candidates=cfg.dsa.max_candidates,
                 swa_window=cfg.swa_window, seq_axis=seq_axis)
             return ((res.new_topk, jnp.ones_like(valid)),
-                    (res.attn_out, res.new_topk, res.gvr_rows))
+                    (res.attn_out, res.new_topk, sel_telemetry(res, valid)))
 
         valid0 = (topk_valid if topk_valid is not None
                   else jnp.ones((b,), bool))
-        _, (attn_all, idx_all, gvr_all) = jax.lax.scan(
+        _, (attn_all, idx_all, tele) = jax.lax.scan(
             sel_row, (prev_topk, valid0),
             (jnp.swapaxes(q, 0, 1), jnp.swapaxes(h, 0, 1),
              jnp.swapaxes(lengths_q, 0, 1)))
 
         out = {"k_pages": kp, "v_pages": vp, "idx_k_pages": idx_kp,
                "sel_idx": idx_all,                       # (Q, B, K)
-               "sel_valid": jnp.ones((d1, b), bool),
-               "sel_gvr": gvr_all}                       # (Q, B)
-        attn = jnp.swapaxes(attn_all, 0, 1)              # (B, Q, H, HD)
-        attn = attn.reshape(b, d1, cfg.n_heads * hd).astype(x.dtype)
-        x = x + attn @ p["wo"]
-        h2 = rms_norm(x, p["ln2"])
-        if cfg.moe.num_experts:
-            mo = jax.lax.map(
-                lambda hh: _mlp(p, hh[:, None, :], cfg, None)[:, 0],
-                jnp.swapaxes(h2, 0, 1))
-            m = jnp.swapaxes(mo, 0, 1)
-        else:
-            m = _mlp(p, h2, cfg, None)
-        x = x + m
+               "sel_valid": jnp.ones((d1, b), bool), **tele}   # (Q, B)
+        with jax.named_scope("step.attention"):
+            attn = jnp.swapaxes(attn_all, 0, 1)          # (B, Q, H, HD)
+            attn = attn.reshape(b, d1, cfg.n_heads * hd).astype(x.dtype)
+            x = x + attn @ p["wo"]
+        with jax.named_scope("step.mlp"):
+            h2 = rms_norm(x, p["ln2"])
+            if cfg.moe.num_experts:
+                mo = jax.lax.map(
+                    lambda hh: _mlp(p, hh[:, None, :], cfg, None)[:, 0],
+                    jnp.swapaxes(h2, 0, 1))
+                m = jnp.swapaxes(mo, 0, 1)
+            else:
+                m = _mlp(p, h2, cfg, None)
+            x = x + m
         return x, out
 
     carry_in = {"p": params["layers"],
@@ -1457,15 +1539,13 @@ def _sp_paged_verify_mq_body(params, state, tokens, draft_len, max_accept,
     if "topk_valid" in state:
         carry_in["topk_valid"] = state["topk_valid"]
     x, outs = jax.lax.scan(layer, x, carry_in)
-
-    x = rms_norm(x, params["final_norm"])
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head).astype(jnp.float32)              # (B, Q, V)
+    logits = _lm_head(params, x, cfg)                    # (B, Q, V)
 
     ys = {"logits": jnp.transpose(logits, (1, 0, 2)),
           "prev_topk": jnp.swapaxes(outs["sel_idx"], 0, 1),
           "topk_valid": jnp.swapaxes(outs["sel_valid"], 0, 1),
-          "sel_gvr": jnp.swapaxes(outs["sel_gvr"], 0, 1)}
+          "sel_gvr": jnp.swapaxes(outs["sel_gvr"], 0, 1),
+          "sel_counts": sel_counts(outs, b)}              # (Q, B, 3)
     end_state = dict(state)
     for key in ("k_pages", "v_pages", "idx_k_pages"):
         end_state[key] = outs[key][:, None]              # restore shard axis
@@ -1525,6 +1605,7 @@ def serve_step_sp_spec_paged(params, state, tokens, cfg: ModelConfig, *,
     param_spec = jax.tree.map(lambda _: P(), params)
     fn = jax.shard_map(body, mesh=mesh,
                    in_specs=(param_spec, st_spec, P(), P(), P(), P()),
-                   out_specs=(P(), P(), P(), P(), st_spec), check_vma=False)
+                   out_specs=(P(), P(), P(), P(), P(), st_spec),
+                   check_vma=False)
     return fn(params, state, tokens, jnp.asarray(draft_len, jnp.int32),
               jnp.asarray(max_accept, jnp.int32), base_mwp)

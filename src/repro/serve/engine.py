@@ -108,6 +108,15 @@ from .feedback_pool import FeedbackPool
 from .paged import PagedKVManager, PoolExhausted, ShardedPagedKVManager
 from .scheduler import DECODE, DONE, PREFILL, QUEUED, Scheduler, make_scheduler
 
+# the on-device GVR counters, in the order of the state's `gvr_counters` leaf
+GVR_COUNTERS = ("gvr_row_layers", "gvr_secant_iters", "gvr_fallbacks")
+
+
+def _add_counts(total, counts, active):
+    """The pool-global counters plus the active rows' GVR counts (B, 3)
+    of one step (the model's `sel_counts`)."""
+    return total + jnp.sum(jnp.where(active[:, None], counts, 0), axis=0)
+
 
 @dataclasses.dataclass(eq=False)       # identity equality: the scheduler
 class Request:                         # queue must never compare ndarray fields
@@ -188,6 +197,13 @@ class EngineReport:
       position j-1's selection inside the tick, so this list is the
       paper's "how does the prev-Top-K hit rate degrade with draft depth"
       measurement (BENCH_spec.json records it per depth).
+    * `gvr_secant_iters_mean` / `gvr_fallbacks` — from the on-device GVR
+      counters (`DecodeEngine.counters()`): the secant iterations (the
+      paper's I, which it expects to be 1–2) per row-layer of an active
+      slot that the GVR path served in the window — prefill and decode
+      steps alike, and every executed verify position under speculation
+      — and how many of those row-layers fell back to GVR's safety net.
+      The operator's view of whether the temporal prediction still holds.
     * `peak_page_utilization` — max utilization of the MOST-PRESSURED
       pool over the window's ticks (the single pool, or the hottest
       shard's pool under `seq_shards` — an aggregate ratio could read
@@ -210,6 +226,8 @@ class EngineReport:
     spec_accepted: int = 0                         # draft tokens accepted
     gvr_hit_rate_by_draft_pos: List[float] = dataclasses.field(
         default_factory=list)
+    gvr_secant_iters_mean: float = 0.0             # per GVR row-layer
+    gvr_fallbacks: int = 0                         # GVR row-layers
 
     @property
     def tokens_per_s(self) -> float:
@@ -385,6 +403,8 @@ class DecodeEngine:
             self._axes = axes
             self.kv = None
             self.state = model.init_decode_state(self.num_slots, self.max_len)
+        # pool-global (no slot axis, so the row merge passes it whole)
+        self.state["gvr_counters"] = self._zero_counters()
 
         # speculative decoding (serve.spec): the drafter proposes up to
         # spec_depth tokens per DECODE slot per tick; the verify tick
@@ -437,18 +457,22 @@ class DecodeEngine:
 
     # ---- jitted kernels -------------------------------------------------
 
-    def _serve_step(self, params, state, tokens, min_write_pos=None):
-        """Layout dispatch: one model step over the given (sub-)pool."""
+    def _serve_step(self, params, state, tokens, min_write_pos=None,
+                    with_counts=False):
+        """Layout dispatch: one model step over the given (sub-)pool.
+        `with_counts` adds the rows' GVR counts (B, 3) as a third result."""
         if self.seq_shards > 1:
             return self.model.serve_step_sp_paged(
                 params, state, tokens, min_write_pos=min_write_pos,
-                mesh=self.mesh)
+                mesh=self.mesh, with_counts=with_counts)
         if self.kv is not None:
             return self.model.serve_step_paged(
                 params, state, tokens, min_write_pos=min_write_pos,
                 paged_attn=self.paged_attn,
-                gather_granularity=self.gather_granularity)
-        return self.model.serve_step(params, state, tokens)
+                gather_granularity=self.gather_granularity,
+                with_counts=with_counts)
+        return self.model.serve_step(params, state, tokens,
+                                     with_counts=with_counts)
 
     def _merge_active(self, new_state, state, active):
         """Keep `new_state` only for active rows; pool-global leaves (the
@@ -466,22 +490,39 @@ class DecodeEngine:
             merged[key] = jnp.where(active.reshape(shape), arr, state[key])
         return merged
 
+    def _zero_counters(self):
+        zeros = jnp.zeros((len(GVR_COUNTERS),), jnp.int32)
+        if self.seq_shards > 1:
+            # replicated over the mesh like the rest of the small state: a
+            # leaf on one device would be another step signature
+            zeros = jax.device_put(zeros,
+                                   NamedSharding(self.mesh, PartitionSpec()))
+        return zeros
+
     def _tick_impl(self, params, state, tokens, active, min_write_pos):
         """One pool-wide step, for decode and prefill alike: active rows
         take one token each, inactive rows keep their old state. Paged
         layout: inactive rows additionally redirect their cache write to
         the sink page (pool-global page leaves can't be row-merged), and
         active rows skip it below `min_write_pos` — the shared-prefix
-        replay must not touch pages it shares. Returns the merged state,
-        the argmax tokens, the logits and which active rows the GVR path
-        served."""
+        replay must not touch pages it shares. The active rows' GVR
+        row-layers, secant iterations and fallbacks add to the state's
+        `gvr_counters`. Returns the merged state, the argmax tokens, the
+        logits and which active rows the GVR path served."""
         mwp = (jnp.where(active, min_write_pos, jnp.int32(PAGED_NEVER_WRITE))
                if self.kv is not None else None)
-        logits, new_state = self._serve_step(params, state, tokens, mwp)
-        merged = self._merge_active(new_state, state, active)
+        if "sel_gvr" not in state:
+            logits, new_state = self._serve_step(params, state, tokens, mwp)
+            merged = self._merge_active(new_state, state, active)
+            gvr = jnp.zeros_like(active)
+        else:
+            logits, new_state, counts = self._serve_step(
+                params, state, tokens, mwp, with_counts=True)
+            merged = self._merge_active(new_state, state, active)
+            merged["gvr_counters"] = _add_counts(state["gvr_counters"],
+                                                 counts, active)
+            gvr = new_state["sel_gvr"][0] & active
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        gvr = (new_state["sel_gvr"][0] & active if "sel_gvr" in new_state
-               else jnp.zeros_like(active))
         return merged, next_tok, logits, gvr
 
     def _tick_spec_impl(self, params, state, tokens, active, draft_len,
@@ -505,8 +546,10 @@ class DecodeEngine:
                 max_accept=max_accept, eos_id=eos, min_write_pos=mwp,
                 paged_attn=self.paged_attn, verify_kernel=self.verify_kernel,
                 gather_granularity=self.gather_granularity)
-        out_tokens, accept_len, logits_all, sel_pos, new_state = out
+        out_tokens, accept_len, logits_all, sel_pos, counts, new_state = out
         merged = self._merge_active(new_state, state, active)
+        merged["gvr_counters"] = _add_counts(state["gvr_counters"], counts,
+                                             active)
         return merged, out_tokens, accept_len, logits_all, sel_pos
 
     # ---- host-side lifecycle --------------------------------------------
@@ -529,6 +572,25 @@ class DecodeEngine:
                     f"{self.kv.sizing_error(total)} — it could never admit")
         self.method_log.setdefault(request.uid, [])
         self.scheduler.submit(request)
+
+    def counters(self) -> Dict[str, int]:
+        """The on-device GVR counters since the last read, as Python ints,
+        which are then reset (keys: GVR_COUNTERS). `gvr_row_layers` counts
+        the row-layers of active slots whose Top-K the GVR path served,
+        `gvr_secant_iters` their secant iterations, `gvr_fallbacks` those
+        that fell back to GVR's safety net. Every step adds to them on the
+        device; a read is their only transfer, so read them at the edges
+        of a measured window, not per tick (`run()` reads them at its
+        start and end).
+
+        The leaf is int32. A step adds at most 12 iterations
+        (DEFAULT_MAX_SECANT) per row-layer, so at the chat cell's shape and
+        rate (24 layers, 64 slots, ~10 pool-wide steps a second) it holds
+        at least 3.4 hours between reads, and at the decode cell's (7
+        layers, 4 slots, ~33 steps a second) at least 53 hours."""
+        values = np.asarray(self.state["gvr_counters"])
+        self.state["gvr_counters"] = self._zero_counters()
+        return {key: int(v) for key, v in zip(GVR_COUNTERS, values)}
 
     def _log(self, req: Request, method: str) -> None:
         self.method_log[req.uid].append((self.tick_count, req.phase, method))
@@ -812,73 +874,80 @@ class DecodeEngine:
         wanting = [(s, req) for s, req in enumerate(self.slots)
                    if req is not None and req.phase == DECODE]
         drafts: Dict[int, List[int]] = self._collect_drafts(wanting)
-        for s in list(drafts):
-            req = self.slots[s]
-            if req is None or req.phase != DECODE:
-                drafts.pop(s)          # preempted while mapping another slot
-                continue
-            pos0 = len(req.prompt) + len(req.generated) - 1
-            for pos in range(pos0, pos0 + len(drafts[s]) + 1):
-                self._ensure_decode_page(s, pos)
-        self._push_page_table()
-        active = np.array([r is not None and r.phase == DECODE
-                           for r in self.slots])
-        if not active.any():
-            return
-        tokens = np.zeros((self.num_slots, d1), np.int32)
-        draft_len = np.zeros((self.num_slots,), np.int32)
-        max_accept = np.zeros((self.num_slots,), np.int32)
-        for s, req in enumerate(self.slots):
-            if not active[s]:
-                continue
-            draft = drafts.get(s, [])
-            tokens[s, 0] = req.generated[-1]
-            tokens[s, 1:1 + len(draft)] = draft
-            draft_len[s] = len(draft)
-            max_accept[s] = req.max_new_tokens - len(req.generated) - 1
-        self.state, out_tokens, accept_len, logits_all, sel_pos = \
-            self._spec_fn(self.params, self.state, jnp.asarray(tokens),
-                          jnp.asarray(active), jnp.asarray(draft_len),
-                          jnp.asarray(max_accept))
-        out_tokens = np.asarray(out_tokens)
-        accept_len = np.asarray(accept_len)
-        sel_pos = np.asarray(sel_pos)
-        logits_np = np.asarray(logits_all) if self.record_logits else None
-        for s, req in enumerate(self.slots):
-            if not active[s]:
-                continue
-            a = int(accept_len[s])
-            dlen = int(draft_len[s])
-            for p in range(a + 1):
-                # accepted positions map one-to-one to non-spec ticks:
-                # log the selector path that really served each
-                self._log(req, self._method_name(bool(sel_pos[s, p])))
-                if p == 0:
-                    # position 0 is the ordinary next-token step; sampled
-                    # requests (always depth 0) draw from its logits
-                    tok = self._next_token(req, int(out_tokens[s, 0]),
-                                           logits_all[s, 0])
-                else:
-                    tok = int(out_tokens[s, p])
-                req.generated.append(tok)
-                if self.record_logits:
-                    # copy: a view would pin the whole per-tick
-                    # (num_slots, d+1, vocab) block for the log's lifetime
-                    req.logits_log.append(logits_np[s, p].copy())
-                self.decoded_tokens += 1
-            # telemetry: every EXECUTED position (accepted or wasted)
-            if dlen > 0:
-                self.spec_ticks += 1
-                self.spec_drafted += dlen
-                self.spec_accepted += a
-            for j in range(dlen + 1):
-                self._spec_pos_total[j] += 1
-                self._spec_pos_hits[j] += bool(sel_pos[s, j])
-            # page-cursor rewind: drop pages mapped past the accepted
-            # prefix — rollback exactness vs non-speculative decode
-            self.kv.rewind_slot(s, int(len(req.prompt) + len(req.generated)
-                                       - 1))
-            self._maybe_finish(s)
+        with jax.profiler.TraceAnnotation("engine.pages"):
+            for s in list(drafts):
+                req = self.slots[s]
+                if req is None or req.phase != DECODE:
+                    drafts.pop(s)      # preempted while mapping another slot
+                    continue
+                pos0 = len(req.prompt) + len(req.generated) - 1
+                for pos in range(pos0, pos0 + len(drafts[s]) + 1):
+                    self._ensure_decode_page(s, pos)
+            self._push_page_table()
+        with jax.profiler.TraceAnnotation("engine.dispatch"):
+            active = np.array([r is not None and r.phase == DECODE
+                               for r in self.slots])
+            if not active.any():
+                return
+            tokens = np.zeros((self.num_slots, d1), np.int32)
+            draft_len = np.zeros((self.num_slots,), np.int32)
+            max_accept = np.zeros((self.num_slots,), np.int32)
+            for s, req in enumerate(self.slots):
+                if not active[s]:
+                    continue
+                draft = drafts.get(s, [])
+                tokens[s, 0] = req.generated[-1]
+                tokens[s, 1:1 + len(draft)] = draft
+                draft_len[s] = len(draft)
+                max_accept[s] = req.max_new_tokens - len(req.generated) - 1
+            self.state, out_tokens, accept_len, logits_all, sel_pos = \
+                self._spec_fn(self.params, self.state, jnp.asarray(tokens),
+                              jnp.asarray(active), jnp.asarray(draft_len),
+                              jnp.asarray(max_accept))
+        with jax.profiler.TraceAnnotation("engine.readback"):
+            out_tokens = np.asarray(out_tokens)
+            accept_len = np.asarray(accept_len)
+            sel_pos = np.asarray(sel_pos)
+            logits_np = (np.asarray(logits_all) if self.record_logits
+                         else None)
+        with jax.profiler.TraceAnnotation("engine.emit"):
+            for s, req in enumerate(self.slots):
+                if not active[s]:
+                    continue
+                a = int(accept_len[s])
+                dlen = int(draft_len[s])
+                for p in range(a + 1):
+                    # accepted positions map one-to-one to non-spec ticks:
+                    # log the selector path that really served each
+                    self._log(req, self._method_name(bool(sel_pos[s, p])))
+                    if p == 0:
+                        # position 0 is the ordinary next-token step;
+                        # sampled requests (always depth 0) draw from its
+                        # logits
+                        tok = self._next_token(req, int(out_tokens[s, 0]),
+                                               logits_all[s, 0])
+                    else:
+                        tok = int(out_tokens[s, p])
+                    req.generated.append(tok)
+                    if self.record_logits:
+                        # copy: a view would pin the whole per-tick
+                        # (num_slots, d+1, vocab) block for the log's
+                        # lifetime
+                        req.logits_log.append(logits_np[s, p].copy())
+                    self.decoded_tokens += 1
+                # telemetry: every EXECUTED position (accepted or wasted)
+                if dlen > 0:
+                    self.spec_ticks += 1
+                    self.spec_drafted += dlen
+                    self.spec_accepted += a
+                for j in range(dlen + 1):
+                    self._spec_pos_total[j] += 1
+                    self._spec_pos_hits[j] += bool(sel_pos[s, j])
+                # page-cursor rewind: drop pages mapped past the accepted
+                # prefix — rollback exactness vs non-speculative decode
+                self.kv.rewind_slot(s, int(len(req.prompt)
+                                           + len(req.generated) - 1))
+                self._maybe_finish(s)
 
     def _decode_tick(self) -> None:
         if self.spec_depth > 0:
@@ -886,67 +955,88 @@ class DecodeEngine:
         if self.kv is not None:
             # map (and COW-protect) each DECODE slot's write page up front;
             # pool pressure may preempt PREFILL slots here
+            with jax.profiler.TraceAnnotation("engine.pages"):
+                for s, req in enumerate(self.slots):
+                    if req is None or req.phase != DECODE:
+                        continue
+                    pos = len(req.prompt) + len(req.generated) - 1
+                    self._ensure_decode_page(s, pos)
+                self._push_page_table()
+        with jax.profiler.TraceAnnotation("engine.dispatch"):
+            active = np.array([r is not None and r.phase == DECODE
+                               for r in self.slots])
+            if not active.any():
+                return
+            tokens = np.zeros((self.num_slots,), np.int32)
             for s, req in enumerate(self.slots):
-                if req is None or req.phase != DECODE:
+                if active[s]:
+                    tokens[s] = req.generated[-1]
+            self.state, next_tok, _logits, sel_gvr = self._tick_fn(
+                self.params, self.state, jnp.asarray(tokens),
+                jnp.asarray(active), jnp.zeros((self.num_slots,), jnp.int32))
+        with jax.profiler.TraceAnnotation("engine.readback"):
+            next_tok = np.asarray(next_tok)
+            sel_gvr = np.asarray(sel_gvr)
+        with jax.profiler.TraceAnnotation("engine.emit"):
+            for s, req in enumerate(self.slots):
+                if not active[s]:
                     continue
-                pos = len(req.prompt) + len(req.generated) - 1
-                self._ensure_decode_page(s, pos)
-            self._push_page_table()
-        active = np.array([r is not None and r.phase == DECODE
-                           for r in self.slots])
-        if not active.any():
-            return
-        tokens = np.zeros((self.num_slots,), np.int32)
-        for s, req in enumerate(self.slots):
-            if active[s]:
-                tokens[s] = req.generated[-1]
-        self.state, next_tok, _logits, sel_gvr = self._tick_fn(
-            self.params, self.state, jnp.asarray(tokens), jnp.asarray(active),
-            jnp.zeros((self.num_slots,), jnp.int32))
-        next_tok = np.asarray(next_tok)
-        sel_gvr = np.asarray(sel_gvr)
-        for s, req in enumerate(self.slots):
-            if not active[s]:
-                continue
-            self._log(req, self._method_name(bool(sel_gvr[s])))
-            req.generated.append(self._next_token(req, int(next_tok[s]),
-                                                  _logits[s]))
-            if self.record_logits:
-                req.logits_log.append(np.asarray(_logits[s]))
-            self.decoded_tokens += 1
-            self._maybe_finish(s)
+                self._log(req, self._method_name(bool(sel_gvr[s])))
+                req.generated.append(self._next_token(req, int(next_tok[s]),
+                                                      _logits[s]))
+                if self.record_logits:
+                    req.logits_log.append(np.asarray(_logits[s]))
+                self.decoded_tokens += 1
+                self._maybe_finish(s)
 
     def _maybe_finish(self, slot: int) -> None:
         req = self.slots[slot]
         if (len(req.generated) >= req.max_new_tokens
                 or (self.eos_id is not None
                     and req.generated[-1] == self.eos_id)):
-            req.phase = DONE
-            req.finished_at = self.tick_count
-            if self.kv is not None:
-                self.kv.release_slot(slot)
-            self.state = self.pool.evict(self.state, slot)
-            self.slots[slot] = None
-            if self.drafter is not None:
-                self.drafter.release(req.uid)
-            self.completed.append(req)
+            # a span per retirement: once in a request's life, not per tick
+            with jax.profiler.TraceAnnotation("engine.retire"):
+                req.phase = DONE
+                req.finished_at = self.tick_count
+                if self.kv is not None:
+                    self.kv.release_slot(slot)
+                self.state = self.pool.evict(self.state, slot)
+                self.slots[slot] = None
+                if self.drafter is not None:
+                    self.drafter.release(req.uid)
+                self.completed.append(req)
 
     def tick(self) -> None:
-        """One engine tick: admit → chunked prefill → pool decode → retire."""
-        self._admit()
-        # occupancy of the serving work this tick: measured post-admission,
-        # pre-retirement (a slot admitted and one retiring this same tick
-        # are both genuinely served by it)
-        self.peak_occupancy = max(self.peak_occupancy,
-                                  sum(r is not None for r in self.slots))
-        self._prefill_tick()
-        self._decode_tick()
-        if self.kv is not None:
-            self.peak_pages_in_use = max(self.peak_pages_in_use,
-                                         self.kv.pages_in_use)
-            self.peak_pool_util = max(self.peak_pool_util,
-                                      self.kv.hot_pool_utilization)
-        self.tick_count += 1
+        """One engine tick: admit → chunked prefill → pool decode → retire.
+
+        The tick is a profiler step span (`engine.tick`), and its phases
+        spans inside it: `engine.admit`, `engine.prefill`, `engine.pages`
+        (mapping the decode pages, pushing the block table),
+        `engine.dispatch` (the step's inputs and its call),
+        `engine.readback` (tokens and GVR telemetry to the host) and
+        `engine.emit` (per-slot bookkeeping), with `engine.retire` around
+        each retirement inside it. A trace puts them on the host's clock
+        beside the device's ops, so each stretch of idle device time can
+        be laid to a phase; with no profiler running a span costs about a
+        microsecond."""
+        with jax.profiler.StepTraceAnnotation("engine.tick",
+                                              step_num=self.tick_count):
+            with jax.profiler.TraceAnnotation("engine.admit"):
+                self._admit()
+            # occupancy of the serving work this tick: measured
+            # post-admission, pre-retirement (a slot admitted and one
+            # retiring this same tick are both genuinely served by it)
+            self.peak_occupancy = max(self.peak_occupancy,
+                                      sum(r is not None for r in self.slots))
+            with jax.profiler.TraceAnnotation("engine.prefill"):
+                self._prefill_tick()
+            self._decode_tick()
+            if self.kv is not None:
+                self.peak_pages_in_use = max(self.peak_pages_in_use,
+                                             self.kv.pages_in_use)
+                self.peak_pool_util = max(self.peak_pool_util,
+                                          self.kv.hot_pool_utilization)
+            self.tick_count += 1
 
     def idle(self) -> bool:
         return (all(r is None for r in self.slots)
@@ -975,9 +1065,11 @@ class DecodeEngine:
         start_spec = (self.spec_ticks, self.spec_drafted, self.spec_accepted)
         start_pos_hits = self._spec_pos_hits.copy()
         start_pos_total = self._spec_pos_total.copy()
+        self.counters()                    # drop what came before the run
         while not self.idle() and self.tick_count - start_tick < max_ticks:
             self.tick()
         wall = time.perf_counter() - t0
+        gvr = self.counters()
         # report THIS run's window only — the engine may be reused
         combined: Dict[str, int] = {}
         by_phase: Dict[str, Dict[str, int]] = {PREFILL: {}, DECODE: {}}
@@ -1007,4 +1099,8 @@ class DecodeEngine:
             spec_accepted=self.spec_accepted - start_spec[2],
             gvr_hit_rate_by_draft_pos=[
                 float(h) / float(t) if t else 0.0
-                for h, t in zip(pos_hits, pos_total)])
+                for h, t in zip(pos_hits, pos_total)],
+            gvr_secant_iters_mean=(
+                gvr["gvr_secant_iters"] / gvr["gvr_row_layers"]
+                if gvr["gvr_row_layers"] else 0.0),
+            gvr_fallbacks=gvr["gvr_fallbacks"])

@@ -89,6 +89,7 @@ class DSAOutput(NamedTuple):
     topk_idx: jnp.ndarray      # (B, K) int32 — next step's prediction
     secant_iters: Optional[jnp.ndarray]
     gvr_rows: Optional[jnp.ndarray] = None   # (B,) bool — selector path taken
+    fallback: Optional[jnp.ndarray] = None   # (B,) bool — GVR safety net ran
 
 
 def dsa_sparse_attention(q: jnp.ndarray, kcache: jnp.ndarray, vcache: jnp.ndarray,
@@ -296,20 +297,24 @@ def dsa_select(indexer_params, x: jnp.ndarray, idx_kcache: jnp.ndarray,
                swa_window: Optional[int] = None, rules=None, mesh=None):
     """Indexer scoring + Top-K selection (the layout-independent front half
     of the DSA pipeline — shared by the logical-view and paged attention
-    forms, which is what keeps them bit-identical)."""
+    forms, which is what keeps them bit-identical). The two stages carry
+    the decode step's `step.indexer` and `step.topk` scopes."""
     positions = lengths - 1
-    scores = indexer_scores(indexer_params, x, idx_kcache, positions, lengths,
-                            heads=heads, dim=dim, rope_base=rope_base,
-                            rules=rules)
-    if swa_window is not None:
-        # SWA interplay: selection restricted to the attention window
-        pos = jnp.arange(scores.shape[-1], dtype=jnp.int32)
-        in_win = pos[None, :] > (lengths[:, None] - 1 - swa_window)
-        scores = jnp.where(in_win, scores, NEG)
-    return select_topk(scores, k, prev_idx=prev_topk, prev_valid=prev_valid,
-                       method=selector,
-                       max_candidates=max_candidates, gate_max_n=gate_max_n,
-                       min_n_for_selection=min_n, mesh=mesh)
+    with jax.named_scope("step.indexer"):
+        scores = indexer_scores(indexer_params, x, idx_kcache, positions,
+                                lengths, heads=heads, dim=dim,
+                                rope_base=rope_base, rules=rules)
+        if swa_window is not None:
+            # SWA interplay: selection restricted to the attention window
+            pos = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+            in_win = pos[None, :] > (lengths[:, None] - 1 - swa_window)
+            scores = jnp.where(in_win, scores, NEG)
+    with jax.named_scope("step.topk"):
+        return select_topk(scores, k, prev_idx=prev_topk,
+                           prev_valid=prev_valid, method=selector,
+                           max_candidates=max_candidates,
+                           gate_max_n=gate_max_n, min_n_for_selection=min_n,
+                           mesh=mesh)
 
 
 def dsa_decode(q: jnp.ndarray, kcache: jnp.ndarray, vcache: jnp.ndarray,
@@ -336,9 +341,11 @@ def dsa_decode(q: jnp.ndarray, kcache: jnp.ndarray, vcache: jnp.ndarray,
                      max_candidates=max_candidates, gate_max_n=gate_max_n,
                      min_n=min_n, swa_window=swa_window, rules=rules,
                      mesh=mesh)
-    out = dsa_sparse_attention(q, kcache, vcache, sel.indices, lengths,
-                               scale=scale, rules=rules)
-    return DSAOutput(out, sel.indices, sel.secant_iters, sel.gvr_rows)
+    with jax.named_scope("step.attention"):
+        out = dsa_sparse_attention(q, kcache, vcache, sel.indices, lengths,
+                                   scale=scale, rules=rules)
+    return DSAOutput(out, sel.indices, sel.secant_iters, sel.gvr_rows,
+                     sel.fallback)
 
 
 def dsa_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -369,8 +376,10 @@ def dsa_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                      max_candidates=max_candidates, gate_max_n=gate_max_n,
                      min_n=min_n, swa_window=swa_window, rules=rules,
                      mesh=mesh)
-    out = dsa_sparse_attention_paged(q, k_pages, v_pages, table, sel.indices,
-                                     lengths, scale=scale,
-                                     granularity=gather_granularity,
-                                     rules=rules)
-    return DSAOutput(out, sel.indices, sel.secant_iters, sel.gvr_rows)
+    with jax.named_scope("step.attention"):
+        out = dsa_sparse_attention_paged(q, k_pages, v_pages, table,
+                                         sel.indices, lengths, scale=scale,
+                                         granularity=gather_granularity,
+                                         rules=rules)
+    return DSAOutput(out, sel.indices, sel.secant_iters, sel.gvr_rows,
+                     sel.fallback)

@@ -54,6 +54,7 @@ class SelectorOutput(NamedTuple):
     method: str                  # resolved method (trace-time)
     secant_iters: Optional[jnp.ndarray] = None
     gvr_rows: Optional[jnp.ndarray] = None   # (B,) bool — rows the GVR path served
+    fallback: Optional[jnp.ndarray] = None   # (B,) bool — GVR's safety net ran
 
 
 def _masked_scores(scores, lengths):
@@ -107,12 +108,15 @@ def select_topk(scores: jnp.ndarray, k: int, *,
                 g = r.gvr_rows
                 if g is None:
                     g = jnp.zeros((s_.shape[0],), bool)
-                return r.indices, r.values, it, g
+                fb = r.fallback
+                if fb is None:
+                    fb = jnp.zeros((s_.shape[0],), bool)
+                return r.indices, r.values, it, g, fb
 
-            idx, vals, iters, gvr_rows = jax.shard_map(
+            idx, vals, iters, gvr_rows, fallback = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(bspec, P(axes), bspec, P(axes)),
-                out_specs=(bspec, bspec, P(axes), P(axes)),
+                out_specs=(bspec, bspec, P(axes), P(axes), P(axes)),
                 check_vma=False,
             )(scores,
               lengths if lengths is not None else
@@ -127,7 +131,8 @@ def select_topk(scores: jnp.ndarray, k: int, *,
                         else "sharded")
             if has_valid and resolved == "gvr":
                 resolved = "mixed"
-            return SelectorOutput(idx, vals, resolved, iters, gvr_rows)
+            return SelectorOutput(idx, vals, resolved, iters, gvr_rows,
+                                  fallback)
 
     n = scores.shape[-1]
     b = scores.shape[0]
@@ -147,7 +152,7 @@ def select_topk(scores: jnp.ndarray, k: int, *,
                               max_candidates=max_candidates)
         vals, idx = extract_topk(scores, stats.threshold, k, lengths=lengths)
         return SelectorOutput(idx, vals, "gvr", stats.secant_iters,
-                              jnp.ones((b,), bool))
+                              jnp.ones((b,), bool), stats.fallback)
     if method == "mixed":
         assert prev_idx is not None, "mixed dispatch needs a prediction signal"
         assert prev_valid is not None, "mixed dispatch needs prev_valid"
@@ -160,11 +165,12 @@ def select_topk(scores: jnp.ndarray, k: int, *,
         idx = jnp.where(warm[:, None], g_idx, r_idx)
         vals = jnp.where(warm[:, None], g_vals, r_vals)
         iters = jnp.where(warm, stats.secant_iters, st.passes)
-        return SelectorOutput(idx, vals, "mixed", iters, warm)
+        return SelectorOutput(idx, vals, "mixed", iters, warm,
+                              stats.fallback & warm)
     if method == "radix":
         vals, idx, st = radix_select_topk(_masked_scores(scores, lengths), k)
         return SelectorOutput(idx, vals, "radix", st.passes,
-                              jnp.zeros((b,), bool))
+                              jnp.zeros((b,), bool), jnp.zeros((b,), bool))
     if method == "exact":
         import jax
         vals, idx = jax.lax.top_k(_masked_scores(scores, lengths), k)
@@ -176,5 +182,5 @@ def select_topk(scores: jnp.ndarray, k: int, *,
         idx = jnp.take_along_axis(idx, order, axis=-1)
         vals = jnp.take_along_axis(vals, order, axis=-1)
         return SelectorOutput(idx.astype(jnp.int32), vals, "exact", None,
-                              jnp.zeros((b,), bool))
+                              jnp.zeros((b,), bool), jnp.zeros((b,), bool))
     raise ValueError(f"unknown selector method {method!r}")

@@ -130,6 +130,7 @@ class SPDSAPagedResult(NamedTuple):
                               # canonical ascending order)
     secant_iters: jnp.ndarray  # (B,) int32 — SP-GVR phase-2 iterations
     gvr_rows: jnp.ndarray     # (B,) bool — rows served off the temporal prior
+    fallback: jnp.ndarray     # (B,) bool — SP-GVR's safety net ran
 
 
 def sp_dsa_decode_paged_local(q, k_pages, v_pages, table_local, idx_params, h,
@@ -197,55 +198,61 @@ def sp_dsa_decode_paged_local(q, k_pages, v_pages, table_local, idx_params, h,
     sink = k_pages.shape[0] - 1
 
     # -- 1. shard-local indexer scores over the local logical view ------
-    # per-position math mirrors dsa.indexer_scores bit-for-bit (contraction
-    # extents are per-position, so the shard slice changes nothing)
-    positions = lengths - 1
-    qi = (h @ idx_params["wq"]).reshape(b, 1, heads, dim)
-    qi = apply_rotary(qi, positions[:, None], kind="rope", base=rope_base)[:, 0]
-    s = jax.nn.relu(jnp.einsum("bhd,bnd->bhn", qi.astype(idx_view_local.dtype),
-                               idx_view_local,
-                               preferred_element_type=jnp.float32))
-    scores = jnp.einsum("h,bhn->bn", idx_params["w"].astype(jnp.float32), s)
-    gpos = jnp.arange(n_local, dtype=jnp.int32)[None, :] + shard_offset
-    scores = jnp.where(gpos < lengths[:, None], scores, NEG)
-    if swa_window is not None:
-        in_win = gpos > (lengths[:, None] - 1 - swa_window)
-        scores = jnp.where(in_win, scores, NEG)
+    with jax.named_scope("step.indexer"):
+        # per-position math mirrors dsa.indexer_scores bit-for-bit
+        # (contraction extents are per-position, so the shard slice
+        # changes nothing)
+        positions = lengths - 1
+        qi = (h @ idx_params["wq"]).reshape(b, 1, heads, dim)
+        qi = apply_rotary(qi, positions[:, None], kind="rope",
+                          base=rope_base)[:, 0]
+        s = jax.nn.relu(jnp.einsum("bhd,bnd->bhn",
+                                   qi.astype(idx_view_local.dtype),
+                                   idx_view_local,
+                                   preferred_element_type=jnp.float32))
+        scores = jnp.einsum("h,bhn->bn", idx_params["w"].astype(jnp.float32), s)
+        gpos = jnp.arange(n_local, dtype=jnp.int32)[None, :] + shard_offset
+        scores = jnp.where(gpos < lengths[:, None], scores, NEG)
+        if swa_window is not None:
+            in_win = gpos > (lengths[:, None] - 1 - swa_window)
+            scores = jnp.where(in_win, scores, NEG)
 
     # -- 2./3. SP-GVR exact distributed Top-K → canonical global buffer --
-    d = jax.lax.axis_size(seq_axis)
-    n = n_local * d
-    sel = sp_gvr_topk_local(scores, prev_topk, k, seq_axis,
-                            max_candidates=max_candidates)
-    topk = sp_canonical_topk(sel.local_indices, k, n, seq_axis)   # (B, K)
+    with jax.named_scope("step.topk"):
+        d = jax.lax.axis_size(seq_axis)
+        n = n_local * d
+        sel = sp_gvr_topk_local(scores, prev_topk, k, seq_axis,
+                                max_candidates=max_candidates)
+        topk = sp_canonical_topk(sel.local_indices, k, n, seq_axis)   # (B, K)
 
     # -- 4. owned-rows paged gather + one O(K) psum assembly -------------
-    rel = topk - shard_offset
-    owned = (rel >= 0) & (rel < n_local)
-    rel_c = jnp.clip(rel, 0, n_local - 1)
-    phys = jnp.take_along_axis(table_local, rel_c // page_size, axis=1)
-    mapped_loc = owned & (phys >= 0)
-    flat = jnp.clip(phys, 0, sink) * page_size + rel_c % page_size  # (B, K)
-    kg = k_pages.reshape((sink + 1) * page_size, kvh, hd)[flat]
-    vg = v_pages.reshape((sink + 1) * page_size, kvh, hd)[flat]
-    hit = mapped_loc[:, :, None, None]
-    kg = jax.lax.psum(jnp.where(hit, kg, jnp.zeros((), kg.dtype)), seq_axis)
-    vg = jax.lax.psum(jnp.where(hit, vg, jnp.zeros((), vg.dtype)), seq_axis)
-    mapped = jax.lax.psum(mapped_loc.astype(jnp.int32), seq_axis) > 0
+    with jax.named_scope("step.attention"):
+        rel = topk - shard_offset
+        owned = (rel >= 0) & (rel < n_local)
+        rel_c = jnp.clip(rel, 0, n_local - 1)
+        phys = jnp.take_along_axis(table_local, rel_c // page_size, axis=1)
+        mapped_loc = owned & (phys >= 0)
+        flat = jnp.clip(phys, 0, sink) * page_size + rel_c % page_size  # (B, K)
+        kg = k_pages.reshape((sink + 1) * page_size, kvh, hd)[flat]
+        vg = v_pages.reshape((sink + 1) * page_size, kvh, hd)[flat]
+        hit = mapped_loc[:, :, None, None]
+        kg = jax.lax.psum(jnp.where(hit, kg, jnp.zeros((), kg.dtype)), seq_axis)
+        vg = jax.lax.psum(jnp.where(hit, vg, jnp.zeros((), vg.dtype)), seq_axis)
+        mapped = jax.lax.psum(mapped_loc.astype(jnp.int32), seq_axis) > 0
 
-    # -- 5. replicated attention over the assembled Top-K rows -----------
-    # mirrors dsa.dsa_sparse_attention_paged: same einsums, same mask
-    logits = jnp.einsum("bkgd,bskd->bkgs", q.reshape(b, kvh, g, hd), kg,
-                        preferred_element_type=jnp.float32) * scale
-    valid = (topk >= 0) & (topk < lengths[:, None]) & mapped
-    logits = jnp.where(valid[:, None, None, :], logits, NEG)
-    pr = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", pr.astype(vg.dtype), vg,
-                     preferred_element_type=jnp.float32)
+        # -- 5. replicated attention over the assembled Top-K rows -----------
+        # mirrors dsa.dsa_sparse_attention_paged: same einsums, same mask
+        logits = jnp.einsum("bkgd,bskd->bkgs", q.reshape(b, kvh, g, hd), kg,
+                            preferred_element_type=jnp.float32) * scale
+        valid = (topk >= 0) & (topk < lengths[:, None]) & mapped
+        logits = jnp.where(valid[:, None, None, :], logits, NEG)
+        pr = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bkgs,bskd->bkgd", pr.astype(vg.dtype), vg,
+                         preferred_element_type=jnp.float32)
     gvr_rows = (prev_valid.astype(bool) if prev_valid is not None
                 else jnp.zeros((b,), bool))
     return SPDSAPagedResult(out.reshape(b, hl, hd), topk,
-                            sel.secant_iters, gvr_rows)
+                            sel.secant_iters, gvr_rows, sel.fallback)
 
 
 def make_sp_dsa(mesh, *, k: int, scale: float, heads: int, dim: int,
