@@ -415,43 +415,46 @@ def _lm_head(params, x, cfg: ModelConfig):
 def sel_telemetry(res, valid) -> Dict[str, jnp.ndarray]:
     """One layer's selector telemetry, (B,) each: which rows the GVR path
     served (`sel_gvr`, kept in the decode state for the engine's method
-    log), and for those rows GVR's secant iterations and whether its
-    safety net ran (`sel_iters`, `sel_fallback`: values of other rows
-    mean nothing; they leave the step only as `sel_counts`). `res` is the
+    log), for those rows GVR's secant iterations and whether its safety
+    net ran (`sel_iters`, `sel_fallback`: values of other rows mean
+    nothing), and which rows had their radix path computed (`sel_radix`);
+    all but `sel_gvr` leave the step only as `sel_counts`. `res` is the
     layer's DSAOutput, SelectorOutput or SPDSAPagedResult; None where no
     selection ran (the dense fallback). `valid` is the (B,) bool feedback
     validity, for shape."""
     none = jnp.zeros_like(valid)
     if res is None:
         return {"sel_gvr": none, "sel_iters": none.astype(jnp.int32),
-                "sel_fallback": none}
+                "sel_fallback": none, "sel_radix": none}
     iters = res.secant_iters       # None where lax.top_k served every row
     return {"sel_gvr": res.gvr_rows,
             "sel_iters": (none if iters is None else iters).astype(jnp.int32),
-            "sel_fallback": res.fallback}
+            "sel_fallback": res.fallback, "sel_radix": res.radix_rows}
 
 
 def sel_counts(outs, b: int) -> jnp.ndarray:
     """Per row, summed over the layer scan's leading (layer) axis of its
     `sel_telemetry` stacks in `outs` ((L, ..., B) each): [GVR-served
-    row-layers, their secant iterations, their safety-net fallbacks] —
-    (..., B, 3) int32; zeros (B, 3) where no layer carries telemetry (DSA
-    off). The counts a step returns with `with_counts=True`."""
+    row-layers, their secant iterations, their safety-net fallbacks,
+    row-layers whose radix path was computed] — (..., B, 4) int32; zeros
+    (B, 4) where no layer carries telemetry (DSA off). The counts a step
+    returns with `with_counts=True`."""
     if "sel_gvr" not in outs:
-        return jnp.zeros((b, 3), jnp.int32)
+        return jnp.zeros((b, 4), jnp.int32)
     gvr = outs["sel_gvr"]
     return jnp.stack(
         [jnp.sum(gvr, axis=0, dtype=jnp.int32),
          jnp.sum(jnp.where(gvr, outs["sel_iters"], 0), axis=0,
                  dtype=jnp.int32),
-         jnp.sum(gvr & outs["sel_fallback"], axis=0, dtype=jnp.int32)],
+         jnp.sum(gvr & outs["sel_fallback"], axis=0, dtype=jnp.int32),
+         jnp.sum(outs["sel_radix"], axis=0, dtype=jnp.int32)],
         axis=-1)
 
 
 def serve_step(params, state, tokens, cfg: ModelConfig, *, mesh=None,
                rules: Optional[MeshRules] = None, with_counts: bool = False):
     """One decode step. tokens: (B,) int32. Returns (logits (B,V), state),
-    and with `with_counts` the rows' GVR counts (B, 3) third (`sel_counts`).
+    and with `with_counts` the rows' GVR counts (B, 4) third (`sel_counts`).
 
     Per layer: append KV (and indexer K) at position `length`, then attend —
     DSA sparse path when enabled and the cache is long enough, dense
@@ -636,7 +639,7 @@ def _sp_paged_token_body(params, state, tokens, mwp, cfg: ModelConfig, *,
     draft positions of a verify tick within a single shard_map
     (`serve_step_sp_spec_paged`); `serve_step_sp_paged` wraps exactly one
     invocation. Returns (logits, new_state, counts) with the shard axis
-    restored on the pool leaves; counts: `sel_counts` (B, 3)."""
+    restored on the pool leaves; counts: `sel_counts` (B, 4)."""
     from repro.sparse import sp_dsa as sp_dsa_mod
 
     b = tokens.shape[0]
@@ -735,7 +738,7 @@ def serve_step_sp_paged(params, state, tokens, cfg: ModelConfig, *, mesh,
                         with_counts: bool = False):
     """One sequence-sharded paged decode step (inside a shard_map over the
     mesh's `seq_axis`). tokens: (B,) int32. Returns (logits, state), and
-    with `with_counts` the rows' GVR counts (B, 3) third (`sel_counts`).
+    with `with_counts` the rows' GVR counts (B, 4) third (`sel_counts`).
 
     Per shard and per layer: the shard owning logical position `length`
     scatters the new token's K/V/indexer-K rows into ITS page pool (every
@@ -859,7 +862,7 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
                      mesh=None, rules: Optional[MeshRules] = None,
                      with_counts: bool = False):
     """One paged decode step. tokens: (B,) int32. Returns (logits, state),
-    and with `with_counts` the rows' GVR counts (B, 3) third (`sel_counts`).
+    and with `with_counts` the rows' GVR counts (B, 4) third (`sel_counts`).
 
     Mirrors `serve_step` exactly, with the logical→physical translation at
     the cache boundary: the new token's rows scatter into
@@ -1037,7 +1040,7 @@ def _spec_verify_scan(step_fn, state, tokens, draft_len, max_accept,
     shard_map, by `serve_step_sp_spec_paged`).
 
     step_fn(state, tok (B,), mwp (B,)) -> (logits (B, V), new_state,
-    counts (B, 3)) — one per-token paged decode step with its `sel_counts`. tokens: (B, D+1) — column 0 is the last
+    counts (B, 4)) — one per-token paged decode step with its `sel_counts`. tokens: (B, D+1) — column 0 is the last
     emitted token, columns 1..D the draft. draft_len: (B,) in [0, D] — rows
     verify positions 0..draft_len (position j > draft_len is frozen: state
     row kept, cache write redirected to the sink page). max_accept: (B,)
@@ -1046,7 +1049,7 @@ def _spec_verify_scan(step_fn, state, tokens, draft_len, max_accept,
     (-1 = disabled; vocab ids are non-negative so it never matches).
 
     Returns (out_tokens (B, D+1), accept_len (B,), logits_all (B, D+1, V),
-    sel_gvr_pos (B, D+1), gvr_counts (B, 3), new_state): `out_tokens[:, j]`
+    sel_gvr_pos (B, D+1), gvr_counts (B, 4), new_state): `out_tokens[:, j]`
     is position j's argmax, the engine appends columns 0..accept_len;
     `sel_gvr_pos` is the layer-0 per-position GVR telemetry (column j valid
     iff j <= draft_len); `gvr_counts` is `sel_counts` over the executed
@@ -1097,7 +1100,7 @@ def _spec_accept_rollback(length0, end_state, ys, tokens, draft_len,
     per-position stacks "prev_topk" (D+1, L, B, K), "topk_valid" and
     "sel_gvr" (D+1, L, B) — RAW (unmerged) values; entry j is only ever
     selected for rows whose position j really executed (accept_len <=
-    draft_len) — and "sel_counts" (D+1, B, 3), each position's
+    draft_len) — and "sel_counts" (D+1, B, 4), each position's
     `sel_counts`, summed over the executed positions only. Returns the
     serve_step_spec_paged 6-tuple.
     """
@@ -1140,7 +1143,7 @@ def _spec_accept_rollback(length0, end_state, ys, tokens, draft_len,
                                    0), axis=0)
     else:
         sel_pos = jnp.zeros((b, d1), bool)
-        counts = jnp.zeros((b, 3), jnp.int32)
+        counts = jnp.zeros((b, 4), jnp.int32)
     return (argmax_all.T, a, jnp.transpose(logits_all, (1, 0, 2)),
             sel_pos, counts, new_state)
 
@@ -1338,7 +1341,7 @@ def _paged_verify_mq(params, state, tokens, cfg: ModelConfig, *, draft_len,
         ys["prev_topk"] = jnp.swapaxes(outs["sel_idx"], 0, 1)   # (Q, L, B, K)
         ys["topk_valid"] = jnp.swapaxes(outs["sel_valid"], 0, 1)
         ys["sel_gvr"] = jnp.swapaxes(outs["sel_gvr"], 0, 1)
-        ys["sel_counts"] = sel_counts(outs, b)            # (Q, B, 3)
+        ys["sel_counts"] = sel_counts(outs, b)            # (Q, B, 4)
     end_state = dict(state)
     end_state["k_pages"] = outs["k_pages"]
     end_state["v_pages"] = outs["v_pages"]
@@ -1371,7 +1374,7 @@ def serve_step_spec_paged(params, state, tokens, cfg: ModelConfig, *,
       (`_paged_verify_mq` — the served form of the Pallas mq kernels).
 
     Returns (out_tokens (B, D+1), accept_len (B,), logits_all (B, D+1, V),
-    sel_gvr_pos (B, D+1), gvr_counts (B, 3), new_state) — see
+    sel_gvr_pos (B, D+1), gvr_counts (B, 4), new_state) — see
     `_spec_verify_scan`.
     """
     b = tokens.shape[0]
@@ -1545,7 +1548,7 @@ def _sp_paged_verify_mq_body(params, state, tokens, draft_len, max_accept,
           "prev_topk": jnp.swapaxes(outs["sel_idx"], 0, 1),
           "topk_valid": jnp.swapaxes(outs["sel_valid"], 0, 1),
           "sel_gvr": jnp.swapaxes(outs["sel_gvr"], 0, 1),
-          "sel_counts": sel_counts(outs, b)}              # (Q, B, 3)
+          "sel_counts": sel_counts(outs, b)}              # (Q, B, 4)
     end_state = dict(state)
     for key in ("k_pages", "v_pages", "idx_k_pages"):
         end_state[key] = outs[key][:, None]              # restore shard axis

@@ -109,11 +109,12 @@ from .paged import PagedKVManager, PoolExhausted, ShardedPagedKVManager
 from .scheduler import DECODE, DONE, PREFILL, QUEUED, Scheduler, make_scheduler
 
 # the on-device GVR counters, in the order of the state's `gvr_counters` leaf
-GVR_COUNTERS = ("gvr_row_layers", "gvr_secant_iters", "gvr_fallbacks")
+GVR_COUNTERS = ("gvr_row_layers", "gvr_secant_iters", "gvr_fallbacks",
+                "radix_row_layers")
 
 
 def _add_counts(total, counts, active):
-    """The pool-global counters plus the active rows' GVR counts (B, 3)
+    """The pool-global counters plus the active rows' GVR counts (B, 4)
     of one step (the model's `sel_counts`)."""
     return total + jnp.sum(jnp.where(active[:, None], counts, 0), axis=0)
 
@@ -460,7 +461,7 @@ class DecodeEngine:
     def _serve_step(self, params, state, tokens, min_write_pos=None,
                     with_counts=False):
         """Layout dispatch: one model step over the given (sub-)pool.
-        `with_counts` adds the rows' GVR counts (B, 3) as a third result."""
+        `with_counts` adds the rows' GVR counts (B, 4) as a third result."""
         if self.seq_shards > 1:
             return self.model.serve_step_sp_paged(
                 params, state, tokens, min_write_pos=min_write_pos,
@@ -578,10 +579,14 @@ class DecodeEngine:
         which are then reset (keys: GVR_COUNTERS). `gvr_row_layers` counts
         the row-layers of active slots whose Top-K the GVR path served,
         `gvr_secant_iters` their secant iterations, `gvr_fallbacks` those
-        that fell back to GVR's safety net. Every step adds to them on the
-        device; a read is their only transfer, so read them at the edges
-        of a measured window, not per tick (`run()` reads them at its
-        start and end).
+        that fell back to GVR's safety net, and `radix_row_layers` the
+        row-layers of active slots whose radix path was computed (every
+        row of a layer whose batch held a cold row, none where all rows
+        were warm: 1 − `radix_row_layers` ÷ the active row-layers with DSA
+        on is the share of radix the selector skipped). Every step adds to
+        them on the device; a read is their only transfer, so read them at
+        the edges of a measured window, not per tick (`run()` reads them at
+        its start and end).
 
         The leaf is int32. A step adds at most 12 iterations
         (DEFAULT_MAX_SECANT) per row-layer, so at the chat cell's shape and

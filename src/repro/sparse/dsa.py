@@ -90,6 +90,7 @@ class DSAOutput(NamedTuple):
     secant_iters: Optional[jnp.ndarray]
     gvr_rows: Optional[jnp.ndarray] = None   # (B,) bool — selector path taken
     fallback: Optional[jnp.ndarray] = None   # (B,) bool — GVR safety net ran
+    radix_rows: Optional[jnp.ndarray] = None  # (B,) bool — radix computed
 
 
 def dsa_sparse_attention(q: jnp.ndarray, kcache: jnp.ndarray, vcache: jnp.ndarray,
@@ -345,7 +346,7 @@ def dsa_decode(q: jnp.ndarray, kcache: jnp.ndarray, vcache: jnp.ndarray,
         out = dsa_sparse_attention(q, kcache, vcache, sel.indices, lengths,
                                    scale=scale, rules=rules)
     return DSAOutput(out, sel.indices, sel.secant_iters, sel.gvr_rows,
-                     sel.fallback)
+                     sel.fallback, sel.radix_rows)
 
 
 def dsa_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
@@ -382,4 +383,4 @@ def dsa_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                                          granularity=gather_granularity,
                                          rules=rules)
     return DSAOutput(out, sel.indices, sel.secant_iters, sel.gvr_rows,
-                     sel.fallback)
+                     sel.fallback, sel.radix_rows)

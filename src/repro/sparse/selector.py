@@ -17,15 +17,16 @@ Continuous batching adds a *per-row* dimension to the gate: a serving batch
 mixes warm slots (genuine previous-step feedback) with cold ones (freshly
 admitted, prediction history reset). `prev_valid` (B,) carries that
 row-level `canUseHeuristic` signal; under `method="auto"` the selector then
-runs the GVR and radix paths and serves each row from its own path
-("mixed"). Both paths are exact with identical lowest-index tie policy, so
-outputs are row-for-row identical either way — the per-row dispatch is
-about cost fidelity (a cold row must not be billed/telemetered as a GVR
-hit) and about the feedback loop: `gvr_rows` reports which rows the GVR
-path actually served, which the serving engine logs per tick. A production
-kernel would partition the grid by row instead of computing both paths;
-at this layer SPMD static shapes make compute-both-and-select the honest
-equivalent (same semantics as a vmapped lax.cond).
+serves each row from its own path ("mixed"), dispatching on the batch at
+run time (`lax.switch` on a device predicate, no host sync): every row
+warm runs GVR alone, no row warm runs radix alone, and only a batch that
+really mixes the two runs both and picks per row. Both paths are exact
+with identical lowest-index tie policy, so outputs are row-for-row
+identical whichever branch ran — the dispatch is about cost (a decode
+tick of warm slots pays for GVR only) and telemetry: `gvr_rows` reports
+which rows the GVR path served, which the serving engine logs per tick,
+and `radix_rows` the rows whose radix path was computed (all of them
+whenever radix ran), which the engine counts.
 
 Layout invariant (paged serving): every index this module consumes
 (`prev_idx`) or produces lives in *logical* token space — position within
@@ -55,6 +56,7 @@ class SelectorOutput(NamedTuple):
     secant_iters: Optional[jnp.ndarray] = None
     gvr_rows: Optional[jnp.ndarray] = None   # (B,) bool — rows the GVR path served
     fallback: Optional[jnp.ndarray] = None   # (B,) bool — GVR's safety net ran
+    radix_rows: Optional[jnp.ndarray] = None  # (B,) bool — radix computed
 
 
 def _masked_scores(scores, lengths):
@@ -83,7 +85,6 @@ def select_topk(scores: jnp.ndarray, k: int, *,
     ops (EXPERIMENTS §Perf iteration 2: 282 MB -> ~0 per decode step).
     """
     if mesh is not None:
-        import jax
         from jax.sharding import PartitionSpec as P
         axes = tuple(a for a in batch_axes if a in mesh.axis_names)
         ext = 1
@@ -105,18 +106,13 @@ def select_topk(scores: jnp.ndarray, k: int, *,
                 it = r.secant_iters
                 if it is None:
                     it = jnp.zeros((s_.shape[0],), jnp.int32)
-                g = r.gvr_rows
-                if g is None:
-                    g = jnp.zeros((s_.shape[0],), bool)
-                fb = r.fallback
-                if fb is None:
-                    fb = jnp.zeros((s_.shape[0],), bool)
-                return r.indices, r.values, it, g, fb
+                return (r.indices, r.values, it, r.gvr_rows, r.fallback,
+                        r.radix_rows)
 
-            idx, vals, iters, gvr_rows, fallback = jax.shard_map(
+            idx, vals, iters, gvr_rows, fallback, radix_rows = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(bspec, P(axes), bspec, P(axes)),
-                out_specs=(bspec, bspec, P(axes), P(axes), P(axes)),
+                out_specs=(bspec, bspec, P(axes), P(axes), P(axes), P(axes)),
                 check_vma=False,
             )(scores,
               lengths if lengths is not None else
@@ -132,7 +128,7 @@ def select_topk(scores: jnp.ndarray, k: int, *,
             if has_valid and resolved == "gvr":
                 resolved = "mixed"
             return SelectorOutput(idx, vals, resolved, iters, gvr_rows,
-                                  fallback)
+                                  fallback, radix_rows)
 
     n = scores.shape[-1]
     b = scores.shape[0]
@@ -152,27 +148,46 @@ def select_topk(scores: jnp.ndarray, k: int, *,
                               max_candidates=max_candidates)
         vals, idx = extract_topk(scores, stats.threshold, k, lengths=lengths)
         return SelectorOutput(idx, vals, "gvr", stats.secant_iters,
-                              jnp.ones((b,), bool), stats.fallback)
+                              jnp.ones((b,), bool), stats.fallback,
+                              jnp.zeros((b,), bool))
     if method == "mixed":
         assert prev_idx is not None, "mixed dispatch needs a prediction signal"
         assert prev_valid is not None, "mixed dispatch needs prev_valid"
         warm = prev_valid.astype(bool)
-        stats = gvr_threshold(scores, prev_idx, k, lengths=lengths,
-                              max_candidates=max_candidates)
-        g_vals, g_idx = extract_topk(scores, stats.threshold, k,
+        no_fallback = jnp.zeros((b,), bool)
+
+        def gvr_path(_):
+            stats = gvr_threshold(scores, prev_idx, k, lengths=lengths,
+                                  max_candidates=max_candidates)
+            vals, idx = extract_topk(scores, stats.threshold, k,
                                      lengths=lengths)
-        r_vals, r_idx, st = radix_select_topk(_masked_scores(scores, lengths), k)
-        idx = jnp.where(warm[:, None], g_idx, r_idx)
-        vals = jnp.where(warm[:, None], g_vals, r_vals)
-        iters = jnp.where(warm, stats.secant_iters, st.passes)
-        return SelectorOutput(idx, vals, "mixed", iters, warm,
-                              stats.fallback & warm)
+            return idx, vals, stats.secant_iters, stats.fallback
+
+        def radix_path(_):
+            vals, idx, st = radix_select_topk(_masked_scores(scores, lengths),
+                                              k)
+            return idx, vals, st.passes, no_fallback
+
+        def both_paths(_):
+            g_idx, g_vals, g_iters, g_fb = gvr_path(None)
+            r_idx, r_vals, r_iters, _ = radix_path(None)
+            return (jnp.where(warm[:, None], g_idx, r_idx),
+                    jnp.where(warm[:, None], g_vals, r_vals),
+                    jnp.where(warm, g_iters, r_iters), g_fb & warm)
+
+        # 0: every row warm, 1: no row warm, 2: a mixed batch
+        branch = jnp.where(jnp.all(warm), 0,
+                           jnp.where(jnp.any(warm), 2, 1))
+        idx, vals, iters, fallback = jax.lax.switch(
+            branch, (gvr_path, radix_path, both_paths), None)
+        return SelectorOutput(idx, vals, "mixed", iters, warm, fallback,
+                              jnp.broadcast_to(branch > 0, (b,)))
     if method == "radix":
         vals, idx, st = radix_select_topk(_masked_scores(scores, lengths), k)
         return SelectorOutput(idx, vals, "radix", st.passes,
-                              jnp.zeros((b,), bool), jnp.zeros((b,), bool))
+                              jnp.zeros((b,), bool), jnp.zeros((b,), bool),
+                              jnp.ones((b,), bool))
     if method == "exact":
-        import jax
         vals, idx = jax.lax.top_k(_masked_scores(scores, lengths), k)
         # Canonical ascending-index order, like the extraction-based paths:
         # downstream attention then sums gathered rows in the same order no
@@ -182,5 +197,6 @@ def select_topk(scores: jnp.ndarray, k: int, *,
         idx = jnp.take_along_axis(idx, order, axis=-1)
         vals = jnp.take_along_axis(vals, order, axis=-1)
         return SelectorOutput(idx.astype(jnp.int32), vals, "exact", None,
-                              jnp.zeros((b,), bool), jnp.zeros((b,), bool))
+                              jnp.zeros((b,), bool), jnp.zeros((b,), bool),
+                              jnp.zeros((b,), bool))
     raise ValueError(f"unknown selector method {method!r}")

@@ -131,6 +131,7 @@ class SPDSAPagedResult(NamedTuple):
     secant_iters: jnp.ndarray  # (B,) int32 — SP-GVR phase-2 iterations
     gvr_rows: jnp.ndarray     # (B,) bool — rows served off the temporal prior
     fallback: jnp.ndarray     # (B,) bool — SP-GVR's safety net ran
+    radix_rows: jnp.ndarray   # (B,) bool — none: SP-GVR never runs radix
 
 
 def sp_dsa_decode_paged_local(q, k_pages, v_pages, table_local, idx_params, h,
@@ -252,7 +253,8 @@ def sp_dsa_decode_paged_local(q, k_pages, v_pages, table_local, idx_params, h,
     gvr_rows = (prev_valid.astype(bool) if prev_valid is not None
                 else jnp.zeros((b,), bool))
     return SPDSAPagedResult(out.reshape(b, hl, hd), topk,
-                            sel.secant_iters, gvr_rows, sel.fallback)
+                            sel.secant_iters, gvr_rows, sel.fallback,
+                            jnp.zeros((b,), bool))
 
 
 def make_sp_dsa(mesh, *, k: int, scale: float, heads: int, dim: int,

@@ -132,3 +132,102 @@ def test_mixed_requires_auto_gate():
     out = select_topk(x, 8, prev_idx=prev, prev_valid=valid, method="auto",
                       min_n_for_selection=64)
     assert out.method == "mixed"
+
+
+def _compute_both(x, prev, valid, k, lengths):
+    """The mixed dispatch as it was before the batch-level switch: both
+    paths on every row, picked per row."""
+    from repro.core.gvr import extract_topk, gvr_threshold
+    from repro.core.topk_baselines import radix_select_topk
+    stats = gvr_threshold(x, prev, k, lengths=lengths)
+    g_vals, g_idx = extract_topk(x, stats.threshold, k, lengths=lengths)
+    xm = jnp.where(jnp.arange(x.shape[-1])[None, :] < lengths[:, None], x,
+                   NEG)
+    r_vals, r_idx, r_st = radix_select_topk(xm, k)
+    return dict(indices=jnp.where(valid[:, None], g_idx, r_idx),
+                values=jnp.where(valid[:, None], g_vals, r_vals),
+                secant_iters=jnp.where(valid, stats.secant_iters,
+                                       r_st.passes),
+                gvr_rows=valid, fallback=stats.fallback & valid)
+
+
+VALIDITY = {"all_warm": [True] * 4, "all_cold": [False] * 4,
+            "mixed": [True, False, False, True]}
+
+
+@pytest.mark.parametrize("mode", ["eager", "jit", "scan", "mesh"])
+@pytest.mark.parametrize("validity", sorted(VALIDITY))
+def test_mixed_dispatch_bit_equal_to_compute_both(validity, mode):
+    """Whichever branch the batch takes (every row warm: GVR alone; none:
+    radix alone; a mix: both), every output is bit-equal to computing
+    both paths and picking per row, eagerly, under jit, inside a lax.scan
+    (the decode step's layer scan) and inside the shard_map body a mesh
+    routes selection through (a one-device mesh); radix is reported
+    computed on every row unless every row is warm."""
+    rng = np.random.default_rng(7)
+    b, n, k, layers = 4, 384, 24, 2
+    valid = jnp.asarray(np.array(VALIDITY[validity]))
+    xs = jnp.asarray(np.stack([_scores(rng, b, n, d)
+                               for d in ("ties", "normal")]))
+    prevs = jnp.asarray(rng.integers(0, n, (layers, b, k)).astype(np.int32))
+    lens = jnp.asarray(rng.integers(k, n + 1, (layers, b)).astype(np.int32))
+    keys = ("indices", "values", "secant_iters", "gvr_rows", "fallback",
+            "radix_rows")
+
+    mesh = (jax.make_mesh((1,), ("data",), devices=jax.devices()[:1])
+            if mode == "mesh" else None)
+
+    def sel(x, prev, lengths):
+        out = select_topk(x, k, prev_idx=prev, prev_valid=valid,
+                          lengths=lengths, min_n_for_selection=64,
+                          mesh=mesh)
+        assert out.method == "mixed"
+        return tuple(getattr(out, key) for key in keys)
+
+    if mode == "scan":
+        got = jax.lax.scan(lambda c, inp: (c, sel(*inp)), 0,
+                           (xs, prevs, lens))[1]
+    else:
+        f = sel if mode == "eager" else jax.jit(sel)
+        got = [jnp.stack(o) for o in zip(*(f(xs[i], prevs[i], lens[i])
+                                           for i in range(layers)))]
+    for i in range(layers):
+        want = _compute_both(xs[i], prevs[i], valid, k, lens[i])
+        want["radix_rows"] = np.full((b,), validity != "all_warm")
+        for key, g in zip(keys, got):
+            np.testing.assert_array_equal(np.asarray(g[i]),
+                                          np.asarray(want[key]),
+                                          err_msg=f"{key}, layer {i}")
+            assert np.asarray(g[i]).dtype == np.asarray(want[key]).dtype
+
+
+def _jits_in(jaxpr, name):
+    """How many `jit(name)` calls a jaxpr holds, through nested jaxprs."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.params.get("name") == name:
+            count += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            count += _jits_in(sub, name)
+    return count
+
+
+def test_all_warm_branch_runs_no_radix():
+    """The mixed dispatch is one switch: radix-select lies only in its
+    all-cold and mixed branches, and the all-warm branch holds GVR alone."""
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(4, 256)).astype(np.float32))
+    prev = jnp.asarray(rng.integers(0, 256, (4, 16)).astype(np.int32))
+    closed = jax.make_jaxpr(lambda x, p, v: select_topk(
+        x, 16, prev_idx=p, prev_valid=v, min_n_for_selection=64).indices)(
+            x, prev, jnp.ones((4,), bool))
+    conds = [e for e in closed.jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    warm, cold, mixed = (br.jaxpr for br in conds[0].params["branches"])
+    assert _jits_in(closed.jaxpr, "radix_select_topk") == 2
+    assert _jits_in(warm, "radix_select_topk") == 0
+    assert _jits_in(warm, "gvr_threshold") == 1
+    assert _jits_in(cold, "radix_select_topk") == 1
+    assert _jits_in(cold, "gvr_threshold") == 0
+    assert _jits_in(mixed, "radix_select_topk") == 1
+    assert _jits_in(mixed, "gvr_threshold") == 1

@@ -4,6 +4,7 @@ tick-phase spans in a profiler trace (CPU, smoke configs)."""
 
 import dataclasses
 import glob
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,8 +61,10 @@ def test_compiled_step_carries_every_scope_dsa():
     eng = _engine(get_config("llama3.2-1b", smoke=True))
     hlo = _step_hlo(eng)
     assert _scopes_in(hlo) == set(STEP_SCOPES)
-    assert "step.topk/jit(gvr_threshold)" in hlo
-    assert "step.topk/jit(radix_select_topk)" in hlo
+    # the selector's batch-level switch puts them under `cond/branch_<i>_fun`
+    for jit in ("gvr_threshold", "radix_select_topk"):
+        assert re.search(rf"/step\.topk/(cond/branch_\d_fun/)?jit\({jit}\)/",
+                         hlo), jit
 
 
 def test_compiled_step_carries_its_scopes_moe_without_dsa():
@@ -96,9 +99,54 @@ def test_gvr_counters_match_the_method_log():
     assert 0 <= got["gvr_secant_iters"] <= (DEFAULT_MAX_SECANT
                                             * got["gvr_row_layers"])
     assert got["gvr_fallbacks"] == 0
+    assert got["radix_row_layers"] == 0        # every row warm: GVR alone
     assert eng.counters() == dict.fromkeys(GVR_COUNTERS, 0)
     eng.tick()
     assert eng._tick_fn._cache_size() == 1
+
+
+@pytest.mark.parametrize("neighbour", ["warm", "admitted_too"])
+def test_radix_row_layers_count_the_admission_step(neighbour):
+    """Radix runs only on a step whose batch holds a cold row. On the tick
+    a slot is admitted, its first prefill step counts n_layers radix
+    row-layers for each active row: beside a warm decoding slot (a mixed
+    batch, the admitted slot the only active row), or with the other slot
+    admitted in the same tick (every row cold, both active). The chunk's
+    later steps and the decode step, every row warm by then, count none,
+    and GVR counts its warm row-layers as before."""
+    cfg = get_config("llama3.2-1b", smoke=True)
+    eng = _engine(cfg)
+    chunk = eng.prefill_chunk
+    prompt = lambda: RNG.integers(0, cfg.vocab, (2 * chunk,))
+    new = [Request(uid=0, prompt=prompt(), max_new_tokens=8)]
+    if neighbour == "warm":
+        old = Request(uid=1, prompt=RNG.integers(0, cfg.vocab, (5,)),
+                      max_new_tokens=40)
+        _warm_decode(eng, [old])
+        # the free slot's row is cold until it has served a request
+        eng.submit(Request(uid=2, prompt=RNG.integers(0, cfg.vocab, (3,)),
+                           max_new_tokens=2))
+        eng.tick()
+        while eng.slots[1] is not None:
+            eng.tick()
+        warm_decoding = 1
+    else:
+        new.append(Request(uid=1, prompt=prompt(), max_new_tokens=8))
+        warm_decoding = 0
+    eng.counters()
+    for r in new:
+        eng.submit(r)
+    eng.tick()
+    got = eng.counters()
+    assert all(r.phase == "PREFILL" for r in new)
+    assert got["radix_row_layers"] == cfg.n_layers * len(new)
+    assert got["gvr_row_layers"] == cfg.n_layers * (
+        len(new) * (chunk - 1) + warm_decoding)
+    assert 0 <= got["gvr_secant_iters"] <= (DEFAULT_MAX_SECANT
+                                            * got["gvr_row_layers"])
+    assert got["gvr_fallbacks"] == 0
+    eng.tick()                          # the second chunk: every row warm
+    assert eng.counters()["radix_row_layers"] == 0
 
 
 def test_spec_counters_count_every_executed_gvr_position():
@@ -128,6 +176,7 @@ def test_spec_counters_count_every_executed_gvr_position():
     assert 6 * len(reqs) < executed < 6 * len(reqs) * (depth + 1)
     assert served == executed
     assert got["gvr_row_layers"] == cfg.n_layers * served
+    assert got["radix_row_layers"] == 0
     assert 0 <= got["gvr_secant_iters"] <= (DEFAULT_MAX_SECANT
                                             * got["gvr_row_layers"])
     assert got["gvr_fallbacks"] == 0
@@ -233,6 +282,7 @@ def test_sequence_sharded_counters():
         assert 0 <= leg["gvr_secant_iters"] <= (DEFAULT_MAX_SECANT
                                                 * leg["gvr_row_layers"])
         assert leg["gvr_fallbacks"] == 0
+        assert leg["radix_row_layers"] == 0     # SP-GVR runs no radix
         assert leg["again"] == zeros
         assert leg["signatures"] == 1
 
