@@ -6,12 +6,17 @@ the MLP or the routed experts actually chosen (top_k of them, not every
 expert), the DSA indexer and the attention over the selected rows when
 the cell runs sparse, and the head. Selection itself (comparisons) and
 elementwise work are not counted. This is the work any implementation of
-the model has to do, so it bounds a step whatever computes it.
+the model has to do, so it bounds a step whatever computes it. Each
+layer's count is the configuration's layout's (`flops_per_layer` in
+bench/layouts/<layout>.py, bench/weights.py); the head's is counted here.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Optional
+
+from bench import weights
+from bench.registry import Registry
 
 
 def sparse_cell(cfg: Dict[str, Any], max_len: int) -> bool:
@@ -21,27 +26,7 @@ def sparse_cell(cfg: Dict[str, Any], max_len: int) -> bool:
     return bool(dsa.get("enabled")) and max_len > dsa["min_n"]
 
 
-def per_layer(cfg: Dict[str, Any], context: int, sparse: bool) -> float:
-    d, h, kvh, hd = (cfg["d_model"], cfg["n_heads"], cfg["n_kv_heads"],
-                     cfg["head_dim"])
-    flops = 2 * d * (h + 2 * kvh) * hd + 2 * h * hd * d
-    moe = cfg.get("moe") or {}
-    if moe.get("num_experts"):
-        flops += 2 * d * moe["num_experts"]
-        flops += moe["top_k"] * 2 * 3 * d * moe["expert_d_ff"]
-    else:
-        flops += 2 * 3 * d * cfg["d_ff"]
-    if sparse:
-        dsa = cfg["dsa"]
-        hi, di = dsa["indexer_heads"], dsa["indexer_dim"]
-        flops += 2 * d * hi * di + 2 * d * di          # indexer q and k
-        flops += 2 * context * hi * di + 2 * hi * context   # scores
-        flops += 4 * h * hd * min(dsa["k"], context)   # attention, K rows
-    else:
-        flops += 4 * h * hd * context
-    return float(flops)
-
-
-def per_token(cfg: Dict[str, Any], context: int, sparse: bool) -> float:
-    return (cfg["n_layers"] * per_layer(cfg, context, sparse)
-            + 2.0 * cfg["d_model"] * cfg["vocab"])
+def per_token(cfg: Dict[str, Any], context: int, sparse: bool,
+              reg: Optional[Registry] = None) -> float:
+    layers = weights.layout(cfg, reg).flops_per_layer(cfg, context, sparse)
+    return float(sum(layers)) + 2.0 * cfg["d_model"] * cfg["vocab"]

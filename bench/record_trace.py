@@ -52,7 +52,8 @@ def main(argv=None) -> int:
                         set(spec["changed_from_registry"]) | {"n_layers"}))
     mix = reg.traffic(cell["traffic"])
     t = time.perf_counter()
-    params = weights.program_params(weights.make_flat(spec, 1))
+    params = weights.program_params(weights.make_flat(spec, 1, reg), spec,
+                                    reg)
     jax.block_until_ready(params)
     print(f"weights {time.perf_counter() - t:.2f} s", flush=True)
     eng = program.build_engine(spec, mix["engine"], params)

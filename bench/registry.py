@@ -11,6 +11,8 @@ Nothing here names a configuration, a traffic mix or a metric: a cell in
                                        in cells that move another metric or
                                        need another bound) has no file of its
                                        own and reads <metric>.py
+    bench/layouts/<layout>.py          a configuration's leaves, parameter
+                                       tree and FLOPs (bench/weights.py)
     bench/reference/<family>.py        the plain float32 reference
     bench/limits/<workload>.json       the limits `correct` is held to
     bench/peaks.json                   peaks keyed by device kind
@@ -18,6 +20,7 @@ Nothing here names a configuration, a traffic mix or a metric: a cell in
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -34,6 +37,10 @@ def _load_module(path: Path, name: str) -> ModuleType:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+# a layout is read for every token a FLOP count covers: load it once
+_load_module_once = functools.lru_cache(maxsize=None)(_load_module)
 
 
 def _load_json(path: Path) -> Any:
@@ -83,6 +90,13 @@ class Registry:
             base = base.rsplit(".", 1)[0]
         return _load_module(self.bench / "metrics" / f"{base}.py",
                             f"bench_metric_{base.replace('.', '_')}")
+
+    def layout(self, spec: Dict[str, Any]) -> ModuleType:
+        """`layouts/<layout>.py` of a configuration: the one its `layout`
+        key names, `gqa` where it names none."""
+        name = spec.get("layout", "gqa")
+        return _load_module_once(self.bench / "layouts" / f"{name}.py",
+                                 f"bench_layout_{name}")
 
     def reference(self, family: str) -> ModuleType:
         return _load_module(self.bench / "reference" / f"{family}.py",

@@ -67,9 +67,9 @@ def run_cell(reg, name: str, spec: dict, mix: dict, limits: dict, *,
 
     specs = reg.generator(mix["generator"]).generate(
         mix, seed, vocab=spec["vocab"], seconds=seconds)
-    flat = weights.make_flat(spec, seed)
+    flat = weights.make_flat(spec, seed, reg)
     eng = program.build_engine(spec, mix["engine"],
-                               weights.program_params(flat))
+                               weights.program_params(flat, spec, reg))
     driver = serve.Driver(eng, specs)
     trace_dir = tempfile.TemporaryDirectory(prefix="bench_trace_")
 

@@ -1,14 +1,18 @@
-"""A new mix, generator and metric are found by name, with no edit.
+"""A new mix, generator, metric and layout are found by name, with no edit.
 
 The test writes a dummy traffic mix, its generator and a dummy metric
 reader into a fresh checkout root, adds a cell and the metric to its
-BENCHMARK.json, and has the registry resolve them.
+BENCHMARK.json, and has the registry resolve them; a test-only layout
+with its own leaves, stacks and expert share is resolved and drawn the
+same way.
 """
 
 import json
 from types import SimpleNamespace
 
+from bench import flops, weights
 from bench.registry import ROOT, Registry, read_metric
+from bench.tests.small import MLA, mla_root
 
 
 def test_new_files_resolve_by_name(tmp_path):
@@ -48,3 +52,25 @@ def test_new_files_resolve_by_name(tmp_path):
     # reader of its own: it reads the quantity's
     assert read_metric(reg, "dummy_metric.other_cell",
                        SimpleNamespace(answer=5)) == 5.0
+
+
+def test_new_layout_resolves_by_name(tmp_path):
+    reg = mla_root(tmp_path)
+    layout = reg.layout(MLA)
+    assert layout.__file__ == str(tmp_path / "bench/layouts/mla.py")
+    assert Registry().layout({}).__file__ == str(ROOT / "bench/layouts/gqa.py")
+    flat = weights.make_flat(MLA, 5, reg)
+    assert flat["dense_wkv_b"].shape == (1, 16, 2 * (8 + 8))
+    assert flat["moe_wq_a"].shape == (2, 32, 24)
+    assert flat["moe_router"].shape == (2, 32, 16)
+    assert flat["moe_router_bias"].shape == (2, 16)
+    assert flat["moe_shared_down"].shape == (2, 20, 32)
+    assert flat["moe_w_gate"].shape == (2, 4, 32, 12)
+    params = weights.program_params(flat, MLA, reg)
+    assert params["moe_layers"]["w_down"] is flat["moe_w_down"]
+    assert params["dense_layers"]["idx_weights_proj"] is \
+        flat["dense_idx_weights_proj"]
+    per_layer = layout.flops_per_layer(MLA, 100, True)
+    assert len(per_layer) == 3 and per_layer[1] == per_layer[2] > 0
+    assert flops.per_token(MLA, 100, True, reg) == \
+        sum(per_layer) + 2 * 32 * 50

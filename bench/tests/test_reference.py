@@ -23,7 +23,8 @@ CASES = [("chatglm3-6b.stage7", 128), ("chatglm3-6b.stage7", 64),
 
 def serve(spec, eng_spec, seed, prompts, new):
     flat = weights.make_flat(spec, seed)
-    eng = program.build_engine(spec, eng_spec, weights.program_params(flat))
+    eng = program.build_engine(spec, eng_spec,
+                               weights.program_params(flat, spec))
     eng.record_logits = True
     reqs = [program.request(u, p, new) for u, p in enumerate(prompts)]
     for r in reqs:

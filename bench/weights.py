@@ -1,27 +1,57 @@
-"""Weights drawn from the seed, on the device, in one jitted call.
+"""Weights drawn from the seed, on the device, one leaf per jitted call.
 
-The benchmark makes the weights itself, in the layout the program's
-transformer takes (embedding, layers stacked on a leading axis, final
-norm, head), so that the reference can draw the very same values again
-from the seed without taking anything the program made. Matrices are
-normal with variance 1/fan_in, norm scales 1 + N(0, 0.1^2), the router
-in float32 (the program keeps it so), everything else in the served type.
+The benchmark makes the weights itself, in the layout the program takes,
+so that the reference can draw the very same values again from the seed
+without taking anything the program made. Which leaves a configuration
+has, and how they make the program's parameter tree, is its layout's:
+`bench/layouts/<name>.py`, named by the configuration's `"layout"` (`gqa`
+where it names none), with
+
+    shapes(spec) -> {leaf: (shape, dtype, scale[, fold])}
+    program_params(flat, spec) -> the program's parameter tree
+    flops_per_layer(spec, context, sparse) -> [one token's FLOPs, a layer]
+
+This module draws. Each leaf comes from a stream of the seed that its
+name alone fixes (`stream`), so its values never depend on which other
+leaves the layout has, and each is drawn in a jitted call of its own, so
+the peak while drawing passes the leaves by one leaf's float32 temporary
+at most. Matrices are
+normal with standard deviation `scale`, norm scales 1 + N(0, 0.1^2)
+(scale -1), the indexer's positive head weights uniform in [0.5, 1.5]
+over their count (scale -2); the layout gives each leaf's type.
+
+`fold` draws the leading axes key by key: slice i of the j-th leading
+axis comes from `fold_in(<key of the slice around it>, fold[j] + i)`. A
+stack drawn one layer at a time has `fold` (0,), and the bound is then
+one layer's temporary. `experts` gives an expert leaf the global index of
+each expert held, so the experts a chip holds are exactly that slice of
+what the uncut layer would draw.
 """
 
 from __future__ import annotations
 
-import json
+import zlib
 from functools import partial
-from typing import Any, Dict, Tuple
+from types import ModuleType
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
-# leaf name -> fixed stream id: a leaf's values never depend on which
-# other leaves the configuration has
+from bench.registry import Registry
+
+# fixed stream ids of the `gqa` leaves: the committed configurations'
+# weights depend on them; any other leaf's id comes from its name
 _STREAMS = {name: i for i, name in enumerate((
     "embed", "final_norm", "lm_head", "ln1", "ln2", "wq", "wk", "wv", "wo",
     "router", "w_gate", "w_up", "w_down", "idx_wq", "idx_wk", "idx_w"))}
+
+
+def stream(name: str) -> int:
+    """A leaf's stream id, from its name alone."""
+    if name in _STREAMS:
+        return _STREAMS[name]
+    return len(_STREAMS) + zlib.crc32(name.encode()) % 2 ** 31
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -31,47 +61,22 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def shapes(cfg: Dict[str, Any]) -> Dict[str, Tuple[Tuple[int, ...], str, float]]:
-    """leaf -> (shape, dtype, scale); layer leaves carry the layer axis.
-    scale is the standard deviation of a matrix; -1 marks a norm scale,
-    -2 the indexer's positive head weights."""
-    d, v, n = cfg["d_model"], cfg["vocab"], cfg["n_layers"]
-    h, kvh, hd = cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"]
-    dt = cfg["dtype"]
-    out = {
-        # a tied embedding is the head too: drawn as a head (1/d_model),
-        # or the input token's own row would outweigh the rest of the
-        # residual stream and win every greedy step whatever the layers do
-        "embed": ((v, d), dt, d ** -0.5 if cfg["tie_embeddings"] else 1.0),
-        "final_norm": ((d,), "float32", -1.0),
-        "ln1": ((n, d), "float32", -1.0),
-        "ln2": ((n, d), "float32", -1.0),
-        "wq": ((n, d, h * hd), dt, d ** -0.5),
-        "wk": ((n, d, kvh * hd), dt, d ** -0.5),
-        "wv": ((n, d, kvh * hd), dt, d ** -0.5),
-        "wo": ((n, h * hd, d), dt, (h * hd) ** -0.5),
-    }
-    if not cfg["tie_embeddings"]:
-        out["lm_head"] = ((d, v), dt, d ** -0.5)
-    moe = cfg.get("moe")
-    if moe and moe["num_experts"]:
-        e, f = moe["num_experts"], moe["expert_d_ff"]
-        out["router"] = ((n, d, e), "float32", d ** -0.5)
-        out["w_gate"] = ((n, e, d, f), dt, d ** -0.5)
-        out["w_up"] = ((n, e, d, f), dt, d ** -0.5)
-        out["w_down"] = ((n, e, f, d), dt, f ** -0.5)
-    else:
-        ff = cfg["d_ff"]
-        out["w_gate"] = ((n, d, ff), dt, d ** -0.5)
-        out["w_up"] = ((n, d, ff), dt, d ** -0.5)
-        out["w_down"] = ((n, ff, d), dt, ff ** -0.5)
-    dsa = cfg.get("dsa")
-    if dsa and dsa["enabled"]:
-        hi, di = dsa["indexer_heads"], dsa["indexer_dim"]
-        out["idx_wq"] = ((n, d, hi * di), dt, d ** -0.5)
-        out["idx_wk"] = ((n, d, di), dt, d ** -0.5)
-        out["idx_w"] = ((n, hi), "float32", -2.0)
-    return out
+def expert_share(moe: Dict[str, Any]) -> Tuple[int, int, int]:
+    """(routed, held, first): the experts the router scores (`total_experts`,
+    the published count), those held on this chip (`num_experts`) and the
+    global index of the first held (`first_expert`). A configuration that
+    names neither holds every expert."""
+    held = moe["num_experts"]
+    return moe.get("total_experts", held), held, moe.get("first_expert", 0)
+
+
+def experts(moe: Dict[str, Any], lead: Sequence[int], shape: Sequence[int],
+            dtype: str, scale: float):
+    """The entry of an expert leaf: `lead` leading axes (a layer stack,
+    drawn a slice at a time), the experts held, then each expert's
+    `shape`; expert e is drawn from its global index."""
+    _, held, first = expert_share(moe)
+    return ((*lead, held, *shape), dtype, scale, (0,) * len(lead) + (first,))
 
 
 def _draw(key, shape, dtype, scale):
@@ -84,34 +89,51 @@ def _draw(key, shape, dtype, scale):
     return x.astype(dtype)
 
 
-_KEYS = ("d_model", "vocab", "n_layers", "n_heads", "n_kv_heads", "head_dim",
-         "dtype", "tie_embeddings", "d_ff", "moe", "dsa")
+def _folded(key, shape, dtype, scale, fold):
+    if not fold:
+        return _draw(key, shape, dtype, scale)
+    return jax.vmap(lambda i: _folded(jax.random.fold_in(key, i), shape[1:],
+                                      dtype, scale, fold[1:]))(
+        fold[0] + jnp.arange(shape[0]))
 
 
-@partial(jax.jit, static_argnums=0)
-def _make_flat(cfg_json: str, key):
-    return {name: _draw(jax.random.fold_in(key, _STREAMS[name]), shape,
-                        jnp.dtype(dt), scale)
-            for name, (shape, dt, scale) in shapes(json.loads(cfg_json)).items()}
+@partial(jax.jit, static_argnums=(1, 2, 3))
+def _whole(key, shape, dtype, scale):
+    return _draw(key, shape, jnp.dtype(dtype), scale)
 
 
-def make_flat(cfg: Dict[str, Any], seed: int) -> Dict[str, jax.Array]:
-    """Every leaf by its flat name, drawn in one jitted call."""
-    cfg_json = json.dumps({k: cfg[k] for k in _KEYS if k in cfg},
-                          sort_keys=True)
-    return _make_flat(cfg_json, seed_key(seed))
+@partial(jax.jit, static_argnums=(3, 4, 5, 6), donate_argnums=0)
+def _into(out, key, i, shape, dtype, scale, fold):
+    x = _folded(jax.random.fold_in(key, fold[0] + i), shape[1:],
+                jnp.dtype(dtype), scale, fold[1:])
+    return jax.lax.dynamic_update_index_in_dim(out, x, i, 0)
 
 
-def program_params(flat: Dict[str, jax.Array]) -> Dict[str, Any]:
+def draw(key, shape, dtype, scale, fold=()) -> jax.Array:
+    """One leaf from its key: whole, or its first axis a slice a call."""
+    shape = tuple(shape)
+    if not fold:
+        return _whole(key, shape, dtype, scale)
+    out = jnp.zeros(shape, dtype)
+    for i in range(shape[0]):
+        out = _into(out, key, i, shape, dtype, scale, tuple(fold))
+    return out
+
+
+def layout(spec: Dict[str, Any], reg: Optional[Registry] = None) -> ModuleType:
+    """The configuration's layout module."""
+    return (reg or Registry()).layout(spec)
+
+
+def make_flat(spec: Dict[str, Any], seed: int,
+              reg: Optional[Registry] = None) -> Dict[str, jax.Array]:
+    """Every leaf of the configuration's layout by its flat name."""
+    key = seed_key(seed)
+    return {name: draw(jax.random.fold_in(key, stream(name)), *entry)
+            for name, entry in layout(spec, reg).shapes(spec).items()}
+
+
+def program_params(flat: Dict[str, jax.Array], spec: Dict[str, Any],
+                   reg: Optional[Registry] = None) -> Dict[str, Any]:
     """The flat leaves in the program's parameter tree (no copies)."""
-    layers = {k: flat[k] for k in ("ln1", "ln2", "wq", "wk", "wv", "wo",
-                                   "w_gate", "w_up", "w_down", "router")
-              if k in flat}
-    if "idx_wq" in flat:
-        layers["indexer"] = {"wq": flat["idx_wq"], "wk": flat["idx_wk"],
-                             "w": flat["idx_w"]}
-    params = {"embed": flat["embed"], "layers": layers,
-              "final_norm": flat["final_norm"]}
-    if "lm_head" in flat:
-        params["lm_head"] = flat["lm_head"]
-    return params
+    return layout(spec, reg).program_params(flat, spec)
