@@ -19,6 +19,7 @@ ARCHS = [
     "granite_moe_1b",
     "moonshot_v1_16b",
     "whisper_medium",
+    "deepseek_v32",
 ]
 
 _ALIASES = {
@@ -32,6 +33,7 @@ _ALIASES = {
     "granite-moe-1b-a400m": "granite_moe_1b",
     "moonshot-v1-16b-a3b": "moonshot_v1_16b",
     "whisper-medium": "whisper_medium",
+    "deepseek-v3.2": "deepseek_v32",
 }
 
 

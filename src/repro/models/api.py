@@ -92,6 +92,12 @@ class Model:
         fn = getattr(self.mod, "paged_state_batch_axes", None)
         return fn(self.cfg) if fn is not None else None
 
+    def step_counters(self) -> tuple:
+        """The names of the columns of the counts a step returns with
+        `with_counts`, in order."""
+        fn = getattr(self.mod, "step_counters", None)
+        return fn(self.cfg) if fn is not None else transformer.GVR_COUNTERS
+
     def serve_step_paged(self, params, state, tokens, *, min_write_pos=None,
                          paged_attn="fused", gather_granularity="token",
                          mesh=None, rules=None, with_counts=False):

@@ -21,10 +21,43 @@ class DSAConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int = 0
-    top_k: int = 2
+    num_experts: int = 0            # experts held here (all of them unless
+    top_k: int = 2                  # a share is stated below)
     expert_d_ff: int = 0
     capacity_factor: float = 1.25
+    # an expert share (expert parallelism): the router scores all
+    # `total_experts` (0: as many as are held) and this chip holds
+    # experts [first_expert, first_expert + num_experts)
+    total_experts: int = 0
+    first_expert: int = 0
+    shared_d_ff: int = 0            # one shared expert of this width (0: none)
+    # DeepSeek's noaux_tc routing: sigmoid scores plus a router bias choose
+    # top_k experts within the `topk_group` best of `n_group` groups, and
+    # the gates are the chosen sigmoid scores normalised to sum 1, times
+    # `routed_scaling`. The MLA expert layer alone reads the share, the
+    # shared expert and these three; other expert layers route by a softmax
+    # over the top_k router logits (ModelConfig refuses them elsewhere)
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling: float = 1.0
+
+    @property
+    def routed_experts(self) -> int:
+        """The experts the router scores (the published count)."""
+        return self.total_experts or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN RoPE scaling (DeepSeek-V3's `rope_scaling`). Its `mscale`
+    scales cos and sin by mscale(mscale) / mscale(mscale_all_dim), which is
+    1 where the two are equal, as in every DeepSeek-V3 release: only
+    `mscale_all_dim` is kept, for the softmax scale."""
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,6 +91,33 @@ class ModelConfig:
     num_patches: int = 0            # stubbed patch embedding prefix length
     dtype: str = "bfloat16"
     tie_embeddings: bool = False
+    # multi-head latent attention (DeepSeek-V3): queries through a
+    # `q_lora_rank` latent, keys and values through one `kv_lora_rank`
+    # latent per token plus a `qk_rope_head_dim` RoPE key shared by every
+    # head. kv_lora_rank > 0 selects it, with two layer stacks: the
+    # `first_k_dense` leading layers with a dense MLP of width d_ff, then
+    # expert layers (`moe`)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    first_k_dense: int = 0
+    yarn: Optional[YaRNConfig] = None   # rope_kind "yarn"
+
+    def __post_init__(self):
+        if self.is_mla:
+            return
+        moe, plain = self.moe, MoEConfig()
+        mla_only = [k for k in ("total_experts", "first_expert", "shared_d_ff",
+                                "n_group", "topk_group", "routed_scaling")
+                    if getattr(moe, k) != getattr(plain, k)]
+        if self.yarn is not None or self.rope_kind == "yarn":
+            mla_only.append("yarn")
+        if mla_only:
+            raise ValueError(
+                f"{self.name}: {', '.join(mla_only)} are read by the "
+                f"multi-head latent attention model alone (kv_lora_rank > 0)")
 
     @property
     def hd(self) -> int:
@@ -67,8 +127,41 @@ class ModelConfig:
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    def _mla_counts(self) -> Tuple[int, int]:
+        """(parameters, parameters a token uses) of an MLA model: the
+        latent projections, the indexer with its per-token head weights,
+        the leading dense layers, then expert layers with their router
+        (as wide as the routed experts), shared expert and held experts."""
+        d, h, v = self.d_model, self.n_heads, self.vocab
+        ql, kvl = self.q_lora_rank, self.kv_lora_rank
+        nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                          self.v_head_dim)
+        attn = (d * ql + ql * h * (nope + rope) + d * (kvl + rope)
+                + kvl * h * (nope + vd) + h * vd * d)
+        if self.dsa.enabled:
+            hi, di = self.dsa.indexer_heads, self.dsa.indexer_dim
+            attn += ql * hi * di + d * di + d * hi
+        moe = self.moe
+        expert = 3 * d * moe.expert_d_ff
+        outer = (attn + d * moe.routed_experts + moe.routed_experts
+                 + 3 * d * moe.shared_d_ff)
+        nd, nm = self.first_k_dense, self.n_layers - self.first_k_dense
+        dense = nd * (attn + 3 * d * self.d_ff)
+        emb = v * d * (1 if self.tie_embeddings else 2)
+        total = emb + dense + nm * (outer + moe.num_experts * expert)
+        # a token's top_k experts lie among the held ones held/routed of
+        # the time
+        used = moe.top_k * min(moe.num_experts / moe.routed_experts, 1.0)
+        return total, int(emb + dense + nm * (outer + used * expert))
+
     def param_count(self) -> int:
         """Approximate parameter count (embeddings + blocks)."""
+        if self.is_mla:
+            return self._mla_counts()[0]
         d, f, v, l = self.d_model, self.d_ff, self.vocab, self.n_layers
         hd = self.hd
         emb = v * d * (1 if self.tie_embeddings else 2)
@@ -103,6 +196,8 @@ class ModelConfig:
 
     def active_param_count(self) -> int:
         """Active params per token (MoE top-k instead of all experts)."""
+        if self.is_mla:
+            return self._mla_counts()[1]
         if not self.moe.num_experts:
             return self.param_count()
         d, l = self.d_model, self.n_layers

@@ -10,6 +10,7 @@ scatter/gather, not one-hot einsums — cost_analysis FLOPs stay 'useful'.
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Optional
 
@@ -45,10 +46,36 @@ def _rope_freqs(dim: int, base: float = 10000.0) -> jnp.ndarray:
     return 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
+def yarn_freqs(dim: int, base: float, yarn) -> jnp.ndarray:
+    """YaRN's rotary frequencies (DeepSeek-V3's `precompute_freqs_cis`):
+    the plain frequencies, divided by `yarn.factor` below the correction
+    range that beta_fast/beta_slow set over the original context, kept
+    above it, and ramped linearly between. `yarn` is a YaRNConfig."""
+    def corr(rot):
+        return (dim * math.log(yarn.original_max_position
+                               / (rot * 2 * math.pi)) / (2 * math.log(base)))
+    low = max(math.floor(corr(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    smooth = 1.0 - ramp
+    freqs = _rope_freqs(dim, base)
+    return freqs / yarn.factor * (1.0 - smooth) + freqs * smooth
+
+
+def yarn_mscale(yarn) -> float:
+    """YaRN's attention temperature m (softmax scale × m²)."""
+    return 0.1 * yarn.mscale_all_dim * math.log(yarn.factor) + 1.0
+
+
 def apply_rotary(x: jnp.ndarray, positions: jnp.ndarray, *, kind: str = "rope",
                  base: float = 10000.0, fraction: float = 1.0,
-                 mrope_sections=(16, 24, 24)) -> jnp.ndarray:
-    """x: (B, S, H, D); positions: (B, S) int32 (or (B, S, 3) for mrope)."""
+                 mrope_sections=(16, 24, 24),
+                 freqs: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """x: (B, S, H, D); positions: (B, S) int32 (or (B, S, 3) for mrope).
+    `freqs` (rot_d/2,) replaces the plain frequencies (YaRN: yarn_freqs)."""
     d = x.shape[-1]
     rot_d = int(d * fraction) // 2 * 2
     xr, xp = x[..., :rot_d], x[..., rot_d:]
@@ -70,7 +97,8 @@ def apply_rotary(x: jnp.ndarray, positions: jnp.ndarray, *, kind: str = "rope",
             axis=-1)                                          # (B, S, rot_d/2)
         ang = pos_per_freq * freqs[None, None, :]
     else:
-        freqs = _rope_freqs(rot_d, base)
+        if freqs is None:
+            freqs = _rope_freqs(rot_d, base)
         ang = positions.astype(jnp.float32)[..., None] * freqs[None, None, :]
 
     cos = jnp.cos(ang)[..., None, :].astype(x.dtype)          # (B, S, 1, rot_d/2)
@@ -310,3 +338,60 @@ def moe_mlp_ep(x, router_w, w_gate, w_up, w_down, *, top_k: int,
         in_specs=(tok_spec, P(), P(expert_axis), P(expert_axis), P(expert_axis)),
         out_specs=tok_spec, check_vma=False,
     )(x, router_w, w_gate, w_up, w_down)
+
+
+def route_grouped_sigmoid(x, router_w, router_bias, *, top_k: int,
+                          n_group: int, topk_group: int,
+                          routed_scaling: float):
+    """DeepSeek-V3's noaux_tc routing over every routed expert.
+
+    x: (T, D); router_w: (D, E) and router_bias: (E,), float32. Scores are
+    sigmoid(x W) in float32; the choice adds the bias. Each of `n_group`
+    groups of E / n_group experts scores by its two largest choices, the
+    `topk_group` best groups stay, and the `top_k` largest choices within
+    them are the token's experts. Their gates are the unbiased scores,
+    normalised to sum 1, times `routed_scaling`. The scores are float32
+    at full precision: a choice near a tie must not flip on a bfloat16
+    pass of the matmul. Returns (idx (T, top_k)
+    int32, gates (T, top_k) float32)."""
+    t = x.shape[0]
+    e = router_w.shape[-1]
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                                    router_w.astype(jnp.float32),
+                                    precision=jax.lax.Precision.HIGHEST))
+    choice = scores + router_bias.astype(jnp.float32)
+    grouped = choice.reshape(t, n_group, e // n_group)
+    group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)   # (T, G)
+    _, keep = jax.lax.top_k(group_score, topk_group)
+    kept = jnp.any(keep[:, :, None] == jnp.arange(n_group)[None, None, :],
+                   axis=1)                                          # (T, G)
+    masked = jnp.where(jnp.repeat(kept, e // n_group, axis=1), choice,
+                       -jnp.inf)
+    _, idx = jax.lax.top_k(masked, top_k)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True) * routed_scaling
+    return idx.astype(jnp.int32), gates
+
+
+def held_experts_mlp(x, idx, gates, w_gate, w_up, w_down, *,
+                     first_expert: int):
+    """This chip's part of a routed expert layer: the experts it holds,
+    [first_expert, first_expert + E_held), for every token routed to them.
+
+    x: (T, D); idx/gates: (T, top_k) from the router over all experts;
+    w_gate/w_up: (E_held, D, F), w_down: (E_held, F, D). Each held expert
+    runs on every token, weighted by the token's gate for it (zero where
+    the token is not routed there): at decode batch sizes an expert reads
+    its weights whatever the number of its rows, and nothing is dropped.
+    What experts held elsewhere add is not computed here. Returns (y
+    (T, D), held (T,) int32: the token's routed experts held here)."""
+    held = w_gate.shape[0]
+    local = idx - first_expert                                      # (T, k)
+    hit = local[..., None] == jnp.arange(held)[None, None, :]      # (T, k, E)
+    mix = jnp.sum(jnp.where(hit, gates[..., None], 0.0), axis=1)    # (T, E)
+    g = jnp.einsum("td,edf->tef", x, w_gate)
+    u = jnp.einsum("td,edf->tef", x, w_up)
+    h = jax.nn.silu(g) * u
+    h = h * mix[..., None].astype(h.dtype)
+    y = jnp.einsum("tef,efd->td", h, w_down)
+    return y, jnp.sum(hit, axis=(1, 2), dtype=jnp.int32)

@@ -35,8 +35,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.parallel.sharding import MeshRules, constrain
 from repro.sparse import dsa as dsa_mod
 from .config import ModelConfig
-from .layers import (apply_rotary, blockwise_causal_attention, decode_attention,
-                     decode_attention_paged, moe_mlp_ep, rms_norm, swiglu_mlp)
+from .layers import (_rope_freqs, apply_rotary, blockwise_causal_attention,
+                     decode_attention, decode_attention_paged,
+                     held_experts_mlp, moe_mlp_ep, rms_norm,
+                     route_grouped_sigmoid, swiglu_mlp, yarn_freqs,
+                     yarn_mscale)
 
 
 def _norm_init(d):
@@ -80,6 +83,8 @@ def init_layer_params(key, cfg: ModelConfig, dtype) -> Dict[str, Any]:
 
 
 def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
+    if cfg.is_mla:
+        return _init_mla_params(key, cfg)
     dtype = jnp.dtype(cfg.dtype)
     k_emb, k_layers, k_head = jax.random.split(key, 3)
     layer_keys = jax.random.split(k_layers, cfg.n_layers)
@@ -97,6 +102,7 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
 
 
 def param_specs(cfg: ModelConfig, rules: MeshRules) -> Dict[str, Any]:
+    _mla_refuse("param_specs (the sharded parameter layout)", cfg)
     d, hd = cfg.d_model, cfg.hd
     sp = rules.spec
     lp = {
@@ -173,6 +179,9 @@ def forward_train(params, tokens, cfg: ModelConfig, *, mesh=None,
                   remat: bool = True):
     """tokens: (B, S) int32 → logits (B, S, V). VLM: the first num_patches
     positions take the stubbed patch embeddings instead of token embeds."""
+    if cfg.is_mla:
+        return _mla_forward_train(params, tokens, cfg, rules=rules,
+                                  remat=remat)
     b, s = tokens.shape
     x = params["embed"][tokens]
     if cfg.num_patches and patch_embeds is not None:
@@ -216,6 +225,7 @@ def loss_fn(params, batch, cfg: ModelConfig, *, mesh=None, rules=None):
 
 def init_decode_state(cfg: ModelConfig, batch: int, max_len: int,
                       dtype=None) -> Dict[str, jnp.ndarray]:
+    _mla_refuse("init_decode_state (the dense-layout decode state)", cfg)
     dtype = dtype or jnp.dtype(cfg.dtype)
     l, hd = cfg.n_layers, cfg.hd
     state = {
@@ -282,6 +292,7 @@ def recycle_slot_state(cfg: ModelConfig, state: Dict[str, jnp.ndarray],
 
 def state_specs(cfg: ModelConfig, rules: MeshRules, *, batch: int, max_len: int,
                 seq_sharded: bool = False) -> Dict[str, Any]:
+    _mla_refuse("state_specs (the dense-layout decode state)", cfg)
     seq_ax = "seq_shard" if seq_sharded else None
     sp = rules.spec
     hd = cfg.hd
@@ -432,6 +443,20 @@ def sel_telemetry(res, valid) -> Dict[str, jnp.ndarray]:
             "sel_fallback": res.fallback, "sel_radix": res.radix_rows}
 
 
+# the columns of the counts a step returns with `with_counts`: the
+# selector's (`sel_counts`), then, where the step's expert layers hold a
+# share of the routed experts, the routed (token, expert) assignments held
+GVR_COUNTERS = ("gvr_row_layers", "gvr_secant_iters", "gvr_fallbacks",
+                "radix_row_layers")
+HELD_EXPERT_COUNTERS = ("held_expert_rows",)
+
+
+def step_counters(cfg: ModelConfig) -> Tuple[str, ...]:
+    """The names of the counts' columns, in order: the MLA step's expert
+    layers (`held_experts_mlp`) add HELD_EXPERT_COUNTERS."""
+    return GVR_COUNTERS + (HELD_EXPERT_COUNTERS if cfg.is_mla else ())
+
+
 def sel_counts(outs, b: int) -> jnp.ndarray:
     """Per row, summed over the layer scan's leading (layer) axis of its
     `sel_telemetry` stacks in `outs` ((L, ..., B) each): [GVR-served
@@ -461,6 +486,7 @@ def serve_step(params, state, tokens, cfg: ModelConfig, *, mesh=None,
     otherwise. prev-Top-K feedback is updated in place (the paper's
     per-layer prev_topk buffer).
     """
+    _mla_refuse("serve_step (the dense-layout decode body)", cfg)
     b = tokens.shape[0]
     hd = cfg.hd
     x = params["embed"][tokens]                          # (B, D)
@@ -567,6 +593,8 @@ def init_sp_paged_decode_state(cfg: ModelConfig, batch: int, max_len: int, *,
     mesh's "seq" axis (device s allocates only shard s), the rest
     replicated — no device ever holds the whole pool.
     """
+    _mla_refuse("init_sp_paged_decode_state (the sequence-sharded decode "
+                "state)", cfg)
     dtype = dtype or jnp.dtype(cfg.dtype)
     if max_len % (page_size * seq_shards) != 0:
         raise ValueError(
@@ -756,6 +784,7 @@ def serve_step_sp_paged(params, state, tokens, cfg: ModelConfig, *, mesh,
     `max_len > cfg.dsa.min_n`): sequence sharding exists for long contexts,
     and the dense fallback attention has no sharded form here.
     """
+    _mla_refuse("serve_step_sp_paged (the sequence-sharded decode body)", cfg)
     b = tokens.shape[0]
     _sp_paged_validate(state, cfg, mesh, seq_axis)
     mwp = (min_write_pos if min_write_pos is not None
@@ -825,13 +854,19 @@ def init_paged_decode_state(cfg: ModelConfig, batch: int, max_len: int, *,
     l, hd = cfg.n_layers, cfg.hd
     mp = max_len // page_size
     state = {
-        "k_pages": jnp.zeros((l, num_pages + 1, page_size, cfg.n_kv_heads, hd),
-                             dtype),
-        "v_pages": jnp.zeros((l, num_pages + 1, page_size, cfg.n_kv_heads, hd),
-                             dtype),
         "page_table": jnp.full((batch, mp), -1, jnp.int32),
         "length": jnp.zeros((batch,), jnp.int32),
     }
+    if cfg.is_mla:
+        # one latent pool: each row [c_kv | k_r], shared by every head
+        state["kv_pages"] = jnp.zeros(
+            (l, num_pages + 1, page_size,
+             cfg.kv_lora_rank + cfg.qk_rope_head_dim), dtype)
+    else:
+        state["k_pages"] = jnp.zeros(
+            (l, num_pages + 1, page_size, cfg.n_kv_heads, hd), dtype)
+        state["v_pages"] = jnp.zeros(
+            (l, num_pages + 1, page_size, cfg.n_kv_heads, hd), dtype)
     if cfg.dsa.enabled:
         from repro.core.temporal import seed_slot_idx
         state["idx_k_pages"] = jnp.zeros(
@@ -853,6 +888,26 @@ def paged_state_batch_axes(cfg: ModelConfig) -> Dict[str, int]:
     if cfg.dsa.enabled:
         axes.update(prev_topk=1, topk_valid=1, sel_gvr=1)
     return axes
+
+
+def _paged_targets(state, pool: str, min_write_pos):
+    """Where a paged step writes each row's new token: (positions, new_len,
+    dest page (the sink page for masked rows), offset, the clipped table
+    for logical views, logical extent)."""
+    positions = state["length"]
+    new_len = positions + 1
+    table = state["page_table"]
+    page_size = state[pool].shape[2]
+    sink = state[pool].shape[1] - 1
+    lp = positions // page_size
+    off = positions % page_size
+    phys = jnp.take_along_axis(table, lp[:, None], axis=1)[:, 0]
+    writable = phys >= 0
+    if min_write_pos is not None:
+        writable &= positions >= min_write_pos
+    dest = jnp.where(writable, phys, sink)
+    return (positions, new_len, dest, off, jnp.clip(table, 0, sink),
+            table.shape[1] * page_size)
 
 
 def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
@@ -894,26 +949,18 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
     page-granular moves each distinct touched page whole and slices rows
     out in fast memory — coarser descriptors, bit-identical output
     (sparse.dsa.dsa_sparse_attention_paged).
+
+    An MLA configuration runs its latent form (`_mla_serve_step_paged`).
     """
+    if cfg.is_mla:
+        return _mla_serve_step_paged(
+            params, state, tokens, cfg, min_write_pos=min_write_pos,
+            paged_attn=paged_attn, gather_granularity=gather_granularity,
+            mesh=mesh, rules=rules, with_counts=with_counts)
     b = tokens.shape[0]
     hd = cfg.hd
     x = params["embed"][tokens]                          # (B, D)
     x = constrain(x, rules, "batch", "d_model")
-    positions = state["length"]                          # 0-based write pos
-    new_len = state["length"] + 1
-    table = state["page_table"]
-    page_size = state["k_pages"].shape[2]
-    sink = state["k_pages"].shape[1] - 1                 # last physical page
-    mp = table.shape[1]
-    n = mp * page_size                                   # logical extent
-
-    lp = positions // page_size
-    off = positions % page_size
-    phys = jnp.take_along_axis(table, lp[:, None], axis=1)[:, 0]
-    writable = phys >= 0
-    if min_write_pos is not None:
-        writable &= positions >= min_write_pos
-    dest = jnp.where(writable, phys, sink)
     # `gather` materializes a logical view: unmapped pages clip to page 0 —
     # garbage rows, dead beyond `length` under the NEG_SENTINEL masking
     # convention (finite values, so their post-mask contribution is exactly
@@ -922,7 +969,9 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
     # attention itself never builds a logical view — it addresses the page
     # pools through the raw table, masking the -1 sentinel explicitly
     # (dsa_sparse_attention_paged / kernels.paged_sparse_decode_attn).
-    gather = jnp.clip(table, 0, sink)
+    positions, new_len, dest, off, gather, n = _paged_targets(
+        state, "k_pages", min_write_pos)
+    table = state["page_table"]
 
     if paged_attn not in ("fused", "gather"):
         raise ValueError(f"unknown paged_attn {paged_attn!r} "
@@ -1003,6 +1052,326 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
     if with_counts:
         return logits, new_state, sel_counts(outs, b)
     return logits, new_state
+
+
+# --------------------------------------------------------------------------
+# Multi-head latent attention with the V3.2 indexer and held experts
+# (DeepSeek-V3.2; ModelConfig.is_mla)
+# --------------------------------------------------------------------------
+#
+# Per layer, h = rms_norm(x) · ln1:
+#
+#   c_q = rms_norm(h W_qa) · q_norm;  q = c_q W_qb → H × (nope | rope)
+#   [c_kv | k_r] = h W_kva;  c_kv = rms_norm(c_kv) · kv_norm;  k_r shared by
+#       every head; q's and k_r's rope dims YaRN-RoPE'd
+#   W_kvb: kv_lora_rank → H × (nope k | v) = [W_uk | W_uv]
+#   attention score (nope + rope)^-0.5 · m² (q_nope·k_nope + q_rope·k_r)
+#
+# Decode runs the absorbed form: the cache row is [c_kv | k_r], one latent
+# "KV head" for all H heads; q̃ = q_nope W_ukᵀ scores c_kv directly, and the
+# softmax-weighted sum of c_kv goes through W_uv and W_o. Training runs the
+# plain form (keys and values per head). The indexer reads the query
+# latent (`sparse.dsa.indexer_query_latent`). The first `first_k_dense`
+# layers (`dense_layers`) end in a SwiGLU of width d_ff; the rest
+# (`moe_layers`) in a shared expert plus the experts this chip holds of
+# the routed ones (`layers.held_experts_mlp`).
+
+def _mla_refuse(body: str, cfg: ModelConfig) -> None:
+    """The decode bodies that have no latent-attention form say so."""
+    if cfg.is_mla:
+        raise ValueError(
+            f"{body} has no multi-head latent attention form ({cfg.name}): "
+            f"MLA configurations decode through serve_step_paged "
+            f"(paged_attn='fused')")
+
+
+def _mla_freqs(cfg: ModelConfig) -> jnp.ndarray:
+    """The rotary frequencies of the rope dims, YaRN-scaled where set."""
+    if cfg.yarn is not None:
+        return yarn_freqs(cfg.qk_rope_head_dim, cfg.rope_base, cfg.yarn)
+    return _rope_freqs(cfg.qk_rope_head_dim, cfg.rope_base)
+
+
+def _mla_scale(cfg: ModelConfig) -> float:
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn is not None:
+        scale *= yarn_mscale(cfg.yarn) ** 2
+    return scale
+
+
+def _init_mla_layer(key, cfg: ModelConfig, dtype, moe: bool):
+    d, h = cfg.d_model, cfg.n_heads
+    ql, kvl = cfg.q_lora_rank, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    hi, di = cfg.dsa.indexer_heads, cfg.dsa.indexer_dim
+    ks = jax.random.split(key, 16)
+    p = {
+        "ln1": _norm_init(d), "ln2": _norm_init(d),
+        "wq_a": _dense(ks[0], (d, ql), dtype),
+        "q_norm": _norm_init(ql),
+        "wq_b": _dense(ks[1], (ql, h * (nope + rope)), dtype),
+        "wkv_a": _dense(ks[2], (d, kvl + rope), dtype),
+        "kv_norm": _norm_init(kvl),
+        "wkv_b": _dense(ks[3], (kvl, h * (nope + vd)), dtype),
+        "wo": _dense(ks[4], (h * vd, d), dtype),
+        "idx_wq_b": _dense(ks[5], (ql, hi * di), dtype),
+        "idx_wk": _dense(ks[6], (d, di), dtype),
+        "idx_k_norm": _norm_init(di),
+        "idx_k_norm_bias": jnp.zeros((di,), jnp.float32),
+        "idx_weights_proj": _dense(ks[7], (d, hi), dtype),
+    }
+    if not moe:
+        p["w_gate"] = _dense(ks[8], (d, cfg.d_ff), dtype)
+        p["w_up"] = _dense(ks[9], (d, cfg.d_ff), dtype)
+        p["w_down"] = _dense(ks[10], (cfg.d_ff, d), dtype)
+        return p
+    m = cfg.moe
+    e, f, fs = m.num_experts, m.expert_d_ff, m.shared_d_ff
+    p["router"] = _dense(ks[11], (d, m.routed_experts), jnp.float32)
+    p["router_bias"] = jnp.zeros((m.routed_experts,), jnp.float32)
+    p["shared_gate"] = _dense(ks[12], (d, fs), dtype)
+    p["shared_up"] = _dense(ks[13], (d, fs), dtype)
+    p["shared_down"] = _dense(ks[14], (fs, d), dtype)
+    kg, ku, kd = jax.random.split(ks[15], 3)
+    p["w_gate"] = _dense(kg, (e, d, f), dtype, scale=d ** -0.5)
+    p["w_up"] = _dense(ku, (e, d, f), dtype, scale=d ** -0.5)
+    p["w_down"] = _dense(kd, (e, f, d), dtype, scale=f ** -0.5)
+    return p
+
+
+def _mla_stacks(cfg: ModelConfig):
+    """(stack name, first layer, layer count, expert layer?) of each
+    non-empty layer stack, in order."""
+    nd = cfg.first_k_dense
+    out = [("dense_layers", 0, nd, False),
+           ("moe_layers", nd, cfg.n_layers - nd, True)]
+    return [s for s in out if s[2] > 0]
+
+
+def _init_mla_params(key, cfg: ModelConfig) -> Dict[str, Any]:
+    dtype = jnp.dtype(cfg.dtype)
+    k_emb, k_head, *k_stacks = jax.random.split(key, 4)
+    params = {
+        "embed": _dense(k_emb, (cfg.vocab, cfg.d_model), dtype, scale=1.0),
+        "final_norm": _norm_init(cfg.d_model),
+        "lm_head": _dense(k_head, (cfg.d_model, cfg.vocab), dtype),
+    }
+    for (name, _, n, moe), k in zip(_mla_stacks(cfg), k_stacks):
+        params[name] = jax.vmap(
+            lambda kk: _init_mla_layer(kk, cfg, dtype, moe))(
+                jax.random.split(k, n))
+    return params
+
+
+def _mla_moe(p, h, cfg: ModelConfig):
+    """The expert block of an MLA expert layer on rows h (T, D): the shared
+    expert once, plus the routed experts held here (`step.experts`).
+    Returns (y (T, D), held (T,): each row's routed experts held here)."""
+    m = cfg.moe
+    y = swiglu_mlp(h, p["shared_gate"], p["shared_up"], p["shared_down"])
+    with jax.named_scope("step.experts"):
+        idx, gates = route_grouped_sigmoid(
+            h, p["router"], p["router_bias"], top_k=m.top_k,
+            n_group=m.n_group, topk_group=m.topk_group,
+            routed_scaling=m.routed_scaling)
+        routed, held = held_experts_mlp(h, idx, gates, p["w_gate"],
+                                        p["w_up"], p["w_down"],
+                                        first_expert=m.first_expert)
+        y = y + routed
+    return y, held
+
+
+def _mla_mlp(p, h, cfg: ModelConfig, moe: bool):
+    """The MLP of an MLA layer on rows h (T, D): (y, held rows or None)."""
+    if moe:
+        return _mla_moe(p, h, cfg)
+    return swiglu_mlp(h, p["w_gate"], p["w_up"], p["w_down"]), None
+
+
+def _mla_attention_train(p, h, cfg: ModelConfig, positions):
+    """Plain (non-absorbed) causal MLA over whole sequences. h: (B, S, D)."""
+    b, s, _ = h.shape
+    nh, kvl = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    freqs = _mla_freqs(cfg)
+    cq = rms_norm(h @ p["wq_a"], p["q_norm"])
+    q = (cq @ p["wq_b"]).reshape(b, s, nh, nope + rope)
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rotary(q[..., nope:], positions, freqs=freqs)],
+        axis=-1)
+    kv = h @ p["wkv_a"]
+    ckv = rms_norm(kv[..., :kvl], p["kv_norm"])
+    k_r = apply_rotary(kv[..., None, kvl:], positions, freqs=freqs)
+    kvb = (ckv @ p["wkv_b"]).reshape(b, s, nh, nope + vd)
+    k = jnp.concatenate(
+        [kvb[..., :nope], jnp.broadcast_to(k_r, (b, s, nh, rope))], axis=-1)
+    # the blockwise kernel takes one head width: values padded to the
+    # query's, the padding sliced off again
+    v = jnp.pad(kvb[..., nope:], ((0, 0),) * 3 + ((0, nope + rope - vd),))
+    out = blockwise_causal_attention(q, k, v, scale=_mla_scale(cfg))
+    out = out[..., :vd].reshape(b, s, nh * vd).astype(h.dtype)
+    return out @ p["wo"]
+
+
+def _mla_forward_train(params, tokens, cfg: ModelConfig, *, rules, remat):
+    b, s = tokens.shape
+    x = constrain(params["embed"][tokens], rules, "batch", "seq", "d_model")
+    positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
+    for name, _, _, moe in _mla_stacks(cfg):
+        def layer(x, p, moe=moe):
+            x = x + _mla_attention_train(p, rms_norm(x, p["ln1"]), cfg,
+                                         positions)
+            h = rms_norm(x, p["ln2"]).reshape(b * s, -1)
+            m, _ = _mla_mlp(p, h, cfg, moe)
+            x = x + m.reshape(b, s, -1)
+            return constrain(x, rules, "batch", "seq", "d_model"), None
+        if remat:
+            layer = jax.checkpoint(layer, prevent_cse=False)
+        x, _ = jax.lax.scan(layer, x, params[name])
+    x = rms_norm(x, params["final_norm"])
+    return constrain(x @ params["lm_head"], rules, "batch", "seq", "vocab")
+
+
+def _mla_project(p, h, positions, cfg: ModelConfig, freqs):
+    """One decode token's latent projections (`step.qkv`). h: (B, D).
+    Returns the absorbed queries (B, H, kv_lora_rank + rope) —
+    [q_nope W_ukᵀ | q_rope] — the cache row (B, kv_lora_rank + rope) —
+    [c_kv | k_r] — and the normed query latent c_q (B, q_lora_rank)."""
+    b = h.shape[0]
+    nh, kvl = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    cq = rms_norm(h @ p["wq_a"], p["q_norm"])
+    q = (cq @ p["wq_b"]).reshape(b, 1, nh, nope + rope)
+    q_r = apply_rotary(q[..., nope:], positions[:, None], freqs=freqs)[:, 0]
+    kv = h @ p["wkv_a"]
+    ckv = rms_norm(kv[:, :kvl], p["kv_norm"])
+    k_r = apply_rotary(kv[:, None, None, kvl:], positions[:, None],
+                       freqs=freqs)[:, 0, 0]
+    w_uk = p["wkv_b"].reshape(kvl, nh, nope + vd)[..., :nope]
+    q_abs = jnp.einsum("bhn,chn->bhc", q[:, 0, :, :nope], w_uk,
+                       preferred_element_type=jnp.float32)
+    qf = jnp.concatenate([q_abs.astype(q_r.dtype), q_r], axis=-1)
+    return qf, jnp.concatenate([ckv, k_r], axis=-1), cq
+
+
+def _mla_out(p, o_lat, cfg: ModelConfig):
+    """Σ p c_kv (B, H, kv_lora_rank) through W_uv and W_o (`step.attention`)."""
+    b = o_lat.shape[0]
+    nh, kvl = cfg.n_heads, cfg.kv_lora_rank
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    w_uv = p["wkv_b"].reshape(kvl, nh, nope + vd)[..., nope:]
+    o = jnp.einsum("bhc,chv->bhv", o_lat.astype(w_uv.dtype), w_uv)
+    return o.reshape(b, nh * vd) @ p["wo"]
+
+
+def _mla_serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
+                          min_write_pos, paged_attn: str,
+                          gather_granularity: str, mesh, rules,
+                          with_counts: bool):
+    """`serve_step_paged` for an MLA configuration: the absorbed latent
+    decode over one latent page pool (`kv_pages`, rows [c_kv | k_r]), the
+    dense then the expert layer stack each scanned over its own slice of
+    the layer-stacked state. With the DSA gate open the V3.2 indexer
+    selects through `dsa_select` and the selector, and attention gathers
+    the K latent rows from the pool (`mla_sparse_attention_paged`);
+    closed, it attends the whole logical extent through
+    `decode_attention_paged` with the pool as its one KV head. Counts: the
+    selector's (`sel_counts`) and, fifth, the row's routed experts held
+    here, summed over the expert layers (HELD_EXPERT_COUNTERS)."""
+    if paged_attn != "fused":
+        raise ValueError(
+            f"serve_step_paged(paged_attn={paged_attn!r}) has no multi-head "
+            f"latent attention form ({cfg.name}): use 'fused'")
+    b = tokens.shape[0]
+    kvl, di = cfg.kv_lora_rank, cfg.dsa.indexer_dim
+    x = constrain(params["embed"][tokens], rules, "batch", "d_model")
+    positions, new_len, dest, off, gather, n = _paged_targets(
+        state, "kv_pages", min_write_pos)
+    table = state["page_table"]
+    use_dsa = cfg.dsa.enabled and n > cfg.dsa.min_n
+    freqs = _mla_freqs(cfg)
+    scale = _mla_scale(cfg)
+
+    def layer(x, carry, moe):
+        p, kvp = carry["p"], carry["kv_pages"]
+        idx_kp = carry.get("idx_k_pages")
+        prev_topk, topk_valid = carry.get("prev_topk"), carry.get("topk_valid")
+        with jax.named_scope("step.qkv"):
+            h = rms_norm(x, p["ln1"])
+            q, row, cq = _mla_project(p, h, positions, cfg, freqs)
+        with jax.named_scope("step.kv_write"):
+            kvp = kvp.at[dest, off].set(row.astype(kvp.dtype))
+        out = {"kv_pages": kvp}
+        if use_dsa:
+            with jax.named_scope("step.indexer"):
+                ik = dsa_mod.indexer_k_latent(p, h, positions, dim=di,
+                                              freqs=freqs)
+                query = dsa_mod.indexer_query_latent(
+                    p, cq, h, positions, heads=cfg.dsa.indexer_heads,
+                    dim=di, freqs=freqs)
+            with jax.named_scope("step.kv_write"):
+                idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
+            with jax.named_scope("step.indexer"):
+                idx_kc = idx_kp[gather].reshape(b, n, di)
+            sel = dsa_mod.dsa_select(
+                None, h, idx_kc, prev_topk, new_len, k=prev_topk.shape[-1],
+                heads=cfg.dsa.indexer_heads, dim=di, rope_base=cfg.rope_base,
+                selector=cfg.dsa.selector, prev_valid=topk_valid,
+                max_candidates=cfg.dsa.max_candidates,
+                gate_max_n=cfg.dsa.gate_max_n, min_n=cfg.dsa.min_n,
+                rules=rules, mesh=mesh, query=query)
+            with jax.named_scope("step.attention"):
+                o_lat = dsa_mod.mla_sparse_attention_paged(
+                    q, kvp, table, sel.indices, new_len, scale=scale,
+                    v_dim=kvl, granularity=gather_granularity, rules=rules)
+            out["prev_topk"] = sel.indices
+            out["topk_valid"] = jnp.ones_like(topk_valid)
+            out.update(sel_telemetry(sel, topk_valid))
+        else:
+            with jax.named_scope("step.attention"):
+                lat = kvp[:, :, None]
+                o_lat = decode_attention_paged(q, lat, lat, table, new_len,
+                                               scale=scale,
+                                               rules=rules)[..., :kvl]
+            if prev_topk is not None:
+                out["prev_topk"], out["topk_valid"] = prev_topk, topk_valid
+                out.update(sel_telemetry(None, topk_valid))
+        if idx_kp is not None:
+            out["idx_k_pages"] = idx_kp
+        with jax.named_scope("step.attention"):
+            x = x + _mla_out(p, o_lat, cfg)
+        with jax.named_scope("step.mlp"):
+            m, held = _mla_mlp(p, rms_norm(x, p["ln2"]), cfg, moe)
+            x = x + m
+        if held is not None:
+            out["held_rows"] = held
+        return constrain(x, rules, "batch", "d_model"), out
+
+    layered = ["kv_pages"]
+    if cfg.dsa.enabled:
+        layered += ["idx_k_pages", "prev_topk", "topk_valid"]
+    outs = []
+    for name, lo, cnt, moe in _mla_stacks(cfg):
+        carry_in = {"p": params[name]}
+        carry_in.update({k: state[k][lo:lo + cnt] for k in layered})
+        x, o = jax.lax.scan(partial(layer, moe=moe), x, carry_in)
+        outs.append(o)
+    cat = {k: jnp.concatenate([o[k] for o in outs], axis=0)
+           for k in outs[0] if k != "held_rows"}
+
+    new_state = dict(state)
+    for k in layered + (["sel_gvr"] if cfg.dsa.enabled else []):
+        new_state[k] = cat[k]
+    new_state["length"] = new_len
+    logits = constrain(_lm_head(params, x, cfg), rules, "batch", "vocab")
+    if not with_counts:
+        return logits, new_state
+    held = jnp.zeros((b,), jnp.int32)
+    for o in outs:
+        if "held_rows" in o:
+            held = held + jnp.sum(o["held_rows"], axis=0)
+    counts = jnp.concatenate([sel_counts(cat, b), held[:, None]], axis=-1)
+    return logits, new_state, counts
 
 
 # --------------------------------------------------------------------------
@@ -1377,6 +1746,8 @@ def serve_step_spec_paged(params, state, tokens, cfg: ModelConfig, *,
     sel_gvr_pos (B, D+1), gvr_counts (B, 4), new_state) — see
     `_spec_verify_scan`.
     """
+    _mla_refuse(f"serve_step_spec_paged (the {verify_kernel!r} verify body)",
+                cfg)
     b = tokens.shape[0]
     base_mwp = (min_write_pos if min_write_pos is not None
                 else jnp.zeros((b,), jnp.int32))
@@ -1579,6 +1950,8 @@ def serve_step_sp_spec_paged(params, state, tokens, cfg: ModelConfig, *,
     per row (`_sp_paged_verify_mq_body`) — bit-identical in tokens,
     accept traces, feedback and telemetry.
     """
+    _mla_refuse(f"serve_step_sp_spec_paged (the sequence-sharded "
+                f"{verify_kernel!r} verify body)", cfg)
     b = tokens.shape[0]
     _sp_paged_validate(state, cfg, mesh, seq_axis)
     if verify_kernel not in ("scan", "mq"):

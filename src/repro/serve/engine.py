@@ -102,15 +102,12 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.models.transformer import PAGED_NEVER_WRITE
+from repro.models.transformer import GVR_COUNTERS  # noqa: F401  (re-export)
 
 from . import sampling
 from .feedback_pool import FeedbackPool
 from .paged import PagedKVManager, PoolExhausted, ShardedPagedKVManager
 from .scheduler import DECODE, DONE, PREFILL, QUEUED, Scheduler, make_scheduler
-
-# the on-device GVR counters, in the order of the state's `gvr_counters` leaf
-GVR_COUNTERS = ("gvr_row_layers", "gvr_secant_iters", "gvr_fallbacks",
-                "radix_row_layers")
 
 
 def _add_counts(total, counts, active):
@@ -405,6 +402,8 @@ class DecodeEngine:
             self.kv = None
             self.state = model.init_decode_state(self.num_slots, self.max_len)
         # pool-global (no slot axis, so the row merge passes it whole)
+        # the names of its entries: the columns of the step's counts
+        self.counter_names = model.step_counters()
         self.state["gvr_counters"] = self._zero_counters()
 
         # speculative decoding (serve.spec): the drafter proposes up to
@@ -492,7 +491,7 @@ class DecodeEngine:
         return merged
 
     def _zero_counters(self):
-        zeros = jnp.zeros((len(GVR_COUNTERS),), jnp.int32)
+        zeros = jnp.zeros((len(self.counter_names),), jnp.int32)
         if self.seq_shards > 1:
             # replicated over the mesh like the rest of the small state: a
             # leaf on one device would be another step signature
@@ -576,17 +575,21 @@ class DecodeEngine:
 
     def counters(self) -> Dict[str, int]:
         """The on-device GVR counters since the last read, as Python ints,
-        which are then reset (keys: GVR_COUNTERS). `gvr_row_layers` counts
-        the row-layers of active slots whose Top-K the GVR path served,
-        `gvr_secant_iters` their secant iterations, `gvr_fallbacks` those
-        that fell back to GVR's safety net, and `radix_row_layers` the
-        row-layers of active slots whose radix path was computed (every
-        row of a layer whose batch held a cold row, none where all rows
-        were warm: 1 − `radix_row_layers` ÷ the active row-layers with DSA
-        on is the share of radix the selector skipped). Every step adds to
-        them on the device; a read is their only transfer, so read them at
-        the edges of a measured window, not per tick (`run()` reads them at
-        its start and end).
+        which are then reset (keys: `counter_names`, the model's
+        `step_counters()`: GVR_COUNTERS, then any its expert layers add).
+        `gvr_row_layers` counts the row-layers of active slots whose Top-K
+        the GVR path served, `gvr_secant_iters` their secant iterations,
+        `gvr_fallbacks` those that fell back to GVR's safety net, and
+        `radix_row_layers` the row-layers of active slots whose radix path
+        was computed (every row of a layer whose batch held a cold row,
+        none where all rows were warm: 1 − `radix_row_layers` ÷ the active
+        row-layers with DSA on is the share of radix the selector skipped).
+        `held_expert_rows` counts the active rows' routed (token, expert)
+        assignments that land on the experts this chip holds, over the
+        expert layers: top_k × expert layers × tokens × held/routed on
+        average. Every step adds to them on the device; a read is their
+        only transfer, so read them at the edges of a measured window, not
+        per tick (`run()` reads them at its start and end).
 
         The leaf is int32. A step adds at most 12 iterations
         (DEFAULT_MAX_SECANT) per row-layer, so at the chat cell's shape and
@@ -595,7 +598,7 @@ class DecodeEngine:
         layers, 4 slots, ~33 steps a second) at least 53 hours."""
         values = np.asarray(self.state["gvr_counters"])
         self.state["gvr_counters"] = self._zero_counters()
-        return {key: int(v) for key, v in zip(GVR_COUNTERS, values)}
+        return {key: int(v) for key, v in zip(self.counter_names, values)}
 
     def _log(self, req: Request, method: str) -> None:
         self.method_log[req.uid].append((self.tick_count, req.phase, method))
@@ -633,7 +636,7 @@ class DecodeEngine:
         `(shard, src, dst)` for the sequence-sharded one (page ids are
         shard-local there — copying across the global page axis would hit
         the wrong shard's pool)."""
-        for key in ("k_pages", "v_pages", "idx_k_pages"):
+        for key in ("k_pages", "v_pages", "kv_pages", "idx_k_pages"):
             if key in self.state:
                 arr = self.state[key]
                 if self.seq_shards > 1:

@@ -37,7 +37,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rotary
+from repro.models.layers import apply_rotary, layer_norm
 from .selector import select_topk
 
 NEG = -3.4028235e38
@@ -82,6 +82,51 @@ def indexer_k(params, x: jnp.ndarray, positions: jnp.ndarray,
     kk = (x @ params["wk"]).reshape(x.shape[0], 1, 1, dim)
     return apply_rotary(kk, positions[:, None], kind="rope",
                         base=rope_base)[:, 0, 0]
+
+
+def indexer_query_latent(params, c_q: jnp.ndarray, x: jnp.ndarray,
+                         positions: jnp.ndarray, *, heads: int, dim: int,
+                         freqs: jnp.ndarray):
+    """DeepSeek-V3.2's indexer query for the new token: heads from the
+    normed query latent `c_q` (B, q_lora_rank), RoPE'd on their first
+    2·len(freqs) dims, and per-token head weights from the normed hidden
+    state `x` (B, D), `weights_proj(x) · heads^-0.5`, with the score's
+    dim^-0.5 folded in. Returns (q (B, heads, dim), w (B, heads) f32)."""
+    b = x.shape[0]
+    rot = 2 * freqs.shape[0]
+    q = (c_q @ params["idx_wq_b"]).reshape(b, 1, heads, dim)
+    q = apply_rotary(q, positions[:, None], fraction=rot / dim,
+                     freqs=freqs)[:, 0]
+    w = jnp.dot(x, params["idx_weights_proj"],
+                preferred_element_type=jnp.float32)
+    return q, w * (heads ** -0.5 * dim ** -0.5)
+
+
+def indexer_k_latent(params, x: jnp.ndarray, positions: jnp.ndarray, *,
+                     dim: int, freqs: jnp.ndarray) -> jnp.ndarray:
+    """DeepSeek-V3.2's indexer key for the new token (B, dim):
+    LayerNorm(x W_k) (eps 1e-6), RoPE'd on its first 2·len(freqs) dims."""
+    kk = layer_norm(x @ params["idx_wk"], params["idx_k_norm"],
+                    params["idx_k_norm_bias"], eps=1e-6)
+    kk = kk.reshape(x.shape[0], 1, 1, dim)
+    return apply_rotary(kk, positions[:, None], fraction=2 * freqs.shape[0]
+                        / dim, freqs=freqs)[:, 0, 0]
+
+
+def indexer_scores_query(q: jnp.ndarray, w: jnp.ndarray,
+                         idx_kcache: jnp.ndarray, lengths: jnp.ndarray,
+                         rules=None) -> jnp.ndarray:
+    """Eq. 1 with per-token head weights: I = sum_j w_j ReLU(q_j · K_I^T).
+    q: (B, H, dim) RoPE'd; w: (B, H) f32; idx_kcache: (B, N, dim). Returns
+    (B, N) f32 scores with sentinel beyond `lengths`."""
+    from repro.parallel.sharding import constrain
+    n = idx_kcache.shape[1]
+    idx_kcache = constrain(idx_kcache, rules, "batch", None, None)
+    s = jnp.einsum("bhd,bnd->bhn", q.astype(idx_kcache.dtype), idx_kcache,
+                   preferred_element_type=jnp.float32)
+    scores = jnp.einsum("bh,bhn->bn", w, jax.nn.relu(s))
+    pos = jnp.arange(n, dtype=jnp.int32)
+    return jnp.where(pos[None, :] < lengths[:, None], scores, NEG)
 
 
 class DSAOutput(NamedTuple):
@@ -261,6 +306,39 @@ def dsa_sparse_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     return out.reshape(b, h, hd)
 
 
+def mla_sparse_attention_paged(q: jnp.ndarray, kv_pages: jnp.ndarray,
+                               table: jnp.ndarray, topk_idx: jnp.ndarray,
+                               lengths: jnp.ndarray, *, scale: float,
+                               v_dim: int, granularity: str = "token",
+                               rules=None) -> jnp.ndarray:
+    """Absorbed latent attention over the Top-K rows of one latent pool —
+    `dsa_sparse_attention_paged` with a single KV "head" whose keys are the
+    whole cached row [c_kv | k_rope] and whose values are its first `v_dim`
+    features (the latent c_kv).
+
+    q: (B, H, C) absorbed queries [q_nope W_uk | q_rope]; kv_pages:
+    (P, page_size, C); table: (B, MP); topk_idx: (B, K) LOGICAL indices.
+    The K rows are gathered once (`_gather_topk_rows_paged`) and serve as
+    keys and values both. Returns (B, H, v_dim) f32: sum_s p_s c_kv(s)."""
+    from repro.parallel.sharding import constrain
+    p, page_size = kv_pages.shape[:2]
+    n = table.shape[1] * page_size
+    q = constrain(q, rules, "batch", None, None)
+    li = jnp.clip(topk_idx, 0, n - 1)
+    phys = jnp.take_along_axis(table, li // page_size, axis=1)     # (B, K)
+    valid = ((topk_idx >= 0) & (topk_idx < lengths[:, None])
+             & (phys >= 0))
+    rows = _gather_topk_rows_paged(kv_pages, table, li, phys,
+                                   granularity=granularity)        # (B, K, C)
+    rows = constrain(rows, rules, "batch", None, None)
+    logits = jnp.einsum("bhc,bkc->bhk", q.astype(rows.dtype), rows,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(valid[:, None, :], logits, NEG)
+    pr = jax.nn.softmax(logits, axis=-1)
+    return jnp.einsum("bhk,bkc->bhc", pr.astype(rows.dtype),
+                      rows[..., :v_dim], preferred_element_type=jnp.float32)
+
+
 def dsa_sparse_attention_paged_mq(q: jnp.ndarray, k_pages: jnp.ndarray,
                                   v_pages: jnp.ndarray, table: jnp.ndarray,
                                   topk_idx: jnp.ndarray,
@@ -295,16 +373,23 @@ def dsa_select(indexer_params, x: jnp.ndarray, idx_kcache: jnp.ndarray,
                prev_valid: Optional[jnp.ndarray] = None,
                max_candidates: Optional[int] = None,
                gate_max_n: int = 200_000, min_n: int = 4096,
-               swa_window: Optional[int] = None, rules=None, mesh=None):
+               swa_window: Optional[int] = None, rules=None, mesh=None,
+               query=None):
     """Indexer scoring + Top-K selection (the layout-independent front half
     of the DSA pipeline — shared by the logical-view and paged attention
     forms, which is what keeps them bit-identical). The two stages carry
-    the decode step's `step.indexer` and `step.topk` scopes."""
+    the decode step's `step.indexer` and `step.topk` scopes. `query`, a
+    (q, w) pair from `indexer_query_latent`, replaces the query the
+    indexer would project from `x` with its fixed head weights."""
     positions = lengths - 1
     with jax.named_scope("step.indexer"):
-        scores = indexer_scores(indexer_params, x, idx_kcache, positions,
-                                lengths, heads=heads, dim=dim,
-                                rope_base=rope_base, rules=rules)
+        if query is not None:
+            scores = indexer_scores_query(*query, idx_kcache, lengths,
+                                          rules=rules)
+        else:
+            scores = indexer_scores(indexer_params, x, idx_kcache, positions,
+                                    lengths, heads=heads, dim=dim,
+                                    rope_base=rope_base, rules=rules)
         if swa_window is not None:
             # SWA interplay: selection restricted to the attention window
             pos = jnp.arange(scores.shape[-1], dtype=jnp.int32)

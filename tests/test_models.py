@@ -38,14 +38,26 @@ def test_arch_smoke_train_and_decode(arch):
     loss = model.loss_fn(params, batch)
     assert loss.shape == () and bool(jnp.isfinite(loss)), arch
 
-    # a few decode steps with the cache exercised past the DSA gate
+    # a few decode steps with the cache exercised past the DSA gate; an
+    # MLA config decodes through its served body, the paged step
     b, max_len = 2, 64
-    state = model.init_decode_state(batch=b, max_len=max_len)
     toks = jnp.asarray(RNG.integers(0, cfg.vocab, (20, b)), jnp.int32)
+    if cfg.is_mla:
+        page = 16
+        state = model.init_paged_decode_state(
+            b, max_len, num_pages=b * max_len // page, page_size=page)
+        state["page_table"] = jnp.arange(
+            b * max_len // page, dtype=jnp.int32).reshape(b, -1)
 
-    def step(state, t):
-        logits, state = model.serve_step(params, state, t)
-        return state, logits
+        def step(state, t):
+            logits, state = model.serve_step_paged(params, state, t)
+            return state, logits
+    else:
+        state = model.init_decode_state(batch=b, max_len=max_len)
+
+        def step(state, t):
+            logits, state = model.serve_step(params, state, t)
+            return state, logits
 
     state, logits = jax.lax.scan(step, state, toks)
     assert logits.shape == (20, b, cfg.vocab)
@@ -125,7 +137,8 @@ def test_param_counts_match_published_scale():
     """Sanity: full configs land near their published parameter counts."""
     expect = {"llama3.2-1b": (1.0e9, 2.1e9), "granite-34b": (30e9, 55e9),
               "chatglm3-6b": (5e9, 9e9), "jamba-1.5-large-398b": (350e9, 450e9),
-              "rwkv6-3b": (2.5e9, 4e9), "moonshot-v1-16b-a3b": (14e9, 30e9)}
+              "rwkv6-3b": (2.5e9, 4e9), "moonshot-v1-16b-a3b": (14e9, 30e9),
+              "deepseek-v3.2": (650e9, 700e9)}
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()
         assert lo <= n <= hi, (arch, n)
