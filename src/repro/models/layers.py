@@ -197,12 +197,30 @@ def decode_attention(q, kcache, vcache, length, *, scale: float,
     return out.reshape(b, h, d)
 
 
+def pool_read(pages, layer, *idx):
+    """Rows of a page pool: `pages[idx]` of one layer's pool (P, page_size,
+    ...) where `layer` is None, else `pages[layer, idx]` of a layer-stacked
+    pool (L, P, page_size, ...). The layer index joins the gather's own
+    indices, so the layer's pool is never sliced out of the stack."""
+    return pages[idx if layer is None else (layer,) + idx]
+
+
+def pool_pages(pages, layer) -> tuple:
+    """(P, page_size) of a page pool read as `pool_read` reads it."""
+    lead = 0 if layer is None else 1
+    return pages.shape[lead:lead + 2]
+
+
 def decode_attention_paged(q, k_pages, v_pages, table, length, *, scale: float,
-                           window: Optional[int] = None, rules=None):
+                           window: Optional[int] = None, rules=None,
+                           layer=None):
     """Fused paged form of `decode_attention` — the dense pre-DSA fallback
     without a caller-materialized logical view.
 
-    q: (B,H,D); k/v_pages: (P, page_size, KVH, D) global page pools;
+    q: (B,H,D); k/v_pages: (P, page_size, ...) global page pools whose
+    rows hold KVH heads of q's width D, as (KVH, D) or flat (KVH·D) — a
+    latent pool's row is one head; with `layer`, layer `layer` of
+    layer-stacked pools (L, P, page_size, ...) (`pool_read`).
     table: (B, MP) int32 block table (-1 = unmapped); length: (B,).
     The logical view is built from the block table here (unmapped entries
     clip to page 0 — their positions lie at or beyond `length`, so the
@@ -213,11 +231,12 @@ def decode_attention_paged(q, k_pages, v_pages, table, length, *, scale: float,
     `kernels.paged_dense_decode_attn`.
     """
     from repro.parallel.sharding import constrain
-    p, page_size = k_pages.shape[:2]
+    p, page_size = pool_pages(k_pages, layer)
     b, mp = table.shape
     gather = jnp.clip(table, 0, p - 1)
-    kc = k_pages[gather].reshape((b, mp * page_size) + k_pages.shape[2:])
-    vc = v_pages[gather].reshape((b, mp * page_size) + v_pages.shape[2:])
+    view = (b, mp * page_size, -1, q.shape[-1])
+    kc = pool_read(k_pages, layer, gather).reshape(view)
+    vc = pool_read(v_pages, layer, gather).reshape(view)
     kc = constrain(kc, rules, "batch", None, None, None)
     vc = constrain(vc, rules, "batch", None, None, None)
     return decode_attention(q, kc, vc, length, scale=scale, window=window,

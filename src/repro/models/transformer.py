@@ -37,7 +37,7 @@ from repro.sparse import dsa as dsa_mod
 from .config import ModelConfig
 from .layers import (_rope_freqs, apply_rotary, blockwise_causal_attention,
                      decode_attention, decode_attention_paged,
-                     held_experts_mlp, moe_mlp_ep, rms_norm,
+                     held_experts_mlp, moe_mlp_ep, pool_read, rms_norm,
                      route_grouped_sigmoid, swiglu_mlp, yarn_freqs,
                      yarn_mscale)
 
@@ -349,7 +349,8 @@ def _attend_decode(p, h, q, kc, vc, idx_kc, prev_topk, topk_valid, new_len,
     GVR's temporal prediction depends on). The attention gather has two
     physical forms: the dense layout (and the paged "gather" oracle) passes
     contiguous K/V views via `kc`/`vc`; the paged "fused" path passes
-    `paged=(k_pages, v_pages, page_table)` instead and attention pulls its
+    `paged=(k_pages, v_pages, page_table, layer)` instead — the
+    layer-stacked pools and the layer to read — and attention pulls its
     Top-K rows straight from the page pools (`dsa_decode_paged`) — same
     bits, O(K) instead of O(N) gathered KV traffic."""
     hd = cfg.hd
@@ -364,10 +365,11 @@ def _attend_decode(p, h, q, kc, vc, idx_kc, prev_topk, topk_valid, new_len,
             gate_max_n=cfg.dsa.gate_max_n, min_n=cfg.dsa.min_n,
             swa_window=cfg.swa_window, rules=rules, mesh=mesh)
         if paged is not None:
-            kp, vp, table = paged
+            kp, vp, table, layer = paged
             res = dsa_mod.dsa_decode_paged(
                 q, kp, vp, table, p["indexer"], h, idx_kc, prev_topk,
-                new_len, gather_granularity=gather_granularity, **dsa_kw)
+                new_len, gather_granularity=gather_granularity, layer=layer,
+                **dsa_kw)
         else:
             res = dsa_mod.dsa_decode(
                 q, kc, vc, p["indexer"], h, idx_kc, prev_topk, new_len,
@@ -384,10 +386,11 @@ def _attend_decode(p, h, q, kc, vc, idx_kc, prev_topk, topk_valid, new_len,
             # fused dense pre-DSA fallback: attend over the full logical
             # extent straight off the page pools (bit-identical to
             # gathering the view first — see layers.decode_attention_paged)
-            kp, vp, table = paged
+            kp, vp, table, layer = paged
             attn = decode_attention_paged(q, kp, vp, table, new_len,
                                           scale=hd ** -0.5,
-                                          window=cfg.swa_window, rules=rules)
+                                          window=cfg.swa_window, rules=rules,
+                                          layer=layer)
         else:
             attn = decode_attention(q, kc, vc, new_len, scale=hd ** -0.5,
                                     window=cfg.swa_window)
@@ -846,6 +849,13 @@ def init_paged_decode_state(cfg: ModelConfig, batch: int, max_len: int, *,
     multiple of `page_size` so the gathered logical view has exactly the
     dense layout's shape (bit-exactness depends on identical reduction
     extents, not just identical values).
+
+    Each pool is (L, num_pages + 1, page_size, row width), a GQA K/V row
+    flattened to one KVH·HD axis. The step updates a pool in place only
+    in the chip's default layout for its shape, and a row as one minor
+    axis keeps that layout row-major: with (KVH, HD) = (8, 64) minor the
+    chip lays the page axis out minor, and every step would relayout the
+    whole pool on entry and exit.
     """
     dtype = dtype or jnp.dtype(cfg.dtype)
     if max_len % page_size != 0:
@@ -863,10 +873,9 @@ def init_paged_decode_state(cfg: ModelConfig, batch: int, max_len: int, *,
             (l, num_pages + 1, page_size,
              cfg.kv_lora_rank + cfg.qk_rope_head_dim), dtype)
     else:
-        state["k_pages"] = jnp.zeros(
-            (l, num_pages + 1, page_size, cfg.n_kv_heads, hd), dtype)
-        state["v_pages"] = jnp.zeros(
-            (l, num_pages + 1, page_size, cfg.n_kv_heads, hd), dtype)
+        shape = (l, num_pages + 1, page_size, cfg.n_kv_heads * hd)
+        state["k_pages"] = jnp.zeros(shape, dtype)
+        state["v_pages"] = jnp.zeros(shape, dtype)
     if cfg.dsa.enabled:
         from repro.core.temporal import seed_slot_idx
         state["idx_k_pages"] = jnp.zeros(
@@ -910,6 +919,26 @@ def _paged_targets(state, pool: str, min_write_pos):
             table.shape[1] * page_size)
 
 
+# The two paged bodies carry the layer-stacked page pools (L, P + 1,
+# page_size, row) whole through their layer scans and touch them only by
+# one scatter of each slot's new row at [layer, dest, off] (`_pool_write`)
+# and by reads that fold the layer into their gather indices
+# (`layers.pool_read`, `_pool_view`). A layer slice `pool[layer]`, or a
+# pool in the scan's xs/ys, would copy the layer's pool on every step.
+
+def _pool_write(pool, layer, dest, off, row):
+    """Scatter the B new rows (B, ...) into layer `layer` of a stacked pool."""
+    row = row.reshape(row.shape[:1] + pool.shape[3:])
+    return pool.at[layer, dest, off].set(row.astype(pool.dtype))
+
+
+def _pool_view(pool, layer, gather, n):
+    """Layer `layer`'s logical view (B, n, ...) of a stacked pool, read
+    through the clipped block table `gather` (B, MP)."""
+    return pool_read(pool, layer, gather).reshape(
+        (gather.shape[0], n) + pool.shape[3:])
+
+
 def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
                      min_write_pos: Optional[jnp.ndarray] = None,
                      paged_attn: str = "fused",
@@ -950,6 +979,15 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
     out in fast memory — coarser descriptors, bit-identical output
     (sparse.dsa.dsa_sparse_attention_paged).
 
+    The layer-stacked pools (`k_pages`, `v_pages`, and `idx_k_pages` where
+    the DSA gate is open) ride the layer scan's carry whole, never its
+    xs/ys, and the step's outputs alias its donated inputs: each layer
+    scatters its new rows at [layer, dest, off] (`_pool_write`) and every
+    reader folds the layer into its gather (`_pool_view`, the `layer`
+    argument of the attention forms). Nothing in the step may take a layer
+    slice `pool[layer]` of a pool: the compiler would copy the layer's
+    pool, and its write-back into the stack, on every step.
+
     An MLA configuration runs its latent form (`_mla_serve_step_paged`).
     """
     if cfg.is_mla:
@@ -983,28 +1021,29 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
     # step never materializes the K/V logical views itself
     fused = paged_attn == "fused"
 
-    def layer(x, carry):
-        p = carry["p"]
-        kp, vp = carry["k_pages"], carry["v_pages"]
-        idx_kp = carry.get("idx_k_pages")
-        prev_topk = carry.get("prev_topk")
-        topk_valid = carry.get("topk_valid")
+    def layer(carry, xs):
+        x, pools = carry
+        p, li = xs["p"], xs["layer"]
+        kp, vp = pools["k_pages"], pools["v_pages"]
+        prev_topk = xs.get("prev_topk")
+        topk_valid = xs.get("topk_valid")
         with jax.named_scope("step.qkv"):
             h = rms_norm(x, p["ln1"])
             q, kn, vn = _project_qkv(p, h, b, positions, cfg, rules)
         with jax.named_scope("step.kv_write"):
-            kp = kp.at[dest, off].set(kn.astype(kp.dtype))
-            vp = vp.at[dest, off].set(vn.astype(vp.dtype))
+            kp = _pool_write(kp, li, dest, off, kn)
+            vp = _pool_write(vp, li, dest, off, vn)
         if fused:
             kc = vc = None            # K/V logical views intentionally unbuilt
         else:
             with jax.named_scope("step.attention"):
-                kc = kp[gather].reshape(b, n, cfg.n_kv_heads, hd)
-                vc = vp[gather].reshape(b, n, cfg.n_kv_heads, hd)
+                heads = (b, n, cfg.n_kv_heads, hd)
+                kc = _pool_view(kp, li, gather, n).reshape(heads)
+                vc = _pool_view(vp, li, gather, n).reshape(heads)
                 kc = constrain(kc, rules, "batch", None, None, None)
                 vc = constrain(vc, rules, "batch", None, None, None)
 
-        out = {"k_pages": kp, "v_pages": vp, "p": p}
+        pools = dict(pools, k_pages=kp, v_pages=vp)
         idx_kc = None
         if use_dsa:
             with jax.named_scope("step.indexer"):
@@ -1012,37 +1051,34 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
                                        dim=cfg.dsa.indexer_dim,
                                        rope_base=cfg.rope_base)
             with jax.named_scope("step.kv_write"):
-                idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
+                pools["idx_k_pages"] = _pool_write(
+                    pools["idx_k_pages"], li, dest, off, ik)
             with jax.named_scope("step.indexer"):
                 # the indexer scores all N tokens (paper Table 2:
                 # irreducible O(N·d_i)), so its logical view costs what
                 # scoring in page space would — and keeps scores/Top-K in
                 # logical order
-                idx_kc = idx_kp[gather].reshape(b, n, cfg.dsa.indexer_dim)
-        if idx_kp is not None:
-            out["idx_k_pages"] = idx_kp
-        attn, extras = _attend_decode(p, h, q, kc, vc, idx_kc, prev_topk,
-                                      topk_valid, new_len, cfg, use_dsa,
-                                      rules, mesh,
-                                      paged=(kp, vp, table) if fused else None,
-                                      gather_granularity=gather_granularity)
-        out.update(extras)
+                idx_kc = _pool_view(pools["idx_k_pages"], li, gather, n)
+        attn, out = _attend_decode(p, h, q, kc, vc, idx_kc, prev_topk,
+                                   topk_valid, new_len, cfg, use_dsa,
+                                   rules, mesh,
+                                   paged=(kp, vp, table, li) if fused else None,
+                                   gather_granularity=gather_granularity)
         x = _decode_out_mlp(p, x, attn, cfg, mesh, rules)
-        return x, out
+        return (x, pools), out
 
-    carry_in = {"p": params["layers"], "k_pages": state["k_pages"],
-                "v_pages": state["v_pages"]}
+    pools = {k: state[k] for k in ("k_pages", "v_pages")}
+    if use_dsa:
+        pools["idx_k_pages"] = state["idx_k_pages"]
+    xs = {"p": params["layers"], "layer": jnp.arange(cfg.n_layers)}
     if cfg.dsa.enabled:
-        carry_in["idx_k_pages"] = state["idx_k_pages"]
-        carry_in["prev_topk"] = state["prev_topk"]
+        xs["prev_topk"] = state["prev_topk"]
         if "topk_valid" in state:
-            carry_in["topk_valid"] = state["topk_valid"]
-    x, outs = jax.lax.scan(layer, x, carry_in)
+            xs["topk_valid"] = state["topk_valid"]
+    (x, pools), outs = jax.lax.scan(layer, (x, pools), xs)
 
-    new_state = dict(state)
-    new_state["k_pages"], new_state["v_pages"] = outs["k_pages"], outs["v_pages"]
+    new_state = dict(state, **pools)
     if cfg.dsa.enabled:
-        new_state["idx_k_pages"] = outs["idx_k_pages"]
         new_state["prev_topk"] = outs["prev_topk"]
         if "topk_valid" in state:
             new_state["topk_valid"] = outs["topk_valid"]
@@ -1270,8 +1306,13 @@ def _mla_serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
                           with_counts: bool):
     """`serve_step_paged` for an MLA configuration: the absorbed latent
     decode over one latent page pool (`kv_pages`, rows [c_kv | k_r]), the
-    dense then the expert layer stack each scanned over its own slice of
-    the layer-stacked state. With the DSA gate open the V3.2 indexer
+    dense then the expert layer stack each scanned over its own layers.
+    The stacked pools (`kv_pages`, and `idx_k_pages` where the gate is
+    open) pass whole through both scans' carries, the expert stack's at
+    layer offset `lo`, as in `serve_step_paged`: written by `_pool_write`,
+    read through the layer index, never sliced per stack or per layer nor
+    joined again. The small per-layer leaves (`prev_topk`, `topk_valid`)
+    are sliced per stack and joined. With the DSA gate open the V3.2 indexer
     selects through `dsa_select` and the selector, and attention gathers
     the K latent rows from the pool (`mla_sparse_attention_paged`);
     closed, it attends the whole logical extent through
@@ -1292,16 +1333,17 @@ def _mla_serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
     freqs = _mla_freqs(cfg)
     scale = _mla_scale(cfg)
 
-    def layer(x, carry, moe):
-        p, kvp = carry["p"], carry["kv_pages"]
-        idx_kp = carry.get("idx_k_pages")
-        prev_topk, topk_valid = carry.get("prev_topk"), carry.get("topk_valid")
+    def layer(carry, xs, moe):
+        x, pools = carry
+        p, li = xs["p"], xs["layer"]
+        prev_topk, topk_valid = xs.get("prev_topk"), xs.get("topk_valid")
         with jax.named_scope("step.qkv"):
             h = rms_norm(x, p["ln1"])
             q, row, cq = _mla_project(p, h, positions, cfg, freqs)
         with jax.named_scope("step.kv_write"):
-            kvp = kvp.at[dest, off].set(row.astype(kvp.dtype))
-        out = {"kv_pages": kvp}
+            kvp = _pool_write(pools["kv_pages"], li, dest, off, row)
+        pools = dict(pools, kv_pages=kvp)
+        out = {}
         if use_dsa:
             with jax.named_scope("step.indexer"):
                 ik = dsa_mod.indexer_k_latent(p, h, positions, dim=di,
@@ -1310,9 +1352,10 @@ def _mla_serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
                     p, cq, h, positions, heads=cfg.dsa.indexer_heads,
                     dim=di, freqs=freqs)
             with jax.named_scope("step.kv_write"):
-                idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
+                pools["idx_k_pages"] = _pool_write(
+                    pools["idx_k_pages"], li, dest, off, ik)
             with jax.named_scope("step.indexer"):
-                idx_kc = idx_kp[gather].reshape(b, n, di)
+                idx_kc = _pool_view(pools["idx_k_pages"], li, gather, n)
             sel = dsa_mod.dsa_select(
                 None, h, idx_kc, prev_topk, new_len, k=prev_topk.shape[-1],
                 heads=cfg.dsa.indexer_heads, dim=di, rope_base=cfg.rope_base,
@@ -1323,21 +1366,20 @@ def _mla_serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
             with jax.named_scope("step.attention"):
                 o_lat = dsa_mod.mla_sparse_attention_paged(
                     q, kvp, table, sel.indices, new_len, scale=scale,
-                    v_dim=kvl, granularity=gather_granularity, rules=rules)
+                    v_dim=kvl, granularity=gather_granularity, rules=rules,
+                    layer=li)
             out["prev_topk"] = sel.indices
             out["topk_valid"] = jnp.ones_like(topk_valid)
             out.update(sel_telemetry(sel, topk_valid))
         else:
             with jax.named_scope("step.attention"):
-                lat = kvp[:, :, None]
-                o_lat = decode_attention_paged(q, lat, lat, table, new_len,
-                                               scale=scale,
-                                               rules=rules)[..., :kvl]
+                # the latent pool reads as one KV head of q's width
+                o_lat = decode_attention_paged(q, kvp, kvp, table, new_len,
+                                               scale=scale, rules=rules,
+                                               layer=li)[..., :kvl]
             if prev_topk is not None:
                 out["prev_topk"], out["topk_valid"] = prev_topk, topk_valid
                 out.update(sel_telemetry(None, topk_valid))
-        if idx_kp is not None:
-            out["idx_k_pages"] = idx_kp
         with jax.named_scope("step.attention"):
             x = x + _mla_out(p, o_lat, cfg)
         with jax.named_scope("step.mlp"):
@@ -1345,21 +1387,22 @@ def _mla_serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
             x = x + m
         if held is not None:
             out["held_rows"] = held
-        return constrain(x, rules, "batch", "d_model"), out
+        return (constrain(x, rules, "batch", "d_model"), pools), out
 
-    layered = ["kv_pages"]
-    if cfg.dsa.enabled:
-        layered += ["idx_k_pages", "prev_topk", "topk_valid"]
+    pools = {"kv_pages": state["kv_pages"]}
+    if use_dsa:
+        pools["idx_k_pages"] = state["idx_k_pages"]
+    layered = ["prev_topk", "topk_valid"] if cfg.dsa.enabled else []
     outs = []
     for name, lo, cnt, moe in _mla_stacks(cfg):
-        carry_in = {"p": params[name]}
-        carry_in.update({k: state[k][lo:lo + cnt] for k in layered})
-        x, o = jax.lax.scan(partial(layer, moe=moe), x, carry_in)
+        xs = {"p": params[name], "layer": jnp.arange(lo, lo + cnt)}
+        xs.update({k: state[k][lo:lo + cnt] for k in layered})
+        (x, pools), o = jax.lax.scan(partial(layer, moe=moe), (x, pools), xs)
         outs.append(o)
     cat = {k: jnp.concatenate([o[k] for o in outs], axis=0)
            for k in outs[0] if k != "held_rows"}
 
-    new_state = dict(state)
+    new_state = dict(state, **pools)
     for k in layered + (["sel_gvr"] if cfg.dsa.enabled else []):
         new_state[k] = cat[k]
     new_state["length"] = new_len
@@ -1594,8 +1637,8 @@ def _paged_verify_mq(params, state, tokens, cfg: ModelConfig, *, draft_len,
         # all Q rows write before anything attends — safe because every
         # consumer masks beyond its own extent (see docstring)
         with jax.named_scope("step.kv_write"):
-            kp = kp.at[dest, off].set(kn.astype(kp.dtype))
-            vp = vp.at[dest, off].set(vn.astype(vp.dtype))
+            kp = kp.at[dest, off].set(kn.reshape(b, d1, -1).astype(kp.dtype))
+            vp = vp.at[dest, off].set(vn.reshape(b, d1, -1).astype(vp.dtype))
 
         out = {"k_pages": kp, "v_pages": vp}
         if use_dsa:

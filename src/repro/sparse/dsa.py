@@ -37,7 +37,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rotary, layer_norm
+from repro.models.layers import apply_rotary, layer_norm, pool_pages, pool_read
 from .selector import select_topk
 
 NEG = -3.4028235e38
@@ -218,8 +218,9 @@ def page_gather_stats(topk_idx: jnp.ndarray, *, page_size: int,
 
 def _gather_topk_rows_paged(pages: jnp.ndarray, table: jnp.ndarray,
                             li: jnp.ndarray, phys: jnp.ndarray,
-                            *, granularity: str) -> jnp.ndarray:
-    """Gather the K selected (feature...) rows from a page pool.
+                            *, granularity: str, layer=None) -> jnp.ndarray:
+    """Gather the K selected (feature...) rows from a page pool (with
+    `layer`, from that layer of a layer-stacked pool: `pool_read`).
 
     "token" moves exactly K rows (one DMA descriptor per Top-K entry);
     "page" moves each *distinct* page once as a whole (`page_size` rows per
@@ -229,10 +230,10 @@ def _gather_topk_rows_paged(pages: jnp.ndarray, table: jnp.ndarray,
     both forms, including invalid entries (unmapped pages clip to page 0
     either way), so downstream masking sees the same values bit for bit.
     """
-    p, page_size = pages.shape[:2]
+    p, page_size = pool_pages(pages, layer)
     if granularity == "token":
-        flat = jnp.clip(phys, 0, p - 1) * page_size + li % page_size
-        return pages.reshape((p * page_size,) + pages.shape[2:])[flat]
+        return pool_read(pages, layer, jnp.clip(phys, 0, p - 1),
+                         li % page_size)
     mp = table.shape[1]
     up = distinct_pages(li, page_size=page_size, num_logical_pages=mp)
     # sentinel slot mp reads a padded -1 column → clips to page 0, but no
@@ -240,7 +241,8 @@ def _gather_topk_rows_paged(pages: jnp.ndarray, table: jnp.ndarray,
     tpad = jnp.concatenate(
         [table, jnp.full((table.shape[0], 1), -1, table.dtype)], axis=1)
     uphys = jnp.take_along_axis(tpad, up, axis=1)                  # (B, S)
-    page_buf = pages[jnp.clip(uphys, 0, p - 1)]        # (B, S, page_size, ...)
+    page_buf = pool_read(pages, layer,
+                         jnp.clip(uphys, 0, p - 1))   # (B, S, page_size, ...)
     slot = jax.vmap(jnp.searchsorted)(up, li // page_size)         # (B, K)
     bi = jnp.arange(li.shape[0])[:, None]
     return page_buf[bi, slot, li % page_size]
@@ -250,11 +252,13 @@ def dsa_sparse_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                                v_pages: jnp.ndarray, table: jnp.ndarray,
                                topk_idx: jnp.ndarray, lengths: jnp.ndarray,
                                *, scale: float, granularity: str = "token",
-                               rules=None) -> jnp.ndarray:
+                               rules=None, layer=None) -> jnp.ndarray:
     """Block-table-native sparse attention (XLA gather form of the fused
     Pallas kernel `kernels.paged_sparse_decode_attn`).
 
-    q: (B,H,HD); k/v_pages: (P, page_size, KVH, HD) global page pools;
+    q: (B,H,HD); k/v_pages: (P, page_size, KVH, HD) global page pools, or
+    (P, page_size, KVH·HD) with each row flattened (with `layer`,
+    layer-stacked pools (L, P, page_size, ...) read at that layer);
     table: (B, MP) int32 block table (-1 = unmapped); topk_idx: (B,K)
     LOGICAL indices. The logical→physical translation is composed with the
     Top-K gather, so exactly K (KVH × HD) rows move per query — O(K)
@@ -277,8 +281,7 @@ def dsa_sparse_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
         raise ValueError(f"granularity must be 'token' or 'page', "
                          f"got {granularity!r}")
     b, h, hd = q.shape
-    p, page_size, kvh = k_pages.shape[:3]
-    g = h // kvh
+    page_size = pool_pages(k_pages, layer)[1]
     n = table.shape[1] * page_size
     from repro.parallel.sharding import constrain
     # same partitioning discipline as the logical-view path: q pinned
@@ -289,10 +292,15 @@ def dsa_sparse_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     phys = jnp.take_along_axis(table, li // page_size, axis=1)     # (B, K)
     valid = ((topk_idx >= 0) & (topk_idx < lengths[:, None])
              & (phys >= 0))
+    rows = li.shape + (-1, hd)                         # (B, K, KVH, HD)
     kg = _gather_topk_rows_paged(k_pages, table, li, phys,
-                                 granularity=granularity)
+                                 granularity=granularity,
+                                 layer=layer).reshape(rows)
     vg = _gather_topk_rows_paged(v_pages, table, li, phys,
-                                 granularity=granularity)
+                                 granularity=granularity,
+                                 layer=layer).reshape(rows)
+    kvh = kg.shape[2]
+    g = h // kvh
     # resharding (for TP heads) happens on the small (B,K) gathered rows,
     # never on the page pool — mirrors dsa_sparse_attention
     kg = constrain(kg, rules, "batch", None, None, None)
@@ -310,18 +318,19 @@ def mla_sparse_attention_paged(q: jnp.ndarray, kv_pages: jnp.ndarray,
                                table: jnp.ndarray, topk_idx: jnp.ndarray,
                                lengths: jnp.ndarray, *, scale: float,
                                v_dim: int, granularity: str = "token",
-                               rules=None) -> jnp.ndarray:
+                               rules=None, layer=None) -> jnp.ndarray:
     """Absorbed latent attention over the Top-K rows of one latent pool —
     `dsa_sparse_attention_paged` with a single KV "head" whose keys are the
     whole cached row [c_kv | k_rope] and whose values are its first `v_dim`
     features (the latent c_kv).
 
     q: (B, H, C) absorbed queries [q_nope W_uk | q_rope]; kv_pages:
-    (P, page_size, C); table: (B, MP); topk_idx: (B, K) LOGICAL indices.
+    (P, page_size, C) (with `layer`, a layer-stacked (L, P, page_size, C)
+    read at that layer); table: (B, MP); topk_idx: (B, K) LOGICAL indices.
     The K rows are gathered once (`_gather_topk_rows_paged`) and serve as
     keys and values both. Returns (B, H, v_dim) f32: sum_s p_s c_kv(s)."""
     from repro.parallel.sharding import constrain
-    p, page_size = kv_pages.shape[:2]
+    page_size = pool_pages(kv_pages, layer)[1]
     n = table.shape[1] * page_size
     q = constrain(q, rules, "batch", None, None)
     li = jnp.clip(topk_idx, 0, n - 1)
@@ -329,7 +338,8 @@ def mla_sparse_attention_paged(q: jnp.ndarray, kv_pages: jnp.ndarray,
     valid = ((topk_idx >= 0) & (topk_idx < lengths[:, None])
              & (phys >= 0))
     rows = _gather_topk_rows_paged(kv_pages, table, li, phys,
-                                   granularity=granularity)        # (B, K, C)
+                                   granularity=granularity,
+                                   layer=layer)                    # (B, K, C)
     rows = constrain(rows, rules, "batch", None, None)
     logits = jnp.einsum("bhc,bkc->bhk", q.astype(rows.dtype), rows,
                         preferred_element_type=jnp.float32) * scale
@@ -446,7 +456,7 @@ def dsa_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                      min_n: int = 4096,
                      swa_window: Optional[int] = None,
                      gather_granularity: str = "token", rules=None,
-                     mesh=None) -> DSAOutput:
+                     mesh=None, layer=None) -> DSAOutput:
     """Block-table-native DSA decode step: identical scoring/selection to
     `dsa_decode` (bit-exact — `idx_kcache` is the logical indexer-K view,
     the paper's irreducible O(N·d_i) read), but attention gathers its K
@@ -454,7 +464,8 @@ def dsa_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     built; feedback indices stay logical, so GVR's temporal warm start is
     untouched by the physical layout. `gather_granularity` selects token-
     vs page-granular DMA for the attention gather (bit-identical either
-    way — see `dsa_sparse_attention_paged`).
+    way — see `dsa_sparse_attention_paged`). With `layer` the pools are
+    layer-stacked and read at that layer.
     """
     sel = dsa_select(indexer_params, x, idx_kcache, prev_topk, lengths,
                      k=k, heads=heads, dim=dim, rope_base=rope_base,
@@ -466,6 +477,6 @@ def dsa_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
         out = dsa_sparse_attention_paged(q, k_pages, v_pages, table,
                                          sel.indices, lengths, scale=scale,
                                          granularity=gather_granularity,
-                                         rules=rules)
+                                         rules=rules, layer=layer)
     return DSAOutput(out, sel.indices, sel.secant_iters, sel.gvr_rows,
                      sel.fallback, sel.radix_rows)

@@ -7,7 +7,12 @@ heads × 128, 32/8 attention heads × 64 (and the block-table kernels
 again at 128K context). Mosaic refuses here exactly what
 it would refuse on the chip (tiling, unsupported ops, VMEM/SMEM budgets),
 and the whole-step compile reports the device memory the step needs.
+At small widths, the paged step is compiled once more to read how its
+page pools move through the layer scan.
 """
+
+import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -152,3 +157,66 @@ def test_paged_decode_step_fits_one_v5e(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, total
+
+
+def _pool_guard_config(case):
+    """Small configurations of the three served step bodies, each with
+    page rows of whole 128-lane rows, as in every served configuration:
+    heads of 128 as in chatglm3, of 64 as in granite, and DeepSeek's
+    latent rows."""
+    def smoke(name, dsa=None, **kw):
+        cfg = get_config(name, smoke=True)
+        dsa = dataclasses.replace(cfg.dsa, indexer_dim=128, **(dsa or {}))
+        return dataclasses.replace(cfg, dtype="bfloat16", dsa=dsa, **kw)
+    if case == "gqa_dsa":            # gate open: indexer, Top-K, sparse
+        return smoke("chatglm3-6b", head_dim=128)
+    if case == "gqa_closed":         # granite-like: dense fallback, experts
+        return smoke("granite-moe-1b-a400m", dsa={"min_n": 4096},
+                     head_dim=64)
+    return smoke("deepseek-v3.2", kv_lora_rank=124)     # [c_kv | k_r] = 128
+
+
+@pytest.mark.parametrize("case", ["gqa_dsa", "gqa_closed", "mla"])
+def test_paged_step_moves_no_pool(one_chip, case):
+    """The layer-stacked page pools stay whole and in place through the
+    step's layer scan: with the state donated, the step's temporaries are
+    smaller than one layer's pools, and no copy, pad, concatenation or
+    (dynamic) slice in the optimized program has the shape of a whole
+    stacked pool or of one layer's pool. The pools hold four times the
+    pages the slots map (room a prefix cache would use), so that one
+    layer's pools outweigh the logical views the step builds of them."""
+    cfg = _pool_guard_config(case)
+    model = build_model(cfg)
+    slots, max_len = 4, 1024
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init_params,
+                                    jax.random.PRNGKey(0)))
+    state = on_chip(jax.eval_shape(lambda: model.init_paged_decode_state(
+        slots, max_len, num_pages=4 * slots * max_len // PAGE,
+        page_size=PAGE)))
+    pools = {k: v for k, v in state.items() if k.endswith("_pages")}
+    layer_bytes = sum(v.size * v.dtype.itemsize // v.shape[0]
+                      for v in pools.values())
+    tokens = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, s, t, m: model.serve_step_paged(
+        p, s, t, min_write_pos=m, with_counts=True), donate_argnums=(1,))
+    compiled = step.lower(params, state, tokens, tokens).compile()
+
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_bytes, (temp, layer_bytes)
+    shapes = set()
+    for v in pools.values():
+        shapes |= {v.shape, v.shape[1:], (1,) + v.shape[1:]}
+    moved = re.compile(r"copy|pad|concatenate|slice")
+    found = []
+    for m in re.finditer(r"%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+                         compiled.as_text()):
+        name, dims, op = m.groups()
+        shape = tuple(int(d) for d in dims.split(",") if d)
+        if shape in shapes and (moved.search(op) or moved.search(name)):
+            found.append(f"{op} {name} {shape}")
+    assert not found, found
