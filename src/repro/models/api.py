@@ -101,7 +101,8 @@ class Model:
     def serve_step_paged(self, params, state, tokens, *, min_write_pos=None,
                          paged_attn="fused", gather_granularity="token",
                          mesh=None, rules=None, with_counts=False):
-        """One paged decode step. `paged_attn` selects the sparse-attention
+        """One paged decode step: the Q = 1 call of the paged forward over
+        Q query rows a slot. `paged_attn` selects the sparse-attention
         form: "fused" (block-table-native, O(K) gathered KV traffic —
         default) or "gather" (materialize the logical view first; the PR-2
         oracle). `gather_granularity` ("token" | "page") picks the DMA
@@ -126,9 +127,11 @@ class Model:
         """Speculative verify tick (serve.spec subsystem): score all d+1
         draft positions, greedy-accept the longest matching prefix, and
         roll the decode state back to the accepted point in-graph.
-        `verify_kernel` picks the verify body: "scan" (d+1 sequential
-        paged steps in one jitted scan) or "mq" (one multi-query-row
-        forward; bit-identical) — see transformer.serve_step_spec_paged."""
+        `verify_kernel` picks the verify body: "mq" (the paged forward's
+        Q = d+1 call, the forward `serve_step_paged` runs with Q = 1) or
+        "scan" (d+1 sequential paged steps in one jitted scan, the
+        reference mq is pinned against; bit-identical) — see
+        transformer.serve_step_spec_paged."""
         fn = getattr(self.mod, "serve_step_spec_paged", None)
         if fn is None:
             raise NotImplementedError(
@@ -168,7 +171,8 @@ class Model:
                             min_write_pos=None, rules=None,
                             with_counts=False):
         """One sequence-sharded paged decode step (shard_map over the
-        mesh's "seq" axis; SP-GVR selection + O(K)-psum paged attention).
+        mesh's "seq" axis; SP-GVR selection + O(K)-psum paged attention):
+        the Q = 1 call of the sharded forward over Q query rows.
         Bit-identical to `serve_step_paged(paged_attn="fused")` — see
         transformer.serve_step_sp_paged. `with_counts` adds the rows' GVR
         counts as a third result."""
@@ -186,9 +190,9 @@ class Model:
                                  min_write_pos=None, verify_kernel="scan",
                                  rules=None):
         """Sequence-sharded speculative verify tick (one shard_map over
-        the d+1 draft positions; `verify_kernel` picks the scan or the
-        batched mq body, bit-identical) — see
-        transformer.serve_step_sp_spec_paged."""
+        the d+1 draft positions; `verify_kernel` picks "mq", the sharded
+        forward's Q = d+1 call, or "scan", its reference of d+1 Q = 1
+        calls; bit-identical) — see transformer.serve_step_sp_spec_paged."""
         fn = getattr(self.mod, "serve_step_sp_spec_paged", None)
         if fn is None:
             raise NotImplementedError(

@@ -12,7 +12,11 @@ all_to_all (layers.moe_mlp_ep) when a mesh is provided.
 
 serve_step carries functional decode state (KV caches, DSA indexer cache,
 prev-Top-K feedback, lengths) and runs the paper's DSA pipeline per layer
-when enabled.
+when enabled. The paged layouts decode through one forward over Q query
+rows a slot per placement — `_paged_forward` on one device,
+`_sp_paged_forward` per shard of a sequence mesh: Q = 1 is a decode step,
+Q = d+1 a speculative verify tick. Latent attention (MLA) has its own
+paged body, `_mla_serve_step_paged`.
 
 Every decode body names its stages with `jax.named_scope`: `step.qkv`,
 `step.kv_write`, `step.indexer`, `step.topk`, `step.attention`,
@@ -338,62 +342,35 @@ def _project_qkv(p, h, b, positions, cfg: ModelConfig, rules):
 
 
 def _attend_decode(p, h, q, kc, vc, idx_kc, prev_topk, topk_valid, new_len,
-                   cfg: ModelConfig, use_dsa: bool, rules, mesh, paged=None,
-                   gather_granularity: str = "token"):
-    """Shared decode-attention core.
+                   cfg: ModelConfig, use_dsa: bool, rules, mesh):
+    """The dense layout's decode-attention core (`serve_step`).
 
-    Scoring/selection always run over a *logical* contiguous indexer view:
-    everything downstream of this point — indexer scores, Top-K selection,
-    the prev-Top-K feedback and the sel_gvr telemetry — lives in logical
-    token space and never sees a physical page id (the layout invariant
-    GVR's temporal prediction depends on). The attention gather has two
-    physical forms: the dense layout (and the paged "gather" oracle) passes
-    contiguous K/V views via `kc`/`vc`; the paged "fused" path passes
-    `paged=(k_pages, v_pages, page_table, layer)` instead — the
-    layer-stacked pools and the layer to read — and attention pulls its
-    Top-K rows straight from the page pools (`dsa_decode_paged`) — same
-    bits, O(K) instead of O(N) gathered KV traffic."""
+    Scoring/selection run over the *logical* contiguous indexer view:
+    indexer scores, Top-K selection, the prev-Top-K feedback and the
+    sel_gvr telemetry live in logical token space and never see a physical
+    page id (the layout invariant GVR's temporal prediction depends on);
+    attention reads the contiguous K/V views `kc`/`vc`. The paged forms
+    are `_paged_forward` and `_sp_paged_forward`."""
     hd = cfg.hd
     out = {}
     if use_dsa:
-        dsa_kw = dict(
+        res = dsa_mod.dsa_decode(
+            q, kc, vc, p["indexer"], h, idx_kc, prev_topk, new_len,
             k=prev_topk.shape[-1], scale=hd ** -0.5,
             heads=cfg.dsa.indexer_heads, dim=cfg.dsa.indexer_dim,
             rope_base=cfg.rope_base, selector=cfg.dsa.selector,
-            prev_valid=topk_valid,
-            max_candidates=cfg.dsa.max_candidates,
+            prev_valid=topk_valid, max_candidates=cfg.dsa.max_candidates,
             gate_max_n=cfg.dsa.gate_max_n, min_n=cfg.dsa.min_n,
             swa_window=cfg.swa_window, rules=rules, mesh=mesh)
-        if paged is not None:
-            kp, vp, table, layer = paged
-            res = dsa_mod.dsa_decode_paged(
-                q, kp, vp, table, p["indexer"], h, idx_kc, prev_topk,
-                new_len, gather_granularity=gather_granularity, layer=layer,
-                **dsa_kw)
-        else:
-            res = dsa_mod.dsa_decode(
-                q, kc, vc, p["indexer"], h, idx_kc, prev_topk, new_len,
-                **dsa_kw)
-        attn = res.attn_out
         out["prev_topk"] = res.topk_idx
         if topk_valid is not None:
             # a DSA step just wrote genuine feedback → rows become warm
             out["topk_valid"] = jnp.ones_like(topk_valid)
             out.update(sel_telemetry(res, topk_valid))
-        return attn, out
+        return res.attn_out, out
     with jax.named_scope("step.attention"):
-        if paged is not None:
-            # fused dense pre-DSA fallback: attend over the full logical
-            # extent straight off the page pools (bit-identical to
-            # gathering the view first — see layers.decode_attention_paged)
-            kp, vp, table, layer = paged
-            attn = decode_attention_paged(q, kp, vp, table, new_len,
-                                          scale=hd ** -0.5,
-                                          window=cfg.swa_window, rules=rules,
-                                          layer=layer)
-        else:
-            attn = decode_attention(q, kc, vc, new_len, scale=hd ** -0.5,
-                                    window=cfg.swa_window)
+        attn = decode_attention(q, kc, vc, new_len, scale=hd ** -0.5,
+                                window=cfg.swa_window)
     if prev_topk is not None:
         out["prev_topk"] = prev_topk
         if topk_valid is not None:
@@ -402,16 +379,84 @@ def _attend_decode(p, h, q, kc, vc, idx_kc, prev_topk, topk_valid, new_len,
     return attn, out
 
 
-def _decode_out_mlp(p, x, attn, cfg: ModelConfig, mesh, rules):
-    """The rest of one decode layer: the attention output projection and
-    the residual MLP/MoE block. attn: (B, H, HD); x: (B, D)."""
+# A paged forward runs Q query rows a slot: row j of slot b sits at
+# position length[b] + j, and the B·Q rows are laid out slot-major, (B·Q,
+# ...). Q = 1 is a decode step; every helper below then returns its input
+# or calls its function once, so the one-row program has no row axis, no
+# repeat and no loop of trip count 1.
+
+def _by_row(a, q: int):
+    """Slot-major rows (B·Q, ...) → (Q, B, ...), one entry a query row."""
+    return jnp.swapaxes(a.reshape((-1, q) + a.shape[1:]), 0, 1)
+
+
+def _flat_rows(a, q: int):
+    """(Q, B, ...) → slot-major rows (B·Q, ...); Q = 1: `a` is (B, ...)."""
+    if q == 1:
+        return a
+    return jnp.swapaxes(a, 0, 1).reshape((-1,) + a.shape[2:])
+
+
+def _per_row(a, q: int):
+    """A slot's array (B, ...) repeated for each of its Q rows (B·Q, ...)."""
+    return a if q == 1 else jnp.repeat(a, q, axis=0)
+
+
+def _row_stack(a, q: int):
+    """The same (B, ...) value at each of the Q rows: (Q, B, ...)."""
+    return a if q == 1 else jnp.broadcast_to(a[None], (q,) + a.shape)
+
+
+def _row_positions(length, q: int):
+    """The positions (B·Q,) of the rows: length[b] + j, slot-major."""
+    if q == 1:
+        return length
+    return (length[:, None] + jnp.arange(q, dtype=length.dtype)).reshape(-1)
+
+
+def _rows_chain(fn, carry, rows, q: int):
+    """`fn(carry, row) -> (carry, out)` over the Q rows in order, each
+    row's carry feeding the next (the Top-K warm start: row j+1 warms
+    from row j). rows: slot-major (B·Q, ...) arrays. Returns the outs,
+    (Q, B, ...) each; for Q = 1 fn's one call, (B, ...)."""
+    if q == 1:
+        return fn(carry, rows)[1]
+    return jax.lax.scan(fn, carry,
+                        jax.tree.map(lambda a: _by_row(a, q), rows))[1]
+
+
+def _project_rows(p, x, positions, cfg: ModelConfig, rules, indexer: bool):
+    """A paged layer's projections of its rows x (T, D) at `positions`
+    (T,): the normed input h, the queries (T, H, HD), the new K and V rows
+    (T, KVH, HD) and, with `indexer`, the new indexer keys (T, d_i)."""
+    with jax.named_scope("step.qkv"):
+        h = rms_norm(x, p["ln1"])
+        q, kn, vn = _project_qkv(p, h, x.shape[0], positions, cfg, rules)
+    ik = None
+    if indexer:
+        with jax.named_scope("step.indexer"):
+            ik = dsa_mod.indexer_k(p["indexer"], h, positions,
+                                   dim=cfg.dsa.indexer_dim,
+                                   rope_base=cfg.rope_base)
+    return h, q, kn, vn, ik
+
+
+def _decode_out_mlp(p, x, attn, cfg: ModelConfig, mesh, rules, q: int = 1):
+    """The rest of one decode layer over its rows: the attention output
+    projection and the residual MLP/MoE block. attn: (T, H, HD); x: (T, D),
+    slot-major rows of Q a slot. The expert block runs one (B, 1, D) call
+    a row position, the call a one-row step makes, so that routing (and
+    an expert-parallel capacity) sees the same token batch."""
     with jax.named_scope("step.attention"):
         attn = attn.reshape(x.shape[0], cfg.n_heads * cfg.hd).astype(x.dtype)
         x = x + attn @ p["wo"]
     with jax.named_scope("step.mlp"):
         h = rms_norm(x, p["ln2"])
         if cfg.moe.num_experts:
-            m = _mlp(p, h[:, None, :], cfg, mesh)[:, 0]
+            def moe(hh):
+                return _mlp(p, hh[:, None, :], cfg, mesh)[:, 0]
+            m = (moe(h) if q == 1
+                 else _flat_rows(jax.lax.map(moe, _by_row(h, q)), q))
         else:
             m = _mlp(p, h, cfg, mesh)
         x = x + m
@@ -661,105 +706,100 @@ def _sp_paged_validate(state, cfg: ModelConfig, mesh, seq_axis: str) -> None:
             f"{seq_axis!r} has {mesh.shape[seq_axis]} devices")
 
 
-def _sp_paged_token_body(params, state, tokens, mwp, cfg: ModelConfig, *,
-                         seq_axis: str):
-    """Per-device body of ONE sequence-sharded paged decode step — executes
-    inside a shard_map over `seq_axis` (state's page-pool leaves arrive as
-    this device's shard slice, everything else replicated). Factored to
-    module level so the speculative verify step can scan it over the d+1
-    draft positions of a verify tick within a single shard_map
-    (`serve_step_sp_spec_paged`); `serve_step_sp_paged` wraps exactly one
-    invocation. Returns (logits, new_state, counts) with the shard axis
-    restored on the pool leaves; counts: `sel_counts` (B, 4)."""
+_SP_POOLS = ("k_pages", "v_pages", "idx_k_pages")
+
+
+def _sp_specs(params, state, seq_axis: str):
+    """shard_map specs of the sharded step's parameters (replicated) and
+    state (the pools split over `seq_axis`, the rest replicated)."""
+    st = {key: P(None, seq_axis) if key in _SP_POOLS else P()
+          for key in state}
+    return jax.tree.map(lambda _: P(), params), st
+
+
+def _sp_paged_forward(params, state, tokens, min_write_pos, cfg: ModelConfig,
+                      *, q: int, seq_axis: str):
+    """The sequence-sharded paged forward over Q query rows a slot, per
+    device, inside a shard_map over `seq_axis`: the pools arrive as this
+    device's shard (L, 1, PL + 1, page_size, ...), everything else
+    replicated. tokens, min_write_pos: (B·Q,) slot-major.
+
+    The single-device forward (`_paged_forward`) with two differences: a
+    row writes where this shard owns its position (else to the shard's
+    sink page), and each row's selection and attention run in
+    `sp_dsa_decode_paged_local` (SP-GVR's O(1)-collective Top-K, the
+    selected rows assembled with one O(K) psum), row j+1 warm-starting
+    from row j. The pools ride the layer scan's carry as one shard's
+    stacks (L, PL + 1, page_size, ...). Returns what `_paged_forward`
+    returns, the pools' shard axis restored."""
     from repro.sparse import sp_dsa as sp_dsa_mod
 
-    b = tokens.shape[0]
+    b = state["length"].shape[0]
     hd = cfg.hd
-    ppl = state["k_pages"].shape[2] - 1                  # pages per shard
+    sink = state["k_pages"].shape[2] - 1                # local sink page
     page_size = state["k_pages"].shape[3]
-    mp = state["page_table"].shape[1]
-    num_shards = jax.lax.axis_size(seq_axis)
-    mp_local = mp // num_shards
+    mp_local = state["page_table"].shape[1] // jax.lax.axis_size(seq_axis)
     n_local = mp_local * page_size
-    kk = state["prev_topk"].shape[-1]
 
     my = jax.lax.axis_index(seq_axis)
     shard_offset = (my * n_local).astype(jnp.int32)
-    table = state["page_table"]                      # (B, MP) replicated
     table_local = jax.lax.dynamic_slice_in_dim(
-        table, my * mp_local, mp_local, axis=1)      # shard-local slice
-    positions = state["length"]
-    new_len = state["length"] + 1
-    sink = ppl                                       # local sink page id
-
-    # this shard writes iff it owns the write position
+        state["page_table"], my * mp_local, mp_local, axis=1)
+    positions = _row_positions(state["length"], q)
+    lengths = positions + 1
+    # this shard writes a row iff it owns the row's position
     owner = (positions >= shard_offset) & (positions < shard_offset + n_local)
     rel = jnp.clip(positions - shard_offset, 0, n_local - 1)
-    phys = jnp.take_along_axis(table_local,
-                               (rel // page_size)[:, None], axis=1)[:, 0]
-    writable = owner & (phys >= 0) & (positions >= mwp)
+    phys = jnp.take_along_axis(table_local, (rel // page_size).reshape(b, q),
+                               axis=1).reshape(-1)
+    writable = owner & (phys >= 0) & (positions >= min_write_pos)
     dest = jnp.where(writable, phys, sink)
     off = positions % page_size                      # page-aligned spans
     gather_local = jnp.clip(table_local, 0, sink)
 
-    x = params["embed"][tokens]
-
-    def layer(x, carry):
-        p = carry["p"]
-        kp, vp = carry["k_pages"], carry["v_pages"]
-        idx_kp = carry["idx_k_pages"]
-        prev_topk = carry["prev_topk"]
-        topk_valid = carry.get("topk_valid")
-        with jax.named_scope("step.qkv"):
-            h = rms_norm(x, p["ln1"])
-            q, kn, vn = _project_qkv(p, h, b, positions, cfg, None)
-        with jax.named_scope("step.indexer"):
-            ik = dsa_mod.indexer_k(p["indexer"], h, positions,
-                                   dim=cfg.dsa.indexer_dim,
-                                   rope_base=cfg.rope_base)
+    def layer(carry, xs):
+        x, pools = carry
+        p, li = xs["p"], xs["layer"]
+        h, qh, kn, vn, ik = _project_rows(p, x, positions, cfg, None, True)
         with jax.named_scope("step.kv_write"):
-            kp = kp.at[dest, off].set(kn.astype(kp.dtype))
-            vp = vp.at[dest, off].set(vn.astype(vp.dtype))
-            idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
+            pools = {key: _pool_write(pools[key], li, dest, off, row)
+                     for key, row in (("k_pages", kn), ("v_pages", vn),
+                                      ("idx_k_pages", ik))}
         with jax.named_scope("step.indexer"):
             # shard-local logical indexer view: N/S × d_i per device — the
-            # irreducible indexer read, now split across the mesh
-            idx_kc = idx_kp[gather_local].reshape(b, n_local,
-                                                  cfg.dsa.indexer_dim)
-        res = sp_dsa_mod.sp_dsa_decode_paged_local(
-            q, kp, vp, table_local, p["indexer"], h, idx_kc,
-            prev_topk, topk_valid, new_len,
-            k=kk, scale=hd ** -0.5, heads=cfg.dsa.indexer_heads,
-            dim=cfg.dsa.indexer_dim, rope_base=cfg.rope_base,
-            shard_offset=shard_offset, page_size=page_size,
-            max_candidates=cfg.dsa.max_candidates,
-            swa_window=cfg.swa_window, seq_axis=seq_axis)
-        out = {"k_pages": kp, "v_pages": vp, "idx_k_pages": idx_kp,
-               "p": p, "prev_topk": res.new_topk}
-        if topk_valid is not None:
-            out["topk_valid"] = jnp.ones_like(topk_valid)
-            out.update(sel_telemetry(res, topk_valid))
-        x = _decode_out_mlp(p, x, res.attn_out, cfg, None, None)
-        return x, out
+            # irreducible indexer read, split across the mesh
+            idx_kc = _pool_view(pools["idx_k_pages"], li, gather_local,
+                                n_local)
 
-    carry_in = {"p": params["layers"],
-                "k_pages": state["k_pages"][:, 0],
-                "v_pages": state["v_pages"][:, 0],
-                "idx_k_pages": state["idx_k_pages"][:, 0],
-                "prev_topk": state["prev_topk"]}
-    if "topk_valid" in state:
-        carry_in["topk_valid"] = state["topk_valid"]
-    x, outs = jax.lax.scan(layer, x, carry_in)
+        def select_attend(carry, row):
+            prev, valid = carry
+            q_j, h_j, len_j = row
+            res = sp_dsa_mod.sp_dsa_decode_paged_local(
+                q_j, pools["k_pages"], pools["v_pages"], table_local,
+                p["indexer"], h_j, idx_kc, prev, valid, len_j,
+                k=prev.shape[-1], scale=hd ** -0.5,
+                heads=cfg.dsa.indexer_heads, dim=cfg.dsa.indexer_dim,
+                rope_base=cfg.rope_base, shard_offset=shard_offset,
+                page_size=page_size, max_candidates=cfg.dsa.max_candidates,
+                swa_window=cfg.swa_window, seq_axis=seq_axis, layer=li)
+            warm = jnp.ones_like(valid)
+            return (res.new_topk, warm), dict(
+                attn=res.attn_out, prev_topk=res.new_topk, topk_valid=warm,
+                **sel_telemetry(res, valid))
 
-    new_state = dict(state)
-    for key in ("k_pages", "v_pages", "idx_k_pages"):
-        new_state[key] = outs[key][:, None]          # restore shard axis
-    new_state["prev_topk"] = outs["prev_topk"]
-    if "topk_valid" in state:
-        new_state["topk_valid"] = outs["topk_valid"]
-        new_state["sel_gvr"] = outs["sel_gvr"]
-    new_state["length"] = new_len
-    return _lm_head(params, x, cfg), new_state, sel_counts(outs, b)
+        out = _rows_chain(select_attend, (xs["prev_topk"], xs["topk_valid"]),
+                          (qh, h, lengths), q)
+        attn = _flat_rows(out.pop("attn"), q)
+        x = _decode_out_mlp(p, x, attn, cfg, None, None, q)
+        return (x, pools), out
+
+    pools = {key: state[key][:, 0] for key in _SP_POOLS}
+    xs = {"p": params["layers"], "layer": jnp.arange(cfg.n_layers),
+          "prev_topk": state["prev_topk"], "topk_valid": state["topk_valid"]}
+    (x, pools), outs = jax.lax.scan(layer, (params["embed"][tokens], pools),
+                                    xs)
+    new_state = dict(state, **{key: v[:, None] for key, v in pools.items()})
+    return _lm_head(params, x, cfg), new_state, outs
 
 
 def serve_step_sp_paged(params, state, tokens, cfg: ModelConfig, *, mesh,
@@ -768,8 +808,9 @@ def serve_step_sp_paged(params, state, tokens, cfg: ModelConfig, *, mesh,
                         rules: Optional[MeshRules] = None,
                         with_counts: bool = False):
     """One sequence-sharded paged decode step (inside a shard_map over the
-    mesh's `seq_axis`). tokens: (B,) int32. Returns (logits, state), and
-    with `with_counts` the rows' GVR counts (B, 4) third (`sel_counts`).
+    mesh's `seq_axis`): the Q = 1 call of `_sp_paged_forward`. tokens:
+    (B,) int32. Returns (logits, state), and with `with_counts` the rows'
+    GVR counts (B, 4) third (`sel_counts`).
 
     Per shard and per layer: the shard owning logical position `length`
     scatters the new token's K/V/indexer-K rows into ITS page pool (every
@@ -794,14 +835,11 @@ def serve_step_sp_paged(params, state, tokens, cfg: ModelConfig, *, mesh,
            else jnp.zeros((b,), jnp.int32))
 
     def body(params, state, tokens, mwp):
-        out = _sp_paged_token_body(params, state, tokens, mwp, cfg,
-                                   seq_axis=seq_axis)
-        return out if with_counts else out[:2]
+        logits, new_state, outs = _sp_paged_forward(
+            params, state, tokens, mwp, cfg, q=1, seq_axis=seq_axis)
+        return _decode_result(logits, new_state, outs, with_counts)
 
-    pool_spec = P(None, seq_axis)
-    st_spec = {key: (pool_spec if key in ("k_pages", "v_pages", "idx_k_pages")
-                     else P()) for key in state}
-    param_spec = jax.tree.map(lambda _: P(), params)
+    param_spec, st_spec = _sp_specs(params, state, seq_axis)
     fn = jax.shard_map(body, mesh=mesh,
                    in_specs=(param_spec, st_spec, P(), P()),
                    out_specs=(P(), st_spec) + ((P(),) if with_counts else ()),
@@ -817,9 +855,12 @@ def serve_step_sp_paged(params, state, tokens, cfg: ModelConfig, *, mesh,
 # a global pool of `num_pages` pages of `page_size` tokens plus a per-slot
 # page table translating logical token positions to physical pages
 # (serve.paged owns allocation, ref-counts and shared-prefix admission).
-# Each step scatters the new token's K/V (and indexer-K) rows into the
-# slot's current page and runs the same `_attend_decode` core as the dense
-# layout. The sparse-attention stage is block-table-native by default
+# One forward over Q query rows a slot (`_paged_forward`) serves the decode
+# step (Q = 1) and the speculative verify tick (Q = d+1): each layer
+# scatters every row's K/V (and indexer-K) entries into the slots' pages,
+# selects per row and attends once over all rows. The sequence-sharded
+# placement is the same forward per shard (`_sp_paged_forward`). The
+# sparse-attention stage is block-table-native by default
 # (`paged_attn="fused"`): Top-K selection happens on the logical indexer
 # view, then attention gathers exactly the selected rows straight from the
 # page pools — the big K/V logical views are never materialized
@@ -899,18 +940,20 @@ def paged_state_batch_axes(cfg: ModelConfig) -> Dict[str, int]:
     return axes
 
 
-def _paged_targets(state, pool: str, min_write_pos):
-    """Where a paged step writes each row's new token: (positions, new_len,
-    dest page (the sink page for masked rows), offset, the clipped table
-    for logical views, logical extent)."""
-    positions = state["length"]
+def _paged_targets(state, pool: str, min_write_pos, q: int = 1):
+    """Where a paged step writes the new tokens of its B·Q query rows
+    (slot-major, row j of slot b at length[b] + j): (positions, causal
+    extents, dest page (the sink page for masked rows), offset, the clipped
+    table for logical views, logical extent)."""
+    positions = _row_positions(state["length"], q)
     new_len = positions + 1
     table = state["page_table"]
     page_size = state[pool].shape[2]
     sink = state[pool].shape[1] - 1
     lp = positions // page_size
     off = positions % page_size
-    phys = jnp.take_along_axis(table, lp[:, None], axis=1)[:, 0]
+    phys = jnp.take_along_axis(table, lp.reshape(table.shape[0], q),
+                               axis=1).reshape(-1)
     writable = phys >= 0
     if min_write_pos is not None:
         writable &= positions >= min_write_pos
@@ -919,15 +962,15 @@ def _paged_targets(state, pool: str, min_write_pos):
             table.shape[1] * page_size)
 
 
-# The two paged bodies carry the layer-stacked page pools (L, P + 1,
+# The paged forwards carry the layer-stacked page pools (L, P + 1,
 # page_size, row) whole through their layer scans and touch them only by
-# one scatter of each slot's new row at [layer, dest, off] (`_pool_write`)
-# and by reads that fold the layer into their gather indices
-# (`layers.pool_read`, `_pool_view`). A layer slice `pool[layer]`, or a
-# pool in the scan's xs/ys, would copy the layer's pool on every step.
+# one scatter of all the rows' new entries at [layer, dest, off]
+# (`_pool_write`) and by reads that fold the layer into their gather
+# indices (`layers.pool_read`, `_pool_view`). A layer slice `pool[layer]`,
+# or a pool in the scan's xs/ys, would copy the layer's pool on every step.
 
 def _pool_write(pool, layer, dest, off, row):
-    """Scatter the B new rows (B, ...) into layer `layer` of a stacked pool."""
+    """Scatter the T new rows (T, ...) into layer `layer` of a stacked pool."""
     row = row.reshape(row.shape[:1] + pool.shape[3:])
     return pool.at[layer, dest, off].set(row.astype(pool.dtype))
 
@@ -939,14 +982,164 @@ def _pool_view(pool, layer, gather, n):
         (gather.shape[0], n) + pool.shape[3:])
 
 
+def _paged_forward(params, state, tokens, cfg: ModelConfig, *, q: int,
+                   min_write_pos, paged_attn: str, gather_granularity: str,
+                   mesh, rules):
+    """The one-device paged forward over Q query rows a slot: a decode step
+    is Q = 1 (`serve_step_paged`), a speculative verify tick Q = d+1
+    (`serve_step_spec_paged`). tokens, min_write_pos: (B·Q,) slot-major,
+    row j of slot b at position length[b] + j.
+
+    Per layer: project all rows; write every row's K/V (and indexer-K)
+    before anything attends, in one scatter a pool; build the logical
+    indexer view once; run the Top-K chain over the Q rows, row 0 warm
+    from the incoming `prev_topk`, row j+1 from row j; then one attention
+    call over every row's selection (or the dense pre-gate fallback over
+    every row's extent); the out-projection and MLP. Row j's consumers mask
+    beyond its own causal extent length + j + 1, so the rows written by
+    later rows of the tick contribute exactly zero.
+
+    Returns (logits (B·Q, V) f32, the state with the written pools, outs):
+    outs holds the layer scan's feedback stacks — `prev_topk` (L, B, K),
+    `topk_valid` and the `sel_telemetry` entries (L, B), with a row axis
+    (L, Q, B, ...) where Q > 1 — where the DSA state is carried.
+    """
+    b = state["length"].shape[0]
+    hd = cfg.hd
+    x = constrain(params["embed"][tokens], rules, "batch", "d_model")
+    # `gather` reads logical views: unmapped pages clip to page 0 —
+    # garbage rows, dead beyond `length` under the NEG_SENTINEL masking
+    # convention (finite values, so their post-mask contribution is exactly
+    # zero, as in the dense layout). The fused forms read the indexer-K
+    # view alone (and the dense pre-DSA fallback its view); sparse
+    # attention addresses the page pools through the raw table, masking
+    # the -1 sentinel explicitly (dsa_sparse_attention_paged).
+    positions, lengths, dest, off, gather, n = _paged_targets(
+        state, "k_pages", min_write_pos, q)
+    table = _per_row(state["page_table"], q)
+
+    if paged_attn not in ("fused", "gather"):
+        raise ValueError(f"unknown paged_attn {paged_attn!r} "
+                         f"(expected 'fused' or 'gather')")
+    use_dsa = cfg.dsa.enabled and n > cfg.dsa.min_n
+    # fused covers both attention forms: the sparse (DSA) stage gathers its
+    # Top-K rows from the pools, and the dense pre-DSA fallback attends the
+    # full logical extent through decode_attention_paged — either way the
+    # forward never materializes the K/V logical views itself
+    fused = paged_attn == "fused"
+
+    def layer(carry, xs):
+        x, pools = carry
+        p, li = xs["p"], xs["layer"]
+        h, qh, kn, vn, ik = _project_rows(p, x, positions, cfg, rules,
+                                          use_dsa)
+        with jax.named_scope("step.kv_write"):
+            pools = dict(pools,
+                         k_pages=_pool_write(pools["k_pages"], li, dest, off,
+                                             kn),
+                         v_pages=_pool_write(pools["v_pages"], li, dest, off,
+                                             vn))
+            if use_dsa:
+                pools["idx_k_pages"] = _pool_write(
+                    pools["idx_k_pages"], li, dest, off, ik)
+        kp, vp = pools["k_pages"], pools["v_pages"]
+        if not fused:
+            with jax.named_scope("step.attention"):
+                heads = (b, n, cfg.n_kv_heads, hd)
+                kc, vc = (_per_row(constrain(
+                    _pool_view(pool, li, gather, n).reshape(heads), rules,
+                    "batch", None, None, None), q) for pool in (kp, vp))
+
+        out = {}
+        if use_dsa:
+            with jax.named_scope("step.indexer"):
+                # the indexer scores all N tokens (paper Table 2:
+                # irreducible O(N·d_i)), so its logical view costs what
+                # scoring in page space would — and keeps scores/Top-K in
+                # logical order
+                idx_kc = _pool_view(pools["idx_k_pages"], li, gather, n)
+
+            def select(carry, row):
+                prev, valid = carry
+                h_j, len_j = row
+                sel = dsa_mod.dsa_select(
+                    p["indexer"], h_j, idx_kc, prev, len_j, k=prev.shape[-1],
+                    heads=cfg.dsa.indexer_heads, dim=cfg.dsa.indexer_dim,
+                    rope_base=cfg.rope_base, selector=cfg.dsa.selector,
+                    prev_valid=valid, max_candidates=cfg.dsa.max_candidates,
+                    gate_max_n=cfg.dsa.gate_max_n, min_n=cfg.dsa.min_n,
+                    swa_window=cfg.swa_window, rules=rules, mesh=mesh)
+                # a DSA step just wrote genuine feedback → rows become warm
+                warm = jnp.ones_like(valid)
+                return (sel.indices, warm), dict(
+                    prev_topk=sel.indices, topk_valid=warm,
+                    **sel_telemetry(sel, valid))
+
+            out = _rows_chain(select, (xs["prev_topk"], xs["topk_valid"]),
+                              (h, lengths), q)
+            idx = _flat_rows(out["prev_topk"], q)
+            with jax.named_scope("step.attention"):
+                if fused:
+                    attn = dsa_mod.dsa_sparse_attention_paged(
+                        qh, kp, vp, table, idx, lengths, scale=hd ** -0.5,
+                        granularity=gather_granularity, rules=rules,
+                        layer=li)
+                else:
+                    attn = dsa_mod.dsa_sparse_attention(
+                        qh, kc, vc, idx, lengths, scale=hd ** -0.5,
+                        rules=rules)
+        else:
+            with jax.named_scope("step.attention"):
+                if fused:
+                    attn = decode_attention_paged(
+                        qh, kp, vp, table, lengths, scale=hd ** -0.5,
+                        window=cfg.swa_window, rules=rules, layer=li)
+                else:
+                    attn = decode_attention(qh, kc, vc, lengths,
+                                            scale=hd ** -0.5,
+                                            window=cfg.swa_window)
+            if cfg.dsa.enabled:
+                # before the gate opens the feedback passes through
+                valid = xs["topk_valid"]
+                out = dict(prev_topk=xs["prev_topk"], topk_valid=valid,
+                           **sel_telemetry(None, valid))
+                out = {key: _row_stack(v, q) for key, v in out.items()}
+        x = _decode_out_mlp(p, x, attn, cfg, mesh, rules, q)
+        return (x, pools), out
+
+    pools = {key: state[key] for key in ("k_pages", "v_pages")}
+    if use_dsa:
+        pools["idx_k_pages"] = state["idx_k_pages"]
+    xs = {"p": params["layers"], "layer": jnp.arange(cfg.n_layers)}
+    if cfg.dsa.enabled:
+        xs["prev_topk"] = state["prev_topk"]
+        xs["topk_valid"] = state["topk_valid"]
+    (x, pools), outs = jax.lax.scan(layer, (x, pools), xs)
+    return _lm_head(params, x, cfg), dict(state, **pools), outs
+
+
+def _decode_result(logits, state, outs, with_counts: bool):
+    """A one-row forward as a decode step: the state takes the feedback the
+    layer scan left and length + 1; `with_counts` adds the rows'
+    `sel_counts` (B, 4) third."""
+    state = dict(state)
+    state.update({key: outs[key] for key in ("prev_topk", "topk_valid",
+                                             "sel_gvr") if key in outs})
+    state["length"] = state["length"] + 1
+    if with_counts:
+        return logits, state, sel_counts(outs, logits.shape[0])
+    return logits, state
+
+
 def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
                      min_write_pos: Optional[jnp.ndarray] = None,
                      paged_attn: str = "fused",
                      gather_granularity: str = "token",
                      mesh=None, rules: Optional[MeshRules] = None,
                      with_counts: bool = False):
-    """One paged decode step. tokens: (B,) int32. Returns (logits, state),
-    and with `with_counts` the rows' GVR counts (B, 4) third (`sel_counts`).
+    """One paged decode step: the Q = 1 call of the paged forward
+    (`_paged_forward`). tokens: (B,) int32. Returns (logits, state), and
+    with `with_counts` the rows' GVR counts (B, 4) third (`sel_counts`).
 
     Mirrors `serve_step` exactly, with the logical→physical translation at
     the cache boundary: the new token's rows scatter into
@@ -956,7 +1149,7 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
     to mask inactive slots and to replay the last prompt token over a
     shared prefix without copy-on-writing the shared page.
 
-    `paged_attn` picks the physical form of the sparse-attention stage
+    `paged_attn` picks the physical form of the attention stage
     (DESIGN.md §paged) — both are bit-identical in tokens, logits, Top-K
     indices and selector telemetry:
 
@@ -995,99 +1188,12 @@ def serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
             params, state, tokens, cfg, min_write_pos=min_write_pos,
             paged_attn=paged_attn, gather_granularity=gather_granularity,
             mesh=mesh, rules=rules, with_counts=with_counts)
-    b = tokens.shape[0]
-    hd = cfg.hd
-    x = params["embed"][tokens]                          # (B, D)
-    x = constrain(x, rules, "batch", "d_model")
-    # `gather` materializes a logical view: unmapped pages clip to page 0 —
-    # garbage rows, dead beyond `length` under the NEG_SENTINEL masking
-    # convention (finite values, so their post-mask contribution is exactly
-    # zero, as in the dense layout). Under the default fused path this is
-    # only used for the indexer-K view (and the dense pre-DSA fallback);
-    # attention itself never builds a logical view — it addresses the page
-    # pools through the raw table, masking the -1 sentinel explicitly
-    # (dsa_sparse_attention_paged / kernels.paged_sparse_decode_attn).
-    positions, new_len, dest, off, gather, n = _paged_targets(
-        state, "k_pages", min_write_pos)
-    table = state["page_table"]
-
-    if paged_attn not in ("fused", "gather"):
-        raise ValueError(f"unknown paged_attn {paged_attn!r} "
-                         f"(expected 'fused' or 'gather')")
-    use_dsa = cfg.dsa.enabled and n > cfg.dsa.min_n
-    # fused covers both attention forms: the sparse (DSA) stage gathers its
-    # Top-K rows from the pools, and the dense pre-DSA fallback attends the
-    # full logical extent through decode_attention_paged — either way the
-    # step never materializes the K/V logical views itself
-    fused = paged_attn == "fused"
-
-    def layer(carry, xs):
-        x, pools = carry
-        p, li = xs["p"], xs["layer"]
-        kp, vp = pools["k_pages"], pools["v_pages"]
-        prev_topk = xs.get("prev_topk")
-        topk_valid = xs.get("topk_valid")
-        with jax.named_scope("step.qkv"):
-            h = rms_norm(x, p["ln1"])
-            q, kn, vn = _project_qkv(p, h, b, positions, cfg, rules)
-        with jax.named_scope("step.kv_write"):
-            kp = _pool_write(kp, li, dest, off, kn)
-            vp = _pool_write(vp, li, dest, off, vn)
-        if fused:
-            kc = vc = None            # K/V logical views intentionally unbuilt
-        else:
-            with jax.named_scope("step.attention"):
-                heads = (b, n, cfg.n_kv_heads, hd)
-                kc = _pool_view(kp, li, gather, n).reshape(heads)
-                vc = _pool_view(vp, li, gather, n).reshape(heads)
-                kc = constrain(kc, rules, "batch", None, None, None)
-                vc = constrain(vc, rules, "batch", None, None, None)
-
-        pools = dict(pools, k_pages=kp, v_pages=vp)
-        idx_kc = None
-        if use_dsa:
-            with jax.named_scope("step.indexer"):
-                ik = dsa_mod.indexer_k(p["indexer"], h, positions,
-                                       dim=cfg.dsa.indexer_dim,
-                                       rope_base=cfg.rope_base)
-            with jax.named_scope("step.kv_write"):
-                pools["idx_k_pages"] = _pool_write(
-                    pools["idx_k_pages"], li, dest, off, ik)
-            with jax.named_scope("step.indexer"):
-                # the indexer scores all N tokens (paper Table 2:
-                # irreducible O(N·d_i)), so its logical view costs what
-                # scoring in page space would — and keeps scores/Top-K in
-                # logical order
-                idx_kc = _pool_view(pools["idx_k_pages"], li, gather, n)
-        attn, out = _attend_decode(p, h, q, kc, vc, idx_kc, prev_topk,
-                                   topk_valid, new_len, cfg, use_dsa,
-                                   rules, mesh,
-                                   paged=(kp, vp, table, li) if fused else None,
-                                   gather_granularity=gather_granularity)
-        x = _decode_out_mlp(p, x, attn, cfg, mesh, rules)
-        return (x, pools), out
-
-    pools = {k: state[k] for k in ("k_pages", "v_pages")}
-    if use_dsa:
-        pools["idx_k_pages"] = state["idx_k_pages"]
-    xs = {"p": params["layers"], "layer": jnp.arange(cfg.n_layers)}
-    if cfg.dsa.enabled:
-        xs["prev_topk"] = state["prev_topk"]
-        if "topk_valid" in state:
-            xs["topk_valid"] = state["topk_valid"]
-    (x, pools), outs = jax.lax.scan(layer, (x, pools), xs)
-
-    new_state = dict(state, **pools)
-    if cfg.dsa.enabled:
-        new_state["prev_topk"] = outs["prev_topk"]
-        if "topk_valid" in state:
-            new_state["topk_valid"] = outs["topk_valid"]
-            new_state["sel_gvr"] = outs["sel_gvr"]
-    new_state["length"] = new_len
-    logits = constrain(_lm_head(params, x, cfg), rules, "batch", "vocab")
-    if with_counts:
-        return logits, new_state, sel_counts(outs, b)
-    return logits, new_state
+    logits, new_state, outs = _paged_forward(
+        params, state, tokens, cfg, q=1, min_write_pos=min_write_pos,
+        paged_attn=paged_attn, gather_granularity=gather_granularity,
+        mesh=mesh, rules=rules)
+    logits = constrain(logits, rules, "batch", "vocab")
+    return _decode_result(logits, new_state, outs, with_counts)
 
 
 # --------------------------------------------------------------------------
@@ -1422,17 +1528,29 @@ def _mla_serve_step_paged(params, state, tokens, cfg: ModelConfig, *,
 # (DESIGN.md §spec-decode)
 # --------------------------------------------------------------------------
 #
-# One verify tick scores all d+1 draft positions of each slot through the
-# SAME per-token paged step the engine already runs, scanned inside one jit:
-# position j writes its K/V at `length + j` and attends with per-position
-# causal extent `length + j + 1`, so every position reproduces the exact
-# bits of the non-speculative step it stands in for. The GVR feedback is
-# causally extended WITHIN the tick: position j's selection warm-starts
-# position j+1 (the scan threads `prev_topk`/`topk_valid` through the
-# per-token steps), which is precisely the paper's temporal-correlation
-# signal stretched across a multi-token step ("Learn from the Past" argues
-# the correlation survives; the per-position `sel_gvr` stack lets the
-# engine measure how the hit rate degrades with draft depth).
+# One verify tick scores all d+1 draft positions of each slot: position j
+# writes its K/V at `length + j` and attends with per-position causal
+# extent `length + j + 1`, so every position reproduces the exact bits of
+# the non-speculative step it stands in for. Two bodies do it: "mq", the
+# paged forward's Q = d+1 call (`_paged_forward`, `_sp_paged_forward`),
+# and "scan", d+1 of its Q = 1 calls scanned inside one jit — the
+# reference mq is pinned against. The GVR feedback is causally extended
+# WITHIN the tick: position j's selection warm-starts position j+1 (the
+# forward's Top-K chain, the scan's carry), which is precisely the paper's
+# temporal-correlation signal stretched across a multi-token step ("Learn
+# from the Past" argues the correlation survives; the per-position
+# `sel_gvr` stack lets the engine measure how the hit rate degrades with
+# draft depth).
+#
+# mq is bit-identical to scan: position j's consumers all mask beyond its
+# own causal extent (indexer scores, sparse-attention validity, the dense
+# fallback's length mask), and the NEG/-inf sentinels zero masked
+# contributions exactly in f32, so the rows later positions write — fresh
+# under mq, stale under scan — are arithmetically invisible. Frozen rows
+# (j > draft_len) compute garbage at advanced positions under mq (scan
+# computes other garbage at frozen ones); the rollback never selects their
+# stack entries (accept_len <= draft_len), and a frozen eos argmax can
+# never lower accept_len below a live position's.
 #
 # Greedy acceptance and EXACT rollback both happen in-graph: draft token j
 # is accepted iff it matches position j-1's argmax (and every earlier draft
@@ -1560,206 +1678,29 @@ def _spec_accept_rollback(length0, end_state, ys, tokens, draft_len,
             sel_pos, counts, new_state)
 
 
-def _paged_verify_mq(params, state, tokens, cfg: ModelConfig, *, draft_len,
-                     base_mwp, paged_attn: str, gather_granularity: str,
-                     mesh, rules):
-    """Multi-query-row verify body (`verify_kernel="mq"`): all d+1 verify
-    positions of every slot run as one batched forward instead of a scan of
-    d+1 single-token steps — the XLA form of the Pallas mq hot-spot kernels
-    (`kernels.paged_sparse_decode_attn_mq` / `paged_indexer_topk_mq`).
+def _verify_mwp(draft_len, base_mwp, q: int):
+    """min_write_pos of a verify tick's B·Q rows: row j > draft_len is
+    frozen (its write goes to the sink page, as the scan form's frozen
+    position's does)."""
+    live = jnp.arange(q, dtype=jnp.int32)[None, :] <= draft_len[:, None]
+    return jnp.where(live, base_mwp[:, None],
+                     jnp.int32(PAGED_NEVER_WRITE)).reshape(-1)
 
-    Per layer: every position's K/V/indexer-K rows scatter FIRST (position
-    j at `length0 + j`; frozen/masked rows to the sink page), then Top-K
-    selection runs as a chain over the Q axis — row 0 warms from the
-    incoming prev-Top-K, row j+1 from row j's selection, exactly the
-    causally-extended GVR feedback the scan threads through its carry —
-    and attention over all (B, Q) selections is ONE multi-query launch
-    (`dsa_sparse_attention_paged_mq`).
 
-    Bit-identity with the scan form: position j's consumers all mask
-    beyond their own causal extent `length0 + j + 1` (indexer scores,
-    sparse-attention validity, the dense fallback's length mask), and the
-    NEG/-inf sentinels zero masked contributions exactly in f32, so the
-    rows written by later positions — fresh here, stale under the scan —
-    are arithmetically invisible; everything inside the extent was written
-    by earlier positions identically in both forms. Frozen rows (j >
-    draft_len) compute garbage at advanced positions (the scan computes
-    different garbage at frozen positions) — their stack entries are never
-    selected by the rollback (accept_len <= draft_len) and frozen eos
-    argmaxes can never lower accept_len below a live position's, so the
-    accept/rollback arithmetic sees identical inputs wherever it looks.
-
-    Returns (ys, end_state) in `_spec_verify_scan`'s stack format, ready
-    for `_spec_accept_rollback`.
-    """
+def _verify_result(length0, logits, end_state, outs, tokens, draft_len,
+                   max_accept, eos_id: int, dsa_enabled: bool):
+    """A Q = d+1 row forward as a verify tick: its outputs turned into the
+    per-position stacks `_spec_accept_rollback` reads, then acceptance and
+    rollback. Row j of the forward is verify position j."""
     b, d1 = tokens.shape
-    hd = cfg.hd
-    length0 = state["length"]
-    table = state["page_table"]
-    page_size = state["k_pages"].shape[2]
-    sink = state["k_pages"].shape[1] - 1
-    mp = table.shape[1]
-    n = mp * page_size
-    use_dsa = cfg.dsa.enabled and n > cfg.dsa.min_n
-    if paged_attn not in ("fused", "gather"):
-        raise ValueError(f"unknown paged_attn {paged_attn!r} "
-                         f"(expected 'fused' or 'gather')")
-    fused = paged_attn == "fused"
-
-    jj = jnp.arange(d1, dtype=jnp.int32)
-    positions = length0[:, None] + jj[None, :]           # (B, Q)
-    lengths_q = positions + 1                            # causal extents
-    live = jj[None, :] <= draft_len[:, None]             # (B, Q)
-    flat_pos = positions.reshape(b * d1)
-
-    off = positions % page_size
-    phys = jnp.take_along_axis(table, positions // page_size, axis=1)
-    writable = live & (phys >= 0) & (positions >= base_mwp[:, None])
-    dest = jnp.where(writable, phys, sink)
-    gather = jnp.clip(table, 0, sink)
-
-    x = params["embed"][tokens]                          # (B, Q, D)
-    x = constrain(x, rules, "batch", None, "d_model")
-
-    def layer(x, carry):
-        p = carry["p"]
-        kp, vp = carry["k_pages"], carry["v_pages"]
-        idx_kp = carry.get("idx_k_pages")
-        prev_topk = carry.get("prev_topk")               # (B, K)
-        topk_valid = carry.get("topk_valid")             # (B,)
-        with jax.named_scope("step.qkv"):
-            h = rms_norm(x, p["ln1"])                    # (B, Q, D)
-            hf = h.reshape(b * d1, -1)
-            q, kn, vn = _project_qkv(p, hf, b * d1, flat_pos, cfg, rules)
-            q = q.reshape(b, d1, cfg.n_heads, hd)
-            kn = kn.reshape(b, d1, cfg.n_kv_heads, hd)
-            vn = vn.reshape(b, d1, cfg.n_kv_heads, hd)
-        # all Q rows write before anything attends — safe because every
-        # consumer masks beyond its own extent (see docstring)
-        with jax.named_scope("step.kv_write"):
-            kp = kp.at[dest, off].set(kn.reshape(b, d1, -1).astype(kp.dtype))
-            vp = vp.at[dest, off].set(vn.reshape(b, d1, -1).astype(vp.dtype))
-
-        out = {"k_pages": kp, "v_pages": vp}
-        if use_dsa:
-            with jax.named_scope("step.indexer"):
-                ik = dsa_mod.indexer_k(p["indexer"], hf, flat_pos,
-                                       dim=cfg.dsa.indexer_dim,
-                                       rope_base=cfg.rope_base)
-                ik = ik.reshape(b, d1, cfg.dsa.indexer_dim)
-            with jax.named_scope("step.kv_write"):
-                idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
-            with jax.named_scope("step.indexer"):
-                idx_kc = idx_kp[gather].reshape(b, n, cfg.dsa.indexer_dim)
-
-            # the per-row Top-K chain: the mq indexer kernel's VMEM
-            # feedback threading, in XLA form — selection is inherently
-            # sequential over Q (row j warms row j+1)
-            def sel_row(cr, inp):
-                prev, valid = cr
-                h_j, len_j = inp
-                sel = dsa_mod.dsa_select(
-                    p["indexer"], h_j, idx_kc, prev, len_j,
-                    k=prev.shape[-1], heads=cfg.dsa.indexer_heads,
-                    dim=cfg.dsa.indexer_dim, rope_base=cfg.rope_base,
-                    selector=cfg.dsa.selector, prev_valid=valid,
-                    max_candidates=cfg.dsa.max_candidates,
-                    gate_max_n=cfg.dsa.gate_max_n, min_n=cfg.dsa.min_n,
-                    swa_window=cfg.swa_window, rules=rules, mesh=mesh)
-                return ((sel.indices, jnp.ones_like(valid)),
-                        (sel.indices, sel_telemetry(sel, valid)))
-
-            _, (idx_all, tele) = jax.lax.scan(
-                sel_row, (prev_topk, topk_valid),
-                (jnp.swapaxes(h, 0, 1), jnp.swapaxes(lengths_q, 0, 1)))
-            idx_q = jnp.swapaxes(idx_all, 0, 1)          # (B, Q, K)
-            with jax.named_scope("step.attention"):
-                if fused:
-                    attn = dsa_mod.dsa_sparse_attention_paged_mq(
-                        q, kp, vp, table, idx_q, lengths_q,
-                        scale=hd ** -0.5, granularity=gather_granularity,
-                        rules=rules)
-                else:
-                    kc = kp[gather].reshape(b, n, cfg.n_kv_heads, hd)
-                    vc = vp[gather].reshape(b, n, cfg.n_kv_heads, hd)
-                    attn = dsa_mod.dsa_sparse_attention(
-                        q.reshape(b * d1, cfg.n_heads, hd),
-                        jnp.repeat(kc, d1, axis=0),
-                        jnp.repeat(vc, d1, axis=0),
-                        idx_q.reshape(b * d1, -1), lengths_q.reshape(b * d1),
-                        scale=hd ** -0.5, rules=rules)
-                    attn = attn.reshape(b, d1, cfg.n_heads, hd)
-            out["sel_idx"] = idx_all                      # (Q, B, K)
-            out.update(tele)                              # (Q, B) each
-            out["sel_valid"] = jnp.ones((d1,) + topk_valid.shape, bool)
-        else:
-            qf = q.reshape(b * d1, cfg.n_heads, hd)
-            lf = lengths_q.reshape(b * d1)
-            with jax.named_scope("step.attention"):
-                if fused:
-                    attn = decode_attention_paged(
-                        qf, kp, vp, jnp.repeat(table, d1, axis=0), lf,
-                        scale=hd ** -0.5, window=cfg.swa_window, rules=rules)
-                else:
-                    kc = kp[gather].reshape(b, n, cfg.n_kv_heads, hd)
-                    vc = vp[gather].reshape(b, n, cfg.n_kv_heads, hd)
-                    attn = decode_attention(
-                        qf, jnp.repeat(kc, d1, axis=0),
-                        jnp.repeat(vc, d1, axis=0), lf,
-                        scale=hd ** -0.5, window=cfg.swa_window)
-                attn = attn.reshape(b, d1, cfg.n_heads, hd)
-            if prev_topk is not None:
-                # pre-gate passthrough: the scan stacks the same incoming
-                # feedback at every position
-                out["sel_idx"] = jnp.broadcast_to(
-                    prev_topk[None], (d1,) + prev_topk.shape)
-                out["sel_valid"] = jnp.broadcast_to(
-                    topk_valid[None], (d1,) + topk_valid.shape)
-                none = sel_telemetry(None, topk_valid)
-                out.update({k: jnp.broadcast_to(v[None], (d1,) + v.shape)
-                            for k, v in none.items()})
-        if idx_kp is not None:
-            out["idx_k_pages"] = idx_kp
-
-        with jax.named_scope("step.attention"):
-            attn = attn.reshape(b, d1, cfg.n_heads * hd).astype(x.dtype)
-            x = x + attn @ p["wo"]
-        with jax.named_scope("step.mlp"):
-            h2 = rms_norm(x, p["ln2"])
-            if cfg.moe.num_experts:
-                # MoE per position with the scan's (B, 1, D) call shape —
-                # routing/capacity must see the same token batch per call
-                mo = jax.lax.map(
-                    lambda hh: _mlp(p, hh[:, None, :], cfg, mesh)[:, 0],
-                    jnp.swapaxes(h2, 0, 1))
-                m = jnp.swapaxes(mo, 0, 1)
-            else:
-                m = _mlp(p, h2, cfg, mesh)
-            x = x + m
-        x = constrain(x, rules, "batch", None, "d_model")
-        return x, out
-
-    carry_in = {"p": params["layers"], "k_pages": state["k_pages"],
-                "v_pages": state["v_pages"]}
-    if cfg.dsa.enabled:
-        carry_in["idx_k_pages"] = state["idx_k_pages"]
-        carry_in["prev_topk"] = state["prev_topk"]
-        carry_in["topk_valid"] = state["topk_valid"]
-    x, outs = jax.lax.scan(layer, x, carry_in)
-    logits = _lm_head(params, x, cfg)                    # (B, Q, V)
-
-    ys = {"logits": jnp.transpose(logits, (1, 0, 2))}    # (D+1, B, V)
-    if cfg.dsa.enabled:
-        ys["prev_topk"] = jnp.swapaxes(outs["sel_idx"], 0, 1)   # (Q, L, B, K)
-        ys["topk_valid"] = jnp.swapaxes(outs["sel_valid"], 0, 1)
-        ys["sel_gvr"] = jnp.swapaxes(outs["sel_gvr"], 0, 1)
-        ys["sel_counts"] = sel_counts(outs, b)            # (Q, B, 4)
-    end_state = dict(state)
-    end_state["k_pages"] = outs["k_pages"]
-    end_state["v_pages"] = outs["v_pages"]
-    if cfg.dsa.enabled:
-        end_state["idx_k_pages"] = outs["idx_k_pages"]
-    return ys, end_state
+    ys = {"logits": _by_row(logits, d1)}                  # (D+1, B, V)
+    if dsa_enabled:
+        for key in ("prev_topk", "topk_valid", "sel_gvr"):
+            stk = outs[key] if d1 > 1 else outs[key][:, None]
+            ys[key] = jnp.swapaxes(stk, 0, 1)             # (D+1, L, B, ...)
+        ys["sel_counts"] = sel_counts(outs, b).reshape(d1, b, -1)
+    return _spec_accept_rollback(length0, end_state, ys, tokens, draft_len,
+                                 max_accept, eos_id, dsa_enabled)
 
 
 def serve_step_spec_paged(params, state, tokens, cfg: ModelConfig, *,
@@ -1780,10 +1721,11 @@ def serve_step_spec_paged(params, state, tokens, cfg: ModelConfig, *,
     `_spec_accept_rollback` arithmetic over provably-equal stacks):
 
     * "scan" — d+1 sequential `serve_step_paged` calls inside one jitted
-      lax.scan (the PR-5 form; the reference).
-    * "mq" — one multi-query-row forward: batched writes, the chained
-      Top-K warm start, and ONE mq attention launch per layer
-      (`_paged_verify_mq` — the served form of the Pallas mq kernels).
+      lax.scan (the PR-5 form): the reference mq is pinned against.
+    * "mq" — the paged forward's Q = d+1 call (`_paged_forward`, the one
+      `serve_step_paged` makes with Q = 1): one scatter of all rows a
+      pool, the chained Top-K warm start, one attention call over all
+      (B, Q) selections a layer.
 
     Returns (out_tokens (B, D+1), accept_len (B,), logits_all (B, D+1, V),
     sel_gvr_pos (B, D+1), gvr_counts (B, 4), new_state) — see
@@ -1791,7 +1733,7 @@ def serve_step_spec_paged(params, state, tokens, cfg: ModelConfig, *,
     """
     _mla_refuse(f"serve_step_spec_paged (the {verify_kernel!r} verify body)",
                 cfg)
-    b = tokens.shape[0]
+    b, d1 = tokens.shape
     base_mwp = (min_write_pos if min_write_pos is not None
                 else jnp.zeros((b,), jnp.int32))
     if verify_kernel not in ("scan", "mq"):
@@ -1801,13 +1743,14 @@ def serve_step_spec_paged(params, state, tokens, cfg: ModelConfig, *,
     max_accept = jnp.asarray(max_accept, jnp.int32)
 
     if verify_kernel == "mq":
-        ys, end_state = _paged_verify_mq(
-            params, state, tokens, cfg, draft_len=draft_len,
-            base_mwp=base_mwp, paged_attn=paged_attn,
-            gather_granularity=gather_granularity, mesh=mesh, rules=rules)
-        return _spec_accept_rollback(state["length"], end_state, ys, tokens,
-                                     draft_len, max_accept, int(eos_id),
-                                     cfg.dsa.enabled)
+        logits, end_state, outs = _paged_forward(
+            params, state, tokens.reshape(-1), cfg, q=d1,
+            min_write_pos=_verify_mwp(draft_len, base_mwp, d1),
+            paged_attn=paged_attn, gather_granularity=gather_granularity,
+            mesh=mesh, rules=rules)
+        return _verify_result(state["length"], logits, end_state, outs,
+                              tokens, draft_len, max_accept, int(eos_id),
+                              cfg.dsa.enabled)
 
     def step_fn(st, tok, mwp):
         return serve_step_paged(params, st, tok, cfg, min_write_pos=mwp,
@@ -1820,156 +1763,6 @@ def serve_step_spec_paged(params, state, tokens, cfg: ModelConfig, *,
                              paged_state_batch_axes(cfg), cfg.dsa.enabled)
 
 
-def _sp_paged_verify_mq_body(params, state, tokens, draft_len, max_accept,
-                             base_mwp, cfg: ModelConfig, *, eos_id: int,
-                             seq_axis: str):
-    """Per-device mq verify body (`verify_kernel="mq"` under sequence
-    sharding) — `_paged_verify_mq` restructured over the shard-local page
-    pools, running inside the `serve_step_sp_spec_paged` shard_map.
-
-    Per layer: ALL d+1 positions' projections run batched and their
-    K/V/indexer-K rows scatter into whichever shard owns each position
-    (frozen/masked rows to the local sink), then the shard-local logical
-    indexer view is built once and the Top-K chain + attention run per
-    query row (`sp_dsa_decode_paged_local` — selection is inherently
-    sequential over Q, and the O(K)-psum collective schedule is per-row,
-    so the tick's collective count matches the scan form's d+1 schedules;
-    the win is the batched projection/write work). Bit-identity with the
-    scan form follows the single-device mq argument: every consumer masks
-    beyond its own causal extent, so later-position rows — fresh here,
-    stale under the scan — contribute exactly zero, and frozen rows'
-    garbage stacks are never selected by the shared rollback arithmetic.
-
-    Returns the serve_step_spec_paged 6-tuple (replicated outputs + the
-    per-shard end state), via `_spec_accept_rollback`.
-    """
-    from repro.sparse import sp_dsa as sp_dsa_mod
-
-    b, d1 = tokens.shape
-    hd = cfg.hd
-    never = jnp.int32(PAGED_NEVER_WRITE)
-    length0 = state["length"]
-    ppl = state["k_pages"].shape[2] - 1                  # pages per shard
-    page_size = state["k_pages"].shape[3]
-    mp = state["page_table"].shape[1]
-    num_shards = jax.lax.axis_size(seq_axis)
-    mp_local = mp // num_shards
-    n_local = mp_local * page_size
-    kk = state["prev_topk"].shape[-1]
-
-    my = jax.lax.axis_index(seq_axis)
-    shard_offset = (my * n_local).astype(jnp.int32)
-    table = state["page_table"]
-    table_local = jax.lax.dynamic_slice_in_dim(
-        table, my * mp_local, mp_local, axis=1)
-    sink = ppl
-
-    jj = jnp.arange(d1, dtype=jnp.int32)
-    positions = length0[:, None] + jj[None, :]           # (B, Q)
-    lengths_q = positions + 1
-    live = jj[None, :] <= draft_len[:, None]
-    mwp_q = jnp.where(live, base_mwp[:, None], never)
-    flat_pos = positions.reshape(b * d1)
-
-    owner = ((positions >= shard_offset)
-             & (positions < shard_offset + n_local))
-    rel = jnp.clip(positions - shard_offset, 0, n_local - 1)
-    phys = jnp.take_along_axis(table_local, rel // page_size, axis=1)
-    writable = owner & (phys >= 0) & (positions >= mwp_q)
-    dest = jnp.where(writable, phys, sink)
-    off = positions % page_size
-    gather_local = jnp.clip(table_local, 0, sink)
-
-    x = params["embed"][tokens]                          # (B, Q, D)
-
-    def layer(x, carry):
-        p = carry["p"]
-        kp, vp = carry["k_pages"], carry["v_pages"]
-        idx_kp = carry["idx_k_pages"]
-        prev_topk = carry["prev_topk"]                   # (B, K)
-        topk_valid = carry.get("topk_valid")             # (B,)
-        with jax.named_scope("step.qkv"):
-            h = rms_norm(x, p["ln1"])                    # (B, Q, D)
-            hf = h.reshape(b * d1, -1)
-            q, kn, vn = _project_qkv(p, hf, b * d1, flat_pos, cfg, None)
-            q = q.reshape(b, d1, cfg.n_heads, hd)
-            kn = kn.reshape(b, d1, cfg.n_kv_heads, hd)
-            vn = vn.reshape(b, d1, cfg.n_kv_heads, hd)
-        with jax.named_scope("step.indexer"):
-            ik = dsa_mod.indexer_k(p["indexer"], hf, flat_pos,
-                                   dim=cfg.dsa.indexer_dim,
-                                   rope_base=cfg.rope_base)
-            ik = ik.reshape(b, d1, cfg.dsa.indexer_dim)
-        with jax.named_scope("step.kv_write"):
-            kp = kp.at[dest, off].set(kn.astype(kp.dtype))
-            vp = vp.at[dest, off].set(vn.astype(vp.dtype))
-            idx_kp = idx_kp.at[dest, off].set(ik.astype(idx_kp.dtype))
-        with jax.named_scope("step.indexer"):
-            idx_kc = idx_kp[gather_local].reshape(b, n_local,
-                                                  cfg.dsa.indexer_dim)
-
-        def sel_row(cr, inp):
-            prev, valid = cr
-            q_j, h_j, len_j = inp
-            res = sp_dsa_mod.sp_dsa_decode_paged_local(
-                q_j, kp, vp, table_local, p["indexer"], h_j, idx_kc,
-                prev, valid, len_j,
-                k=kk, scale=hd ** -0.5, heads=cfg.dsa.indexer_heads,
-                dim=cfg.dsa.indexer_dim, rope_base=cfg.rope_base,
-                shard_offset=shard_offset, page_size=page_size,
-                max_candidates=cfg.dsa.max_candidates,
-                swa_window=cfg.swa_window, seq_axis=seq_axis)
-            return ((res.new_topk, jnp.ones_like(valid)),
-                    (res.attn_out, res.new_topk, sel_telemetry(res, valid)))
-
-        valid0 = (topk_valid if topk_valid is not None
-                  else jnp.ones((b,), bool))
-        _, (attn_all, idx_all, tele) = jax.lax.scan(
-            sel_row, (prev_topk, valid0),
-            (jnp.swapaxes(q, 0, 1), jnp.swapaxes(h, 0, 1),
-             jnp.swapaxes(lengths_q, 0, 1)))
-
-        out = {"k_pages": kp, "v_pages": vp, "idx_k_pages": idx_kp,
-               "sel_idx": idx_all,                       # (Q, B, K)
-               "sel_valid": jnp.ones((d1, b), bool), **tele}   # (Q, B)
-        with jax.named_scope("step.attention"):
-            attn = jnp.swapaxes(attn_all, 0, 1)          # (B, Q, H, HD)
-            attn = attn.reshape(b, d1, cfg.n_heads * hd).astype(x.dtype)
-            x = x + attn @ p["wo"]
-        with jax.named_scope("step.mlp"):
-            h2 = rms_norm(x, p["ln2"])
-            if cfg.moe.num_experts:
-                mo = jax.lax.map(
-                    lambda hh: _mlp(p, hh[:, None, :], cfg, None)[:, 0],
-                    jnp.swapaxes(h2, 0, 1))
-                m = jnp.swapaxes(mo, 0, 1)
-            else:
-                m = _mlp(p, h2, cfg, None)
-            x = x + m
-        return x, out
-
-    carry_in = {"p": params["layers"],
-                "k_pages": state["k_pages"][:, 0],
-                "v_pages": state["v_pages"][:, 0],
-                "idx_k_pages": state["idx_k_pages"][:, 0],
-                "prev_topk": state["prev_topk"]}
-    if "topk_valid" in state:
-        carry_in["topk_valid"] = state["topk_valid"]
-    x, outs = jax.lax.scan(layer, x, carry_in)
-    logits = _lm_head(params, x, cfg)                    # (B, Q, V)
-
-    ys = {"logits": jnp.transpose(logits, (1, 0, 2)),
-          "prev_topk": jnp.swapaxes(outs["sel_idx"], 0, 1),
-          "topk_valid": jnp.swapaxes(outs["sel_valid"], 0, 1),
-          "sel_gvr": jnp.swapaxes(outs["sel_gvr"], 0, 1),
-          "sel_counts": sel_counts(outs, b)}              # (Q, B, 4)
-    end_state = dict(state)
-    for key in ("k_pages", "v_pages", "idx_k_pages"):
-        end_state[key] = outs[key][:, None]              # restore shard axis
-    return _spec_accept_rollback(length0, end_state, ys, tokens, draft_len,
-                                 max_accept, eos_id, True)
-
-
 def serve_step_sp_spec_paged(params, state, tokens, cfg: ModelConfig, *,
                              mesh, draft_len, max_accept, eos_id: int = -1,
                              min_write_pos: Optional[jnp.ndarray] = None,
@@ -1977,10 +1770,9 @@ def serve_step_sp_spec_paged(params, state, tokens, cfg: ModelConfig, *,
                              seq_axis: str = "seq",
                              rules: Optional[MeshRules] = None):
     """Sequence-sharded speculative verify tick: the same verify semantics
-    as `serve_step_spec_paged`, with the per-device sharded body
-    (`_sp_paged_token_body`) and the whole verify — including the in-graph
-    acceptance/rollback, which is replicated arithmetic — inside ONE
-    shard_map over the mesh's `seq_axis`. Per position the collective
+    as `serve_step_spec_paged`, with the whole verify — including the
+    in-graph acceptance/rollback, which is replicated arithmetic — inside
+    ONE shard_map over the mesh's `seq_axis`. Per position the collective
     schedule is exactly the non-speculative sharded step's (O(1) in
     context length), so a verify tick costs d+1 of those schedules and
     nothing more. Bit-identical to the single-device
@@ -1988,14 +1780,15 @@ def serve_step_sp_spec_paged(params, state, tokens, cfg: ModelConfig, *,
     what pins spec == non-spec on sharded meshes (tests/test_spec.py).
 
     `verify_kernel` picks the verify body, as in the single-device step:
-    "scan" runs d+1 sequential sharded token steps; "mq" batches each
-    layer's projections/writes across all positions and chains selection
-    per row (`_sp_paged_verify_mq_body`) — bit-identical in tokens,
+    "scan" runs d+1 sequential Q = 1 calls of the sharded forward
+    (`_sp_paged_forward`), the reference; "mq" is its one Q = d+1 call —
+    each layer's projections and writes batched over every position,
+    selection and attention chained per row — bit-identical in tokens,
     accept traces, feedback and telemetry.
     """
     _mla_refuse(f"serve_step_sp_spec_paged (the sequence-sharded "
                 f"{verify_kernel!r} verify body)", cfg)
-    b = tokens.shape[0]
+    b, d1 = tokens.shape
     _sp_paged_validate(state, cfg, mesh, seq_axis)
     if verify_kernel not in ("scan", "mq"):
         raise ValueError(f"unknown verify_kernel {verify_kernel!r} "
@@ -2006,22 +1799,23 @@ def serve_step_sp_spec_paged(params, state, tokens, cfg: ModelConfig, *,
 
     def body(params, state, tokens, draft_len, max_accept, base_mwp):
         if verify_kernel == "mq":
-            return _sp_paged_verify_mq_body(params, state, tokens,
-                                            draft_len, max_accept, base_mwp,
-                                            cfg, eos_id=int(eos_id),
-                                            seq_axis=seq_axis)
+            logits, end_state, outs = _sp_paged_forward(
+                params, state, tokens.reshape(-1),
+                _verify_mwp(draft_len, base_mwp, d1), cfg, q=d1,
+                seq_axis=seq_axis)
+            return _verify_result(state["length"], logits, end_state, outs,
+                                  tokens, draft_len, max_accept, int(eos_id),
+                                  True)
 
         def step_fn(st, tok, mwp):
-            return _sp_paged_token_body(params, st, tok, mwp, cfg,
-                                        seq_axis=seq_axis)
+            logits, new_state, outs = _sp_paged_forward(
+                params, st, tok, mwp, cfg, q=1, seq_axis=seq_axis)
+            return _decode_result(logits, new_state, outs, True)
         return _spec_verify_scan(step_fn, state, tokens, draft_len,
                                  max_accept, int(eos_id), base_mwp, axes,
                                  cfg.dsa.enabled)
 
-    pool_spec = P(None, seq_axis)
-    st_spec = {key: (pool_spec if key in ("k_pages", "v_pages", "idx_k_pages")
-                     else P()) for key in state}
-    param_spec = jax.tree.map(lambda _: P(), params)
+    param_spec, st_spec = _sp_specs(params, state, seq_axis)
     fn = jax.shard_map(body, mesh=mesh,
                    in_specs=(param_spec, st_spec, P(), P(), P(), P()),
                    out_specs=(P(), P(), P(), P(), P(), st_spec),

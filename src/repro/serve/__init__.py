@@ -75,8 +75,12 @@ tests/test_sp_engine.py).
 `spec_depth=d` (+ a `serve.spec` drafter) turns the decode tick into a
 d+1-position verify tick over the paged step — draft, verify, and roll
 back exactly on rejection, with the GVR feedback causally extended
-across the draft positions inside the tick. Greedy decode stays
-bit-identical to the non-speculative engine for any draft trace
+across the draft positions inside the tick. The decode step and the
+verify tick are one paged forward over Q query rows a slot (Q = 1 and
+Q = d+1; `verify_kernel="mq"`), one for each placement, single-device
+and sequence-sharded; `verify_kernel="scan"` runs d+1 one-row steps
+instead, the reference the mq form is pinned against. Greedy decode
+stays bit-identical to the non-speculative engine for any draft trace
 (DESIGN.md §spec-decode, tests/test_spec.py).
 """
 

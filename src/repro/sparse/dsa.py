@@ -19,15 +19,17 @@ start is only meaningful in logical space.
 Two physical forms of the sparse-attention stage share the scoring/select
 front half (`dsa_select`):
 
-* `dsa_decode` — caches arrive as contiguous logical views (the dense
-  serving layout, or the paged layout's `paged_attn="gather"` oracle path
-  which materializes the view first);
-* `dsa_decode_paged` — block-table-native (DESIGN.md §paged): attention
-  gathers exactly the Top-K rows straight from the global page pools via
-  the logical→physical translation `table[b, idx // page_size]`, offset
-  `idx % page_size`. The logical K/V views are never built, so per-step
-  gathered KV traffic is O(K) instead of O(N). Selection itself still
-  consumes logical-view indexer scores, so both forms are bit-identical.
+* `dsa_sparse_attention` — caches arrive as contiguous logical views (the
+  dense serving layout through `dsa_decode`, or the paged layout's
+  `paged_attn="gather"` oracle path, which materializes the view first);
+* `dsa_sparse_attention_paged` — block-table-native (DESIGN.md §paged):
+  attention gathers exactly the Top-K rows straight from the global page
+  pools via the logical→physical translation `table[b, idx // page_size]`,
+  offset `idx % page_size`. The logical K/V views are never built, so
+  per-step gathered KV traffic is O(K) instead of O(N). Selection itself
+  still consumes logical-view indexer scores, so both forms are
+  bit-identical. The paged forward (`models.transformer._paged_forward`)
+  runs `dsa_select` per query row and this once over all rows.
 """
 
 from __future__ import annotations
@@ -354,10 +356,12 @@ def dsa_sparse_attention_paged_mq(q: jnp.ndarray, k_pages: jnp.ndarray,
                                   topk_idx: jnp.ndarray,
                                   lengths: jnp.ndarray,
                                   *, scale: float, granularity: str = "token",
-                                  rules=None) -> jnp.ndarray:
-    """Multi-query-row form of `dsa_sparse_attention_paged` — the XLA shape
-    of the speculative verify tick's attention stage (the Pallas hot-spot
-    form is `kernels.paged_sparse_decode_attn_mq`).
+                                  rules=None, layer=None) -> jnp.ndarray:
+    """Multi-query-row form of `dsa_sparse_attention_paged` — the XLA twin
+    of the Pallas hot-spot form `kernels.paged_sparse_decode_attn_mq`
+    (the paged forward's verify tick folds its query rows into the batch
+    the same way, on rows it already holds slot-major); with `layer`, the
+    pools are layer-stacked and read at that layer.
 
     q: (B, Q, H, HD) — the d+1 draft positions' queries; topk_idx:
     (B, Q, K) per-position LOGICAL selections; lengths: (B, Q) per-position
@@ -372,7 +376,7 @@ def dsa_sparse_attention_paged_mq(q: jnp.ndarray, k_pages: jnp.ndarray,
         q.reshape((b * qn,) + q.shape[2:]), k_pages, v_pages,
         jnp.repeat(table, qn, axis=0), topk_idx.reshape(b * qn, -1),
         lengths.reshape(b * qn), scale=scale, granularity=granularity,
-        rules=rules)
+        rules=rules, layer=layer)
     return out.reshape((b, qn) + out.shape[1:])
 
 
@@ -440,43 +444,5 @@ def dsa_decode(q: jnp.ndarray, kcache: jnp.ndarray, vcache: jnp.ndarray,
     with jax.named_scope("step.attention"):
         out = dsa_sparse_attention(q, kcache, vcache, sel.indices, lengths,
                                    scale=scale, rules=rules)
-    return DSAOutput(out, sel.indices, sel.secant_iters, sel.gvr_rows,
-                     sel.fallback, sel.radix_rows)
-
-
-def dsa_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
-                     v_pages: jnp.ndarray, table: jnp.ndarray,
-                     indexer_params, x: jnp.ndarray, idx_kcache: jnp.ndarray,
-                     prev_topk: jnp.ndarray, lengths: jnp.ndarray,
-                     *, k: int, scale: float, heads: int, dim: int,
-                     rope_base: float, selector: str = "auto",
-                     prev_valid: Optional[jnp.ndarray] = None,
-                     max_candidates: Optional[int] = None,
-                     gate_max_n: int = 200_000,
-                     min_n: int = 4096,
-                     swa_window: Optional[int] = None,
-                     gather_granularity: str = "token", rules=None,
-                     mesh=None, layer=None) -> DSAOutput:
-    """Block-table-native DSA decode step: identical scoring/selection to
-    `dsa_decode` (bit-exact — `idx_kcache` is the logical indexer-K view,
-    the paper's irreducible O(N·d_i) read), but attention gathers its K
-    rows straight from the page pools. The K/V logical views are never
-    built; feedback indices stay logical, so GVR's temporal warm start is
-    untouched by the physical layout. `gather_granularity` selects token-
-    vs page-granular DMA for the attention gather (bit-identical either
-    way — see `dsa_sparse_attention_paged`). With `layer` the pools are
-    layer-stacked and read at that layer.
-    """
-    sel = dsa_select(indexer_params, x, idx_kcache, prev_topk, lengths,
-                     k=k, heads=heads, dim=dim, rope_base=rope_base,
-                     selector=selector, prev_valid=prev_valid,
-                     max_candidates=max_candidates, gate_max_n=gate_max_n,
-                     min_n=min_n, swa_window=swa_window, rules=rules,
-                     mesh=mesh)
-    with jax.named_scope("step.attention"):
-        out = dsa_sparse_attention_paged(q, k_pages, v_pages, table,
-                                         sel.indices, lengths, scale=scale,
-                                         granularity=gather_granularity,
-                                         rules=rules, layer=layer)
     return DSAOutput(out, sel.indices, sel.secant_iters, sel.gvr_rows,
                      sel.fallback, sel.radix_rows)

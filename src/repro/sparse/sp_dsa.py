@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.sp_gvr import sp_canonical_topk, sp_gvr_topk_local
-from repro.models.layers import apply_rotary
+from repro.models.layers import apply_rotary, pool_pages, pool_read
 
 NEG = -3.4028235e38
 
@@ -141,7 +141,8 @@ def sp_dsa_decode_paged_local(q, k_pages, v_pages, table_local, idx_params, h,
                               page_size: int,
                               max_candidates=None,
                               swa_window=None,
-                              seq_axis: str = "seq") -> SPDSAPagedResult:
+                              seq_axis: str = "seq",
+                              layer=None) -> SPDSAPagedResult:
     """Shard-local *paged* DSA decode stage (call inside shard_map) — the
     sequence-sharded serving engine's per-layer selection + attention core.
 
@@ -149,7 +150,8 @@ def sp_dsa_decode_paged_local(q, k_pages, v_pages, table_local, idx_params, h,
     partial combine), this form addresses each shard's *local page pool*
     through its slice of the block table and assembles the gathered Top-K
     rows with a single O(K) psum, so the step is **bit-identical** to the
-    single-device block-table-native path (`sparse.dsa.dsa_decode_paged`):
+    single-device block-table-native path
+    (`sparse.dsa.dsa_sparse_attention_paged`):
 
       1. indexer     — each shard scores its local logical view (Eq. 1;
                        per-position math identical to `dsa.indexer_scores`).
@@ -171,7 +173,9 @@ def sp_dsa_decode_paged_local(q, k_pages, v_pages, table_local, idx_params, h,
                        `dsa.dsa_sparse_attention_paged` → identical bits.
 
     Shapes (per shard): q (B, H, HD); k/v_pages (PL+1, page_size, KVH, HD)
-    local pool (last page = this shard's write sink); table_local
+    local pool (last page = this shard's write sink; with `layer`, the
+    layer-stacked (L, PL+1, page_size, KVH, HD) read at that layer through
+    `pool_read`); table_local
     (B, MP_local) int32 LOCAL physical ids (-1 unmapped); idx_view_local
     (B, N_local, dim) the shard's logical indexer view; prev_topk (B, K)
     GLOBAL logical indices (replicated); prev_valid (B,) bool (replicated);
@@ -185,18 +189,16 @@ def sp_dsa_decode_paged_local(q, k_pages, v_pages, table_local, idx_params, h,
     bit-identity pin runs below `gate_max_n` where the single-device auto
     gate resolves to the same mixed dispatch).
 
-    Speculative verify (DESIGN.md §spec-decode): the sharded verify tick
-    (`transformer.serve_step_sp_spec_paged`) scans this stage once per
-    draft position inside one shard_map, threading `prev_topk` from each
-    position's `new_topk` into the next — the collective schedule per
-    position is exactly the non-speculative step's, so a d+1-position
-    verify tick costs d+1 of these O(1)-in-context schedules.
+    Speculative verify (DESIGN.md §spec-decode): the sharded forward over
+    Q query rows (`transformer._sp_paged_forward`) calls this stage once
+    per row, threading `prev_topk` from each row's `new_topk` into the
+    next — the collective schedule per row is exactly the decode step's,
+    so a d+1-position verify tick costs d+1 of these O(1)-in-context
+    schedules.
     """
     b, hl, hd = q.shape
-    kvh = k_pages.shape[2]
-    g = hl // kvh
     n_local = idx_view_local.shape[1]
-    sink = k_pages.shape[0] - 1
+    sink = pool_pages(k_pages, layer)[0] - 1
 
     # -- 1. shard-local indexer scores over the local logical view ------
     with jax.named_scope("step.indexer"):
@@ -233,9 +235,11 @@ def sp_dsa_decode_paged_local(q, k_pages, v_pages, table_local, idx_params, h,
         rel_c = jnp.clip(rel, 0, n_local - 1)
         phys = jnp.take_along_axis(table_local, rel_c // page_size, axis=1)
         mapped_loc = owned & (phys >= 0)
-        flat = jnp.clip(phys, 0, sink) * page_size + rel_c % page_size  # (B, K)
-        kg = k_pages.reshape((sink + 1) * page_size, kvh, hd)[flat]
-        vg = v_pages.reshape((sink + 1) * page_size, kvh, hd)[flat]
+        rows = (jnp.clip(phys, 0, sink), rel_c % page_size)           # (B, K)
+        kg = pool_read(k_pages, layer, *rows).reshape(topk.shape + (-1, hd))
+        vg = pool_read(v_pages, layer, *rows).reshape(topk.shape + (-1, hd))
+        kvh = kg.shape[2]
+        g = hl // kvh
         hit = mapped_loc[:, :, None, None]
         kg = jax.lax.psum(jnp.where(hit, kg, jnp.zeros((), kg.dtype)), seq_axis)
         vg = jax.lax.psum(jnp.where(hit, vg, jnp.zeros((), vg.dtype)), seq_axis)

@@ -7,8 +7,9 @@ heads × 128, 32/8 attention heads × 64 (and the block-table kernels
 again at 128K context). Mosaic refuses here exactly what
 it would refuse on the chip (tiling, unsupported ops, VMEM/SMEM budgets),
 and the whole-step compile reports the device memory the step needs.
-At small widths, the paged step is compiled once more to read how its
-page pools move through the layer scan.
+At small widths, the paged step and the speculative verify tick, on one
+chip and sequence-sharded over four, are compiled once more to read how
+their page pools move through the layer scan.
 """
 
 import dataclasses
@@ -16,7 +17,9 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.registry import get_config
 from repro.kernels import ops
@@ -176,7 +179,8 @@ def _pool_guard_config(case):
     return smoke("deepseek-v3.2", kv_lora_rank=124)     # [c_kv | k_r] = 128
 
 
-@pytest.mark.parametrize("case", ["gqa_dsa", "gqa_closed", "mla"])
+@pytest.mark.parametrize("case", ["gqa_dsa", "gqa_closed", "mla",
+                                  "gqa_dsa.verify", "gqa_closed.verify"])
 def test_paged_step_moves_no_pool(one_chip, case):
     """The layer-stacked page pools stay whole and in place through the
     step's layer scan: with the state donated, the step's temporaries are
@@ -184,8 +188,12 @@ def test_paged_step_moves_no_pool(one_chip, case):
     (dynamic) slice in the optimized program has the shape of a whole
     stacked pool or of one layer's pool. The pools hold four times the
     pages the slots map (room a prefix cache would use), so that one
-    layer's pools outweigh the logical views the step builds of them."""
-    cfg = _pool_guard_config(case)
+    layer's pools outweigh the logical views the step builds of them.
+    A `.verify` case compiles the speculative verify tick of depth 2 in
+    its multi-query form (`verify_kernel="mq"`), the same forward over
+    three query rows a slot."""
+    body, _, tick = case.partition(".")
+    cfg = _pool_guard_config(body)
     model = build_model(cfg)
     slots, max_len = 4, 1024
 
@@ -201,22 +209,88 @@ def test_paged_step_moves_no_pool(one_chip, case):
     pools = {k: v for k, v in state.items() if k.endswith("_pages")}
     layer_bytes = sum(v.size * v.dtype.itemsize // v.shape[0]
                       for v in pools.values())
-    tokens = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
-    step = jax.jit(lambda p, s, t, m: model.serve_step_paged(
-        p, s, t, min_write_pos=m, with_counts=True), donate_argnums=(1,))
-    compiled = step.lower(params, state, tokens, tokens).compile()
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    if tick == "verify":
+        tokens = jax.ShapeDtypeStruct((slots, 3), jnp.int32,
+                                      sharding=one_chip)
+        step = jax.jit(lambda p, s, t, m: model.serve_step_spec_paged(
+            p, s, t, draft_len=m, max_accept=m, min_write_pos=m,
+            verify_kernel="mq"), donate_argnums=(1,))
+    else:
+        tokens = rows
+        step = jax.jit(lambda p, s, t, m: model.serve_step_paged(
+            p, s, t, min_write_pos=m, with_counts=True), donate_argnums=(1,))
+    compiled = step.lower(params, state, tokens, rows).compile()
 
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < layer_bytes, (temp, layer_bytes)
     shapes = set()
     for v in pools.values():
         shapes |= {v.shape, v.shape[1:], (1,) + v.shape[1:]}
+    found = _pool_moves(compiled.as_text(), shapes)
+    assert not found, found
+
+
+def _pool_moves(hlo, shapes):
+    """The copies, pads, concatenations and (dynamic) slices in optimized
+    HLO text whose result has one of `shapes`."""
     moved = re.compile(r"copy|pad|concatenate|slice")
     found = []
     for m in re.finditer(r"%([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
-                         compiled.as_text()):
+                         hlo):
         name, dims, op = m.groups()
         shape = tuple(int(d) for d in dims.split(",") if d)
         if shape in shapes and (moved.search(op) or moved.search(name)):
             found.append(f"{op} {name} {shape}")
+    return found
+
+
+@pytest.mark.parametrize("tick", ["step", "verify"])
+def test_sharded_step_moves_no_pool(one_chip, topo, tick):
+    """The sequence-sharded decode step and mq verify tick (depth 2), on a
+    four-chip "seq" mesh, keep each shard's stacked pools whole and in
+    place too: temporaries below one layer of a shard's pools, and no
+    copy, pad, concatenation or slice shaped like a shard's stacks or one
+    layer of them (`test_paged_step_moves_no_pool`'s test, per shard)."""
+    mesh = Mesh(np.array(topo.devices), ("seq",))
+    model = build_model(_pool_guard_config("gqa_dsa"))
+    slots, max_len, shards = 4, 1024, 4
+
+    def placed(tree, spec=lambda key: P()):
+        return {k: jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, spec(k))), v)
+            for k, v in tree.items()}
+
+    params = placed(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    state = jax.eval_shape(lambda: model.init_sp_paged_decode_state(
+        slots, max_len, num_pages_per_shard=slots * max_len // PAGE,
+        page_size=PAGE, seq_shards=shards))
+    pools = {k: v for k, v in state.items() if k.endswith("_pages")}
+    state = placed(state, lambda k: P(None, "seq") if k in pools else P())
+    layer_bytes = sum(v.size * v.dtype.itemsize // (v.shape[0] * shards)
+                      for v in pools.values())
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32,
+                                sharding=NamedSharding(mesh, P()))
+    if tick == "verify":
+        tokens = jax.ShapeDtypeStruct((slots, 3), jnp.int32,
+                                      sharding=NamedSharding(mesh, P()))
+        step = jax.jit(lambda p, s, t, m: model.serve_step_sp_spec_paged(
+            p, s, t, mesh=mesh, draft_len=m, max_accept=m, min_write_pos=m,
+            verify_kernel="mq"), donate_argnums=(1,))
+    else:
+        tokens = rows
+        step = jax.jit(lambda p, s, t, m: model.serve_step_sp_paged(
+            p, s, t, mesh=mesh, min_write_pos=m, with_counts=True),
+            donate_argnums=(1,))
+    compiled = step.lower(params, state, tokens, rows).compile()
+
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_bytes, (temp, layer_bytes)
+    shapes = set()
+    for v in pools.values():
+        shard = (v.shape[0], 1) + v.shape[2:]            # one device's
+        layer = v.shape[2:]
+        shapes |= {shard, shard[:1] + shard[2:], layer, (1,) + layer,
+                   (1, 1) + layer}
+    found = _pool_moves(compiled.as_text(), shapes)
     assert not found, found
